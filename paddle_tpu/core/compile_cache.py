@@ -1,30 +1,42 @@
 """Persistent XLA compilation cache — the cold-start killer.
 
-ROADMAP item 1 / ISSUE 7 tentpole (a): BENCH_r02-r04 measured
-``compile_s`` of 117-370 s against 35 ms steps, so every restart (and at
-production scale restarts are *constant* — autoscaling, preemption,
-deploys) pays minutes of XLA work to rebuild byte-identical executables.
-jax already ships the fix — ``jax_compilation_cache_dir`` persists
-compiled executables keyed by (HLO, compile options, jax/XLA version,
-accelerator) — but it was applied ad hoc in two places with two
-different hard-coded directories.  This module is the ONE seat:
+Compiling is most of a cold run: minutes of XLA work against
+millisecond steps, paid again by every restart (autoscaling,
+preemption, deploys, and each sealed machine a chip run gets) to rebuild
+byte-identical executables.  jax ships the fix —
+``jax_compilation_cache_dir`` persists compiled executables keyed by
+(HLO, compile options, jax/XLA version, accelerator).  This module is
+the ONE seat that points it somewhere, by one rule
+(:func:`resolve_dir`):
 
-* ``FLAGS_compilation_cache_dir`` (+ ``FLAGS_enable_compilation_cache``,
-  ``FLAGS_compilation_cache_min_entry_bytes``,
-  ``FLAGS_compilation_cache_min_compile_secs``) are the operator
-  surface; :func:`initialize_from_flags` applies them once at package
-  import — before any backend touch — and the flag ``on_change`` hooks
-  re-apply at runtime.
-* ``bench.py`` and ``incubate.autotune`` route through
-  :func:`configure` instead of private ``jax.config.update`` blocks.
-* Cache effectiveness is *observable*: jax's monitoring events feed the
-  ``compile.cache_hits_total`` / ``compile.cache_misses_total`` registry
-  counters (rendered by the Prometheus exporter under exactly those
-  names) and :func:`cache_report` — hits, misses, hit ratio, on-disk
-  entries/bytes, retrieval seconds — which
-  ``observability.compile_tracker.compile_report()`` embeds so one
-  ``--compile-report`` readout answers both "who compiled" and "did the
-  persistent cache absorb it".
+1. ``JAX_COMPILATION_CACHE_DIR`` in the environment: that directory, as
+   given.  Whoever launches the process places the cache (a chip run
+   that is handed a cache which survives the call sets exactly this),
+   and nothing in the repo writes another directory over it — not a
+   flag, not ``bench.py``, not ``incubate.autotune``.
+2. else ``FLAGS_compilation_cache_dir``, when set.
+3. else ``<checkout>/.jax_cache`` (gitignored) — a FIXED path, never a
+   temp name, pid or time: a directory that moves never hits.
+
+:func:`configure` turns the cache on at that directory
+(``FLAGS_enable_compilation_cache=0`` is the one thing that turns it
+off) and applies the entry floors
+(``FLAGS_compilation_cache_min_entry_bytes``,
+``FLAGS_compilation_cache_min_compile_secs``).
+:func:`initialize_from_flags` calls it at package import — before any
+backend touch — when the environment variable or the flag names a
+directory; programs that want the cache regardless (``chip_smoke.py``,
+``bench.py``, ``incubate.autotune``) call :func:`configure` themselves
+and land on rule 3.  The flag ``on_change`` hooks re-apply at runtime.
+
+Cache effectiveness is *observable* whichever rule chose the directory:
+jax's monitoring events feed the ``compile.cache_hits_total`` /
+``compile.cache_misses_total`` registry counters (rendered by the
+Prometheus exporter under exactly those names) and :func:`cache_report`
+— hits, misses, hit ratio, on-disk entries/bytes, retrieval seconds —
+which ``observability.compile_tracker.compile_report()`` embeds so one
+``--compile-report`` readout answers both "who compiled" and "did the
+persistent cache absorb it".
 
 Cache keying (what makes an entry reusable): the key hashes the
 optimized HLO module, the compile options (donation, device assignment),
@@ -39,28 +51,34 @@ import os
 import threading
 from typing import Any, Dict, Optional
 
+import jax
+from jax._src import compilation_cache as _jax_cc
+from jax._src import monitoring as _jax_monitoring
+
 from .. import flags as _flags
 from ..observability import metrics as _metrics
 
 __all__ = [
     "configure", "initialize_from_flags", "cache_report", "active_dir",
-    "is_enabled", "DEFAULT_AUTOTUNE_DIR",
+    "is_enabled", "resolve_dir", "ENV_VAR", "DEFAULT_DIR",
 ]
 
-# the directory incubate.autotune's kernel.enable used to hard-code; it
-# is now just the fallback when FLAGS_compilation_cache_dir is unset
-DEFAULT_AUTOTUNE_DIR = os.path.join("~", ".paddle_tpu_cache")
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: this file is <checkout>/paddle_tpu/core/...
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _M_HITS = _metrics.counter(
     "compile.cache_hits_total", "persistent compilation-cache hits: an "
-    "XLA compile request served from FLAGS_compilation_cache_dir "
-    "instead of compiling (the warm-restart fast path)")
+    "XLA compile request served from the cache directory instead of "
+    "compiling (the warm-restart fast path)")
 _M_MISSES = _metrics.counter(
     "compile.cache_misses_total", "persistent compilation-cache misses: "
     "compile requests that ran XLA and (when above the entry-size/"
     "compile-time floors) wrote a new cache entry")
 
-# jax monitoring event names (stable across the 0.4.x line we support)
+# jax monitoring event names
 _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_MISS = "/jax/compilation_cache/cache_misses"
 _EV_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
@@ -106,103 +124,96 @@ def _install_listeners() -> None:
     with _lock:
         if _state["listeners"]:
             return
-        try:
-            from jax._src import monitoring
-            monitoring.register_event_listener(_on_event)
-            monitoring.register_event_duration_secs_listener(_on_duration)
-            _state["listeners"] = True
-        except Exception:  # noqa: BLE001 - older/newer jax: cache still
-            pass           # works, only the hit/miss evidence is lost
+        _jax_monitoring.register_event_listener(_on_event)
+        _jax_monitoring.register_event_duration_secs_listener(_on_duration)
+        _state["listeners"] = True
 
 
 # ---------------------------------------------------------- application
 
-def _config_update(name: str, value) -> bool:
-    import jax
-    try:
-        jax.config.update(name, value)
-        return True
-    except Exception:  # noqa: BLE001 - option name varies across jax
-        return False
+def resolve_dir() -> str:
+    """The cache directory by the module's one rule: the environment
+    variable (verbatim), else the flag, else ``<checkout>/.jax_cache``."""
+    env = os.environ.get(ENV_VAR, "")
+    if env:
+        return env
+    flag = str(_flags.get_flag("compilation_cache_dir"))
+    if flag:
+        return os.path.abspath(os.path.expanduser(flag))
+    return DEFAULT_DIR
 
 
-def configure(directory: Optional[str] = None, *,
-              min_entry_bytes: Optional[int] = None,
-              min_compile_secs: Optional[float] = None,
-              enable: Optional[bool] = None) -> Optional[str]:
-    """Apply the persistent-cache configuration to jax; returns the
-    active cache directory (None = disabled).
+def configure() -> Optional[str]:
+    """Turn the persistent cache on at :func:`resolve_dir`'s directory,
+    with the entry floors the flags give; returns the active cache
+    directory (None = disabled by ``FLAGS_enable_compilation_cache=0``).
 
-    Every argument defaults to its flag
-    (``FLAGS_compilation_cache_dir`` etc.), so ``configure()`` with no
-    arguments is "apply whatever the flags say" — the idempotent call
-    sites in ``paddle_tpu/__init__``, ``bench.py`` and
-    ``incubate.autotune`` all reduce to that.  The FLAG stays the source
-    of truth across re-applies: callers that want a directory to survive
-    later flag changes must set ``FLAGS_compilation_cache_dir`` (as
-    ``bench.py`` and autotune do), not just pass ``directory=``.  Safe
-    to call before OR after backend init: ``jax.config`` updates are
-    plain config state and the cache is consulted per compile request.
+    There are deliberately no arguments: no call site chooses where the
+    cache lives or what it keeps.  Idempotent, and safe to call before
+    OR after backend init: ``jax.config`` updates are plain config state
+    and the cache is consulted per compile request.
     """
     # flag reads happen OUTSIDE _lock: flags.set_flags holds the flags
     # lock while its on_change hook enters configure(), so taking the
     # locks here in the opposite order would be an AB-BA deadlock
-    if enable is None:
-        enable = bool(_flags.get_flag("enable_compilation_cache"))
-    if directory is None:
-        directory = str(_flags.get_flag("compilation_cache_dir"))
-    if min_entry_bytes is None:
-        min_entry_bytes = int(
-            _flags.get_flag("compilation_cache_min_entry_bytes"))
-    if min_compile_secs is None:
-        min_compile_secs = float(
-            _flags.get_flag("compilation_cache_min_compile_secs"))
+    enable = bool(_flags.get_flag("enable_compilation_cache"))
+    directory = resolve_dir() if enable else None
+    if directory:
+        os.makedirs(directory, exist_ok=True)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs",
+            float(_flags.get_flag("compilation_cache_min_compile_secs")))
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes",
+            int(_flags.get_flag("compilation_cache_min_entry_bytes")))
+    _apply_dir(directory)
+    if directory:
+        _install_listeners()
+    return directory
+
+
+def _apply_dir(directory: Optional[str]) -> None:
+    """The one place ``jax_compilation_cache_dir`` is written."""
     with _lock:
-        directory = directory or None
-        if not enable:
-            directory = None
-        if directory:
-            directory = os.path.abspath(os.path.expanduser(directory))
-            os.makedirs(directory, exist_ok=True)
-        _config_update("jax_compilation_cache_dir", directory)
-        if directory:
-            _config_update("jax_persistent_cache_min_compile_time_secs",
-                           float(min_compile_secs))
-            _config_update("jax_persistent_cache_min_entry_size_bytes",
-                           int(min_entry_bytes))
+        jax.config.update("jax_compilation_cache_dir", directory)
         # jax LATCHES cache-in-use at the first compile of the process
         # (and pins the cache object to the dir it initialized with):
         # without a reset, enabling after anything compiled is silently
         # ignored, and disabling keeps feeding a stale dir.  Return it
         # to pristine so the next compile re-reads the config we just
         # wrote.
-        try:
-            from jax._src import compilation_cache as _jax_cc
-            _jax_cc.reset_cache()
-        except Exception:  # noqa: BLE001 - private across jax versions
-            pass
+        _jax_cc.reset_cache()
         _state["dir"] = directory
-    if directory:
-        _install_listeners()
-    return directory
 
 
 def initialize_from_flags() -> Optional[str]:
     """One-shot apply at package import (the "backend init" seat: it
-    runs before the first program can possibly compile).  A no-op when
-    ``FLAGS_compilation_cache_dir`` is empty, so a user driving
-    ``jax_compilation_cache_dir`` directly is never overridden."""
-    if not str(_flags.get_flag("compilation_cache_dir")):
+    runs before the first program can possibly compile).  Acts when the
+    environment variable or ``FLAGS_compilation_cache_dir`` names a
+    directory — the variable alone already points jax at it; this adds
+    the floors, the hit/miss listeners and the :func:`cache_report`
+    bookkeeping.  With neither set it is a no-op: a library user who
+    never asked for a persistent cache does not get one."""
+    if not os.environ.get(ENV_VAR) \
+            and not str(_flags.get_flag("compilation_cache_dir")):
         return None
     return configure()
 
 
 def flags_changed(_value=None) -> None:
     """on_change hook for every compilation_cache_* flag: re-apply.
-    Only acts once a directory is in play (set now or set before), so
-    merely flipping the min-size flags pre-enable stays a no-op."""
-    if str(_flags.get_flag("compilation_cache_dir")) or _state["dir"]:
+    Only acts once a directory is in play, so merely flipping the
+    min-size flags pre-enable stays a no-op.  Emptying the flag that
+    chose the directory detaches it; a directory chosen by the
+    environment variable or by an explicit :func:`configure` (the
+    checkout default) stays."""
+    applied = active_dir()
+    if os.environ.get(ENV_VAR) \
+            or str(_flags.get_flag("compilation_cache_dir")) \
+            or applied == DEFAULT_DIR:
         configure()
+    elif applied:
+        _apply_dir(None)
 
 
 # -------------------------------------------------------------- readout

@@ -13,9 +13,20 @@ import hashlib
 import os
 import subprocess
 import tempfile
-from typing import Optional
+from typing import Dict, Optional
 
 _CACHE = os.path.join(tempfile.gettempdir(), "paddle_tpu_native")
+
+# component name -> "native" | "python": which implementation each
+# build() call of this process ended on.  A component that was never
+# asked for is absent.  Callers behave the same either way; a run that
+# must not depend on which happened (chip_smoke.py) prints this.
+_outcome: Dict[str, str] = {}
+
+
+def status() -> Dict[str, str]:
+    """Which implementation each requested component is running on."""
+    return dict(_outcome)
 
 
 def build(name: str, extra_flags=()) -> Optional[ctypes.CDLL]:
@@ -23,8 +34,14 @@ def build(name: str, extra_flags=()) -> Optional[ctypes.CDLL]:
 
     Returns None when no C++ toolchain is available (callers fall back to
     their pure-Python implementation).  Set PADDLE_TPU_DISABLE_NATIVE=1 to
-    force the fallback.
+    force the fallback.  The outcome is recorded for :func:`status`.
     """
+    lib = _build(name, extra_flags)
+    _outcome[name] = "native" if lib is not None else "python"
+    return lib
+
+
+def _build(name: str, extra_flags) -> Optional[ctypes.CDLL]:
     if os.environ.get("PADDLE_TPU_DISABLE_NATIVE"):
         return None
     src = os.path.join(os.path.dirname(__file__), f"{name}.cc")

@@ -17,8 +17,9 @@ from typing import Optional
 import jax
 
 from ..core.device import (CPUPlace, CustomPlace, Place,  # noqa: F401
-                           TPUPlace, device_count, get_all_devices,
-                           get_device, is_compiled_with_tpu, set_device)
+                           TPUPlace, current_jax_device, device_count,
+                           get_all_devices, get_device,
+                           is_compiled_with_tpu, set_device)
 
 __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
            "memory_allocated", "memory_reserved", "max_memory_allocated",
@@ -34,7 +35,7 @@ def _device(device=None) -> jax.Device:
         return device
     if isinstance(device, int):
         return jax.devices()[device]
-    return jax.devices()[0]
+    return current_jax_device()
 
 
 def _live_bytes(d: jax.Device) -> int:

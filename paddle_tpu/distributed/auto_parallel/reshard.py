@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ...core.jax_compat import shard_map as _compat_shard_map
 from ...framework.tensor import Tensor
 from .placement import Partial, Placement, Replicate, Shard
 from .process_mesh import ProcessMesh
@@ -88,7 +87,7 @@ def make_partial(fn_per_rank, mesh: Mesh, axis_name: str, *args,
     else:
         in_specs = tuple(in_specs)
 
-    @functools.partial(_compat_shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P(axis_name))
     def run(*local_args):
         out = fn_per_rank(*local_args)
@@ -204,7 +203,7 @@ def nd_mesh_reshard(value, mesh, src_placements, dst_placements,
             mid = list(cur)
             mid[i] = Replicate()
             out_spec = spec_of(mid)
-            value = jax.jit(_compat_shard_map(
+            value = jax.jit(jax.shard_map(
                 lambda x: jax.lax.psum(x, psum_axis), mesh=mesh,
                 in_specs=in_spec, out_specs=out_spec,
                 check_vma=False))(value)

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Tensor
-from ..core.jax_compat import axis_size as _axis_size
 from ..observability import metrics as _metrics
 from ..ops.registry import dispatch as _d, register_op
 from . import mesh as _mesh
@@ -216,9 +215,9 @@ def _reducescatter_impl(x, op, axis):
         return jax.lax.psum_scatter(x, axis, tiled=True)
     if op == ReduceOp.AVG:
         return jax.lax.psum_scatter(x, axis, tiled=True) / \
-            _axis_size(axis)
+            jax.lax.axis_size(axis)
     # MAX/MIN/PROD: full reduce then slice out this rank's tile
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if x.shape[0] % n != 0:
         raise ValueError(
             f"reduce_scatter: dim0 {x.shape[0]} not divisible by group "

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.jax_compat import shard_map as _shard_map
 
 _AXIS = "world"
 
@@ -188,7 +187,7 @@ def _program(kind: str, ranks: Optional[tuple], ndim: int,
         out_spec = P(_AXIS, *([None] * ndim))
     else:  # pragma: no cover
         raise ValueError(kind)
-    body = _shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+    body = jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
                       out_specs=out_spec, check_vma=False)
     return jax.jit(body)
 

@@ -48,8 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ...core.jax_compat import axis_size as _axis_size, \
-    shard_map as _compat_shard_map
 
 __all__ = [
     "HybridConfig", "init_gpt_params", "stack_for_pipeline",
@@ -383,7 +381,7 @@ def _moe_ffn_dist(blocks, x, lidx, cfg, dp_axis="dp"):
     residual path passes through.  The sort/scatter indices are integer
     (non-differentiable); gradients ride the gathered values and the gate
     prob, and the all_to_all transposes to the reverse all_to_all."""
-    DP = _axis_size(dp_axis)
+    DP = jax.lax.axis_size(dp_axis)
     E = cfg.moe_num_experts
     El = E // DP
     B, S, H = x.shape
@@ -780,7 +778,7 @@ def make_hybrid_train_step(mesh: Mesh, cfg: HybridConfig):
     # psum'd over dp before the update and shards all-gathered after), but
     # the static varying-axes analysis can't prove it through all_gather
     ids_spec = P(None, "dp", "cp") if CP > 1 else P(None, "dp", None)
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(specs, opt_specs, opt_specs, P(), ids_spec),
         out_specs=(P(), specs, opt_specs, opt_specs),
@@ -810,12 +808,13 @@ def make_hybrid_train_step(mesh: Mesh, cfg: HybridConfig):
                                   * cfg.intermediate_size)
                 + 2 * cfg.vocab_size * cfg.hidden_size
                 + cfg.seq_len * cfg.hidden_size)
+    dev0 = mesh.devices.flat[0]
     tl = _telemetry.StepTimeline(
         name="train",
         flops_per_token=training_flops_per_token(
             n_params, cfg.num_layers, cfg.hidden_size, cfg.seq_len),
-        device_kind=str(getattr(mesh.devices.flat[0], "device_kind",
-                                "cpu")))
+        # MFU only where the peak table has a row: absent on a CPU mesh
+        device_kind=dev0.device_kind if dev0.platform == "tpu" else None)
     _step_count = [0]
 
     def timed_step(*args, **kwargs):
@@ -1084,7 +1083,7 @@ def make_zero3_train_step(mesh: Mesh, cfg: HybridConfig, grain: int = 0):
     flat_specs = jax.tree_util.tree_unflatten(treedef, [P("dp")] * n_leaves)
     # check_vma=False: the loss IS dp-replicated (pmean / ordered fold of
     # an all_gather), but the static analysis can't prove it
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(flat_specs, flat_specs, flat_specs, P(),
                   P(None, "dp", None)),

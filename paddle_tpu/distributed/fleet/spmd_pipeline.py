@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...core.jax_compat import axis_size as _axis_size, \
-    pvary as _compat_pvary
 from .. import mesh as _mesh
 
 __all__ = ["pipeline_forward", "interleaved_pipeline_forward",
@@ -63,7 +61,7 @@ def pipeline_forward(stage_fn: Callable, params_local: Any, inputs,
     Schedule: M + P - 1 ticks; tick t feeds microbatch t into stage 0; stage s
     processes microbatch t - s.  All ranks execute stage_fn every tick.
     """
-    P_ = _axis_size(pp_axis)
+    P_ = jax.lax.axis_size(pp_axis)
     M = n_microbatches
     idx = jax.lax.axis_index(pp_axis)
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -72,9 +70,8 @@ def pipeline_forward(stage_fn: Callable, params_local: Any, inputs,
     carry0 = jnp.zeros(mb_shape, inputs.dtype)  # activation from prev stage
     outs0 = jnp.zeros((M,) + mb_shape, inputs.dtype)
     perm_fwd = [(i, (i + 1) % P_) for i in range(P_)]
-    # jax_compat.pvary dispatches pcast/pvary and no-ops on pre-vma jax
-    carry0 = _compat_pvary(carry0, (pp_axis,))
-    outs0 = _compat_pvary(outs0, (pp_axis,))
+    carry0 = jax.lax.pcast(carry0, (pp_axis,), to="varying")
+    outs0 = jax.lax.pcast(outs0, (pp_axis,), to="varying")
 
     def tick(state, t):
         carry, outs = state
@@ -124,7 +121,7 @@ def interleaved_pipeline_forward(stage_fn: Callable, chunk_params_local: Any,
     stage_fn(chunk_params, h) -> h' for ONE chunk.
     inputs: [M, mb, ...]; returns [M, mb, ...] last-global-stage outputs.
     """
-    P_ = _axis_size(pp_axis)
+    P_ = jax.lax.axis_size(pp_axis)
     M, V = n_microbatches, n_chunks
     idx = jax.lax.axis_index(pp_axis)
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -133,9 +130,8 @@ def interleaved_pipeline_forward(stage_fn: Callable, chunk_params_local: Any,
     carry0 = jnp.zeros(mb_shape, inputs.dtype)
     outs0 = jnp.zeros((M,) + mb_shape, inputs.dtype)
     perm_fwd = [(i, (i + 1) % P_) for i in range(P_)]
-    # jax_compat.pvary dispatches pcast/pvary and no-ops on pre-vma jax
-    carry0 = _compat_pvary(carry0, (pp_axis,))
-    outs0 = _compat_pvary(outs0, (pp_axis,))
+    carry0 = jax.lax.pcast(carry0, (pp_axis,), to="varying")
+    outs0 = jax.lax.pcast(outs0, (pp_axis,), to="varying")
     # exact tick count: the last microbatch enters at s_{M-1} =
     # ((M-1)//P)*P*V + (M-1)%P and needs P*V ticks to drain
     total_ticks = ((M - 1) // P_) * P_ * V + (M - 1) % P_ + P_ * V

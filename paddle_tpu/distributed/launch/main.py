@@ -4,7 +4,9 @@ Parity: `python/paddle/distributed/launch/main.py:20` (launch),
 `launch/controllers/collective.py:22` (CollectiveController),
 `fleet/elastic/manager.py:124` (restart policy).
 
-Spawns `nproc_per_node` worker processes per host, wires the coordination
+Spawns `nproc_per_node` worker processes per host (one, on a host with
+TPU chips: a chip belongs to one process, and one process drives every
+local chip — see `check_nproc_for_chips`), wires the coordination
 env (PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_MASTER, which
 `init_parallel_env` maps onto `jax.distributed.initialize`), hosts or joins
 the TCPStore rendezvous at `--master`, writes one log file per rank, and —
@@ -36,6 +38,7 @@ import time
 from typing import List, Optional
 
 from ... import flags as _flags
+from ...core.device import local_tpu_chips
 from ...testing import chaos as _chaos
 from ..store import TCPStore
 
@@ -72,12 +75,13 @@ def parse_args(argv=None):
     p.add_argument("--nnodes", type=str, default=None,
                    help="number of nodes (N or MIN:MAX for elastic); "
                         "unset = 1, or auto-detected on a TPU pod")
-    p.add_argument("--nproc_per_node", type=int, default=1)
+    p.add_argument("--nproc_per_node", type=int, default=1,
+                   help="worker processes per host; must stay 1 on a "
+                        "host with TPU chips unless the workers are "
+                        "pinned off them (JAX_PLATFORMS=cpu)")
     p.add_argument("--log_dir", default="log")
     p.add_argument("--log_level", default="INFO")
     p.add_argument("--job_id", default="default")
-    p.add_argument("--devices", default=None,
-                   help="device ids to expose per process (comma list)")
     p.add_argument("--run_mode", default="collective",
                    choices=["collective"])
     p.add_argument("--max_restart", type=int, default=0,
@@ -89,6 +93,37 @@ def parse_args(argv=None):
 
 
 _TPU_STORE_PORT = 37757   # deterministic cross-host TCPStore port
+
+
+def check_nproc_for_chips(nproc: int, environ=None, chips=None) -> None:
+    """Refuse `--nproc_per_node > 1` where the workers would fight over
+    the host's TPU chips.
+
+    A chip belongs to one process at a time: every worker that imports
+    jax opens ALL local chips, so the second one fails or hangs in PJRT
+    start-up.  Nothing here binds a worker to a subset of the chips, and
+    one process driving every local chip through a mesh is the supported
+    layout (`--nproc_per_node 1`, the default; more hosts mean more
+    nodes, not more processes).  Workers pinned off the chips by
+    ``JAX_PLATFORMS`` (e.g. ``cpu`` for a CPU-mesh rehearsal) do not
+    open them and may be as many as asked."""
+    if nproc <= 1:
+        return
+    environ = os.environ if environ is None else environ
+    platforms = [p for p in environ.get("JAX_PLATFORMS", "").split(",")
+                 if p]
+    if platforms and "tpu" not in platforms:
+        return
+    chips = local_tpu_chips() if chips is None else chips
+    if chips:
+        raise SystemExit(
+            f"[launch] --nproc_per_node {nproc} refused: this host has "
+            f"{chips} TPU chip(s) and a chip belongs to one process — "
+            f"each of {nproc} workers would open every local chip and "
+            "all but the first fail or hang at start-up.  Run one "
+            "process per host (--nproc_per_node 1; it drives all local "
+            "chips through the mesh), or set JAX_PLATFORMS=cpu to "
+            "rehearse on a CPU mesh.")
 
 
 def detect_tpu_pod(environ=None):
@@ -458,9 +493,6 @@ class CollectiveController:
         })
         if getattr(self, "coordinator", None):
             env["COORDINATOR_ADDRESS"] = self.coordinator
-        if self.args.devices:
-            devs = self.args.devices.split(",")
-            env["PADDLE_DEVICES"] = devs[local_rank % len(devs)]
         return env
 
     def start_workers(self):
@@ -701,6 +733,7 @@ def launch(argv=None) -> int:
                   f"{args.master}", file=sys.stderr)
     if args.nnodes is None:
         args.nnodes = "1"
+    check_nproc_for_chips(args.nproc_per_node)
     controller = CollectiveController(args)
 
     def handler(sig, frame):

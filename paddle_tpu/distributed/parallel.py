@@ -42,8 +42,7 @@ def init_parallel_env(backend: Optional[str] = None):
     # publishes COORDINATOR_ADDRESS) — PADDLE_MASTER is the TCPStore and
     # cannot double as the coordinator port
     addr = os.environ.get("COORDINATOR_ADDRESS")
-    from ..core.jax_compat import distributed_is_initialized
-    if addr and world > 1 and not distributed_is_initialized():
+    if addr and world > 1 and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=addr,
             num_processes=world,

@@ -265,15 +265,18 @@ def _compile_cache_flag_changed(_value):
 
 
 define_flag("compilation_cache_dir", "",
-            "directory of the persistent XLA compilation cache "
-            "(jax_compilation_cache_dir), applied once at import and "
-            "re-applied on change; warm restarts then skip XLA "
-            "compilation for every already-seen program.  Empty (the "
-            "default) leaves jax's own configuration untouched",
+            "directory of the persistent XLA compilation cache, "
+            "applied once at import and re-applied on change; warm "
+            "restarts then skip XLA compilation for every already-seen "
+            "program.  JAX_COMPILATION_CACHE_DIR in the environment "
+            "takes precedence over this flag; with both empty the "
+            "cache is off until a program calls "
+            "core.compile_cache.configure(), which then uses "
+            "<checkout>/.jax_cache",
             on_change=_compile_cache_flag_changed)
 define_flag("enable_compilation_cache", True,
             "master switch for the persistent compilation cache; 0 "
-            "keeps FLAGS_compilation_cache_dir inert (and detaches an "
+            "keeps every directory choice inert (and detaches an "
             "already-applied dir on change)",
             on_change=_compile_cache_flag_changed)
 define_flag("compilation_cache_min_entry_bytes", -1,
@@ -483,13 +486,16 @@ define_flag("serving_pallas_prefill", True,
             "the prefix-hit suffix write and ladder-bucket chunks) "
             "through the chunked paged-prefill Pallas kernel "
             "(PagedChunkKernelView) instead of the dense linearized-"
-            "table gather; interpret-mode fallback off-TPU, greedy "
-            "streams stay bit-identical either way")
+            "table gather; the kernel compiles through Mosaic on a TPU "
+            "and runs interpreted elsewhere "
+            "(ops/pallas_common.interpret_default), greedy streams stay "
+            "bit-identical either way")
 define_flag("serving_pallas_verify", True,
             "run the spec-decode verify chunk (spec_tick's k candidate "
             "positions) through the paged spec-verify Pallas kernel "
             "(PagedVerifyKernelView) instead of gathering the whole "
-            "pool; interpret-mode fallback off-TPU, accept/reject "
+            "pool; Mosaic on a TPU, interpreted elsewhere "
+            "(ops/pallas_common.interpret_default), accept/reject "
             "decisions stay bit-identical either way")
 define_flag("moe_fused_dispatch", True,
             "route MoE token dispatch/combine through the fused "

@@ -115,7 +115,7 @@ class Tensor:
         if isinstance(self._value, jax.Array) and not self._is_traced():
             try:
                 d = list(self._value.devices())[0]
-                return _device.Place(_device._kind(d), d.id)
+                return _device.Place(d.platform, d.id)
             except Exception:
                 pass
         return _device.current_place()

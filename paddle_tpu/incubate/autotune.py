@@ -44,21 +44,10 @@ def set_config(config=None):
             _config[k].update(v)
     if _config["kernel"]["enable"]:
         # XLA's kernel autotune runs unconditionally; the persistent
-        # compile cache is the knob that saves its results across runs.
-        # Setting the FLAG (not just jax.config) keeps one source of
-        # truth: later flag changes re-apply rather than silently
-        # detaching the dir enabled here.
-        try:
-            from .. import flags as _flags
-            from ..core import compile_cache as _cc
-            if not str(_flags.get_flag("compilation_cache_dir")):
-                _flags.set_flags({
-                    "compilation_cache_dir": _cc.DEFAULT_AUTOTUNE_DIR})
-            else:
-                _cc.configure()
-            _config["kernel"]["cache_dir"] = _cc.active_dir()
-        except Exception:  # noqa: BLE001 - cache dir is best-effort
-            pass
+        # compile cache is the knob that saves its results across runs
+        # (core/compile_cache.py decides where it lives).
+        from ..core import compile_cache as _cc
+        _config["kernel"]["cache_dir"] = _cc.configure()
 
 
 def get_config():

@@ -129,7 +129,6 @@ class MoELayer(Layer):
         # snapshot (R004): the fused data plane is chosen at construction,
         # never inside a traced forward
         self._fused = (bool(_flags.get_flag("moe_fused_dispatch"))
-                       and _pk.moe_fused_available()
                        and hasattr(self.gate, "forward_indices"))
 
     def forward(self, x):

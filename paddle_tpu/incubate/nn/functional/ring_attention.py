@@ -42,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ....core.jax_compat import axis_size as _axis_size, \
-    pvary as _compat_pvary, shard_map as _compat_shard_map
 from ....ops import pallas_flash
 
 __all__ = ["ring_attention_local", "ring_attention",
@@ -197,9 +195,8 @@ def _causal_hop_idx(src, rank):
 
 def _pvary(*xs, axis_name):
     """Mark rank-invariant scan carries as varying over the manual axis so
-    carry types match the rank-dependent updates (jax_compat dispatches
-    the pcast/pvary spelling and no-ops on pre-vma jax)."""
-    return tuple(_compat_pvary(x, (axis_name,)) for x in xs)
+    carry types match the rank-dependent updates."""
+    return tuple(jax.lax.pcast(x, (axis_name,), to="varying") for x in xs)
 
 
 # ----------------------------------------------------- multi-device ring
@@ -215,7 +212,7 @@ def _ring_fwd(q, k, v, axis_name, causal, interpret):
     lse-merge between hops, K/V rotating via ppermute (uniform rotation so
     XLA pipelines hop i+1's permute under hop i's compute; n hops return
     the buffers home)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     B, S, nh, hd = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -249,7 +246,7 @@ def _ring_core_bwd(axis_name, causal, interpret, res, g):
     alongside, so each chunk collects its gradient contributions from every
     rank and arrives home after the full rotation."""
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     lse_b = _lse128(lse)
@@ -288,7 +285,7 @@ def _ring_local_jnp(q, k, v, axis_name, causal, scale):
     repeated per hop right before the block update — the ppermute traffic
     stays 1/(nh/nkv) of the expanded size."""
     _check_gqa(q.shape[1], k.shape[1])
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     B, H, S, D = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -346,7 +343,7 @@ def _ring_attention_val(q, k, v, mesh=None, axis_name="sp", causal=False,
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        _compat_shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         # pallas_call outputs can't declare their varying mesh axes; skip
         # the vma check (the ring math is manifestly rank-varying)
@@ -543,7 +540,7 @@ def ulysses_attention_local(q, k, v, axis_name: str, causal: bool = False,
     Returns (B, H, S_local, D).  Differentiable (all_to_all is its own
     transpose).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, H, Sl, D = q.shape
     if H % n or k.shape[1] % n:
         raise ValueError(
@@ -572,7 +569,7 @@ def _ulysses_attention_val(q, k, v, mesh=None, axis_name="sep",
     spec = P(None, None, axis_name, None)
 
     @functools.partial(
-        _compat_shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     def run(q, k, v):
         return ulysses_attention_local(q, k, v, axis_name, causal, scale)

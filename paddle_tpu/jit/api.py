@@ -282,7 +282,14 @@ class StaticFunction:
                         # zeros_like on a non-default-memory array (e.g.
                         # pinned_host offloaded state) trips XLA's memory-
                         # space check; build zeros then copy the placement
-                        if hasattr(arr, "sharding"):
+                        # — of a state that WAS placed (mesh-sharded,
+                        # offloaded).  State left at the default
+                        # placement stays uncommitted like the parameters
+                        # beside it: a device_put would commit it, the
+                        # first step's outputs would then all come back
+                        # committed, and the second call would compile
+                        # the whole step a second time.
+                        if getattr(arr, "committed", False):
                             z = jax.device_put(z, arr.sharding)
                         store[key] = z
                     slots.append(_DictSlot(store, key))

@@ -196,7 +196,7 @@ def status() -> dict:
     """Per-StaticFunction capture report: compiled signatures, SOT
     specializations, guard misses, and graph-break reasons.  The
     observability counterpart of the reference SOT's break-reason logs
-    (`jit/sot/utils/exceptions.py` BreakGraphError taxonomy)."""
+    (`jit/sot/utils/exceptions.py` BreakGraphError classes)."""
     report = {}
     for sf in list(_REGISTRY):
         st = getattr(sf, "_stats", None)

@@ -44,35 +44,40 @@ def training_flops_per_token(n_params: float,
     return flops
 
 
-# bf16 peak FLOP/s per chip by device kind (public spec sheets).  The
-# CPU fallback is a deliberate round 2e12 so CPU-smoke MFU numbers read
-# as schema checks, not performance claims.
+# bf16 peak FLOP/s per chip, keyed by a lower-cased substring of jax's
+# ``device_kind`` (Google Cloud TPU documentation, the per-chip "peak
+# compute" row of each generation's page).  More specific names first:
+# "tpu v5" is a substring of "tpu v5 lite".  There is no CPU row and no
+# default: MFU is a statement about an accelerator, and a device that
+# is not in the table is an error, not 197e12.
 _PEAK_TABLE = {
     "tpu v5 lite": 197e12,   # v5e
     "tpu v5e": 197e12,
-    "tpu v5": 459e12,        # v5p
     "tpu v5p": 459e12,
+    "tpu v5": 459e12,        # v5p reports "TPU v5"
     "tpu v4": 275e12,
     "tpu v6 lite": 918e12,   # v6e (Trillium)
     "tpu v6e": 918e12,
 }
 
 
-def peak_flops(device_kind: Optional[str]) -> float:
-    """bf16 peak FLOP/s per chip for a jax ``device_kind`` string."""
+def peak_flops(device_kind: str) -> float:
+    """bf16 peak FLOP/s per chip for a jax ``device_kind`` string;
+    raises ``ValueError`` for a kind the table does not hold."""
     kind = (device_kind or "").lower()
     for k, v in _PEAK_TABLE.items():
         if k in kind:
             return v
-    return 197e12 if "tpu" in kind else 2e12  # conservative default / CPU
+    raise ValueError(
+        f"no peak FLOP/s entry for device kind {device_kind!r}: MFU is "
+        f"defined only for {sorted(_PEAK_TABLE)} (observability/flops.py)")
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,
         device_kind: Optional[str] = None,
         peak: Optional[float] = None) -> float:
-    """Model FLOPs utilization: achieved FLOP/s over peak FLOP/s."""
+    """Model FLOPs utilization: achieved FLOP/s over peak FLOP/s (the
+    table's row for `device_kind` unless an explicit `peak` is given)."""
     if peak is None:
         peak = peak_flops(device_kind)
-    if not peak or peak <= 0:
-        return 0.0
     return tokens_per_sec * flops_per_token / peak

@@ -231,8 +231,9 @@ def _record_sample(entry: ProgramEntry, dt: float) -> None:
         entry.max_s = max(entry.max_s, dt)
         mean = entry.sampled_seconds / entry.samples
     _M_DEVICE_S.inc(dt, program=entry.key)
-    if entry.flops and mean > 0:
-        _M_MFU.set(round(entry.flops / mean / _peak(), 6),
+    peak = _peak()
+    if entry.flops and mean > 0 and peak is not None:
+        _M_MFU.set(round(entry.flops / mean / peak, 6),
                    program=entry.key)
 
 
@@ -323,15 +324,14 @@ def attach_lowered(entry: Optional[ProgramEntry], lowered,
 
 # ---------------------------------------------------------------- readout
 
-def _device_kind() -> Optional[str]:
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 - readout must render backend-less
+def _peak() -> Optional[float]:
+    """Peak FLOP/s of the chip the programs run on.  None off-TPU: MFU
+    is a chip metric, absent on a CPU rather than computed against a
+    made-up peak.  A TPU kind missing from the table raises."""
+    d = jax.devices()[0]
+    if d.platform != "tpu":
         return None
-
-
-def _peak() -> float:
-    return _flops.peak_flops(_device_kind())
+    return _flops.peak_flops(d.device_kind)
 
 
 def ledger() -> List[Dict[str, Any]]:
@@ -364,7 +364,7 @@ def ledger() -> List[Dict[str, Any]]:
         r["achieved_gflops_per_s"] = (round(achieved / 1e9, 3)
                                       if achieved else None)
         r["mfu"] = (round(achieved / peak, 6)
-                    if achieved and peak > 0 else None)
+                    if achieved and peak is not None else None)
         r["device_time_frac"] = (round(est / total, 4)
                                  if est and total > 0 else None)
     rows.sort(key=lambda r: (-(r["est_device_s"] or 0.0),
@@ -425,6 +425,7 @@ def kernel_coverage() -> List[Dict[str, Any]]:
                "kernel": kernel,
                "via": via,
                "kernels": sorted({n for n, _ in claimed}),
+               "claims": [list(c) for c in claimed],
                "custom_calls": e.custom_calls,
                "targets": list(e.custom_call_targets)}
         if not kernel and any(e.name == s or e.name.startswith(s)
@@ -444,7 +445,7 @@ def report(top: Optional[int] = None) -> Dict[str, Any]:
                 if r["est_device_s"]) or 0.0
     return {"schema": "paddle_tpu.xray/v1",
             "sample_interval": _SAMPLE_INTERVAL,
-            "device_kind": _device_kind(),
+            "device_kind": jax.devices()[0].device_kind,
             "peak_flops_per_chip": _peak(),
             "total_est_device_s": round(total, 6),
             "programs_tracked": len(rows),

@@ -46,13 +46,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only imports on TPU-enabled builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from . import pallas_common
 
 __all__ = ["flash_attention", "flash_attention_fwd",
            "flash_attention_bwd", "supported"]
@@ -60,30 +56,16 @@ __all__ = ["flash_attention", "flash_attention_fwd",
 _NEG_INF = -1e30
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _resolve_interpret(interpret, rate):
-    """The generic pallas interpreter has no lowering for the TPU PRNG
-    primitives; when this jax build ships the TPU-semantics interpreter
-    (``pltpu.InterpretParams``), dropout kernels in interpret mode (CPU
-    CI) run under it.  Older builds don't have it — those fall through
-    to the generic interpreter and the kernels switch to the hash-based
-    mask (see :func:`_dropout_keep`)."""
+    """``None`` asks `pallas_common.interpret_default`.  The generic
+    Pallas interpreter has no lowering for the TPU PRNG primitives, so
+    dropout kernels in interpret mode (CPU CI) run under the
+    TPU-semantics interpreter (``pltpu.InterpretParams``) instead."""
     if interpret is None:
-        interpret = _interpret_default()
-    if (interpret is True and rate > 0.0 and _HAS_PLTPU
-            and hasattr(pltpu, "InterpretParams")):
+        interpret = pallas_common.interpret_default()
+    if interpret is True and rate > 0.0:
         return pltpu.InterpretParams()
     return interpret
-
-
-def _native_prng(interpret) -> bool:
-    """True when the TPU PRNG primitives can run: native TPU, or the
-    TPU-semantics interpreter.  ``interpret is True`` is the generic
-    interpreter, which has no lowering for them."""
-    return interpret is not True
 
 
 def supported(q_shape, k_shape=None, dtype=None) -> bool:
@@ -113,40 +95,15 @@ def _block_seed(seed, bh, qi, ki):
                               + qi * jnp.int32(1 << 10) + ki)
 
 
-def _hash_bits(shape, seed_word):
-    """Per-element uint32 stream as a pure function of (seed word,
-    element coordinates): coordinate-mixed lowbias32 finalizer.  No
-    PRNG state, so it lowers everywhere the VPU ops do — the dropout
-    fallback for the generic pallas interpreter, which has no lowering
-    for ``pltpu.prng_random_bits``."""
-    rows = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-    sw = jax.lax.bitcast_convert_type(
-        jnp.asarray(seed_word, jnp.int32), jnp.uint32)
-    x = (rows * jnp.uint32(0x0001_0193)
-         + cols + sw * jnp.uint32(0x9E37_79B9))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB_352D)
-    x = x ^ (x >> 15)
-    x = x * jnp.uint32(0x846C_A68B)
-    x = x ^ (x >> 16)
-    return x
-
-
-def _dropout_keep(shape, rate, seed_word, native_prng):
-    """Regenerate the dropout keep-mask for the current block.  Both
-    paths are pure functions of (seed_word, coords), so forward and
-    backward kernels redraw bit-identical masks.  ``native_prng``
-    selects the hardware PRNG (TPU / TPU-semantics interpreter) vs the
-    hash stream (generic interpreter)."""
-    if native_prng:
-        pltpu.prng_seed(seed_word)
-        bits = pltpu.prng_random_bits(shape)
-        # bitcast keeps the threshold comparison unsigned
-        if bits.dtype != jnp.uint32:
-            bits = jax.lax.bitcast_convert_type(bits, jnp.uint32)
-    else:
-        bits = _hash_bits(shape, seed_word)
+def _dropout_keep(shape, rate, seed_word):
+    """Regenerate the dropout keep-mask for the current block from the
+    TPU PRNG: a pure function of (seed_word, shape), so the forward and
+    both backward kernels redraw bit-identical masks."""
+    pltpu.prng_seed(seed_word)
+    bits = pltpu.prng_random_bits(shape)
+    # bitcast keeps the threshold comparison unsigned
+    if bits.dtype != jnp.uint32:
+        bits = jax.lax.bitcast_convert_type(bits, jnp.uint32)
     # keep with probability (1 - rate): threshold on the uint32 line
     thresh = jnp.uint32((1.0 - rate) * 4294967295.0)
     return bits < thresh
@@ -158,8 +115,7 @@ def _dropout_keep(shape, rate, seed_word, native_prng):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, nk, offset, rate, has_mask,
-                native_prng):
+                *, scale, causal, bq, bk, nk, offset, rate, has_mask):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -210,8 +166,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
         v = v_ref[:, :]                        # [bk, hd]
         if rate > 0.0:
             keep = _dropout_keep((bq, bk), rate,
-                                 _block_seed(seed_ref[0], bh, qi, ki),
-                                 native_prng)
+                                 _block_seed(seed_ref[0], bh, qi, ki))
             p_v = jnp.where(keep, p / (1.0 - rate), 0.0)
         else:
             p_v = p
@@ -277,6 +232,7 @@ def flash_attention_fwd(q, k, v, causal=False, interpret=None,
     squeezing the (batch, head) dims — Mosaic's lane/sublane alignment
     applies to the (seq, hd) dims, which are tile-friendly."""
     interpret = _resolve_interpret(interpret, float(dropout_rate))
+    pallas_common.claim("flash_fwd", interpret)
     B, Sq, nh, hd = q.shape
     Sk, nkv = k.shape[1], k.shape[2]
     group = nh // nkv
@@ -289,8 +245,7 @@ def flash_attention_fwd(q, k, v, causal=False, interpret=None,
 
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              bq=bq, bk=bk, nk=nk, offset=Sk - Sq,
-                             rate=rate, has_mask=has_mask,
-                             native_prng=_native_prng(interpret))
+                             rate=rate, has_mask=has_mask)
     grid = (B * nh, nq, nk)
 
     def qmap(bh, qi, ki, *_):
@@ -339,8 +294,7 @@ def flash_attention_fwd(q, k, v, causal=False, interpret=None,
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                    mask_ref, dq_ref, dq_scr,
-                   *, scale, causal, bq, bk, nk, offset, rate, has_mask,
-                   native_prng):
+                   *, scale, causal, bq, bk, nk, offset, rate, has_mask):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -387,8 +341,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32)      # [bq, bk]
         if rate > 0.0:
             keep = _dropout_keep((bq, bk), rate,
-                                 _block_seed(seed_ref[0], bh, qi, ki),
-                                 native_prng)
+                                 _block_seed(seed_ref[0], bh, qi, ki))
             dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
         ds = p * (dp - delta) * scale
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
@@ -402,8 +355,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                     mask_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, bq, bk, nq, offset, rate, has_mask,
-                    native_prng):
+                    *, scale, causal, bq, bk, nq, offset, rate, has_mask):
     bh = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -450,8 +402,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             # grid iterates (bh, ki, qi) but must regenerate the exact
             # bits the forward drew for the (qi, ki) tile
             keep = _dropout_keep((bq, bk), rate,
-                                 _block_seed(seed_ref[0], bh, qi, ki),
-                                 native_prng)
+                                 _block_seed(seed_ref[0], bh, qi, ki))
             p_v = jnp.where(keep, p / (1.0 - rate), 0.0)
         else:
             keep = None
@@ -481,6 +432,8 @@ def _flash_bwd(causal, interpret, kv_mask_shape, rate, res, g,
                block_q=512, block_k=512):
     q, k, v, out, lse, mask_arr, seed_arr = res
     interpret = _resolve_interpret(interpret, rate)
+    pallas_common.claim("flash_bwd_dq", interpret)
+    pallas_common.claim("flash_bwd_dkv", interpret)
     B, Sq, nh, hd = q.shape
     Sk, nkv = k.shape[1], k.shape[2]
     group = nh // nkv
@@ -526,8 +479,7 @@ def _flash_bwd(causal, interpret, kv_mask_shape, rate, res, g,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, offset=Sk - Sq, rate=rate,
-                          has_mask=has_mask,
-                          native_prng=_native_prng(interpret)),
+                          has_mask=has_mask),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, Sq, hd), q.dtype),
         interpret=interpret,
@@ -570,8 +522,7 @@ def _flash_bwd(causal, interpret, kv_mask_shape, rate, res, g,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, offset=Sk - Sq, rate=rate,
-                          has_mask=has_mask,
-                          native_prng=_native_prng(interpret)),
+                          has_mask=has_mask),
         grid_spec=dkv_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, nh, Sk, hd), k.dtype),

@@ -7,8 +7,9 @@ in `pallas_flash.py` / `pallas_moe.py`; this module gates applicability and
 registers the dispatched ops so the eager tape engine differentiates
 through each kernel's custom VJP.
 
-Gating: the kernel path is taken on a real TPU backend with supported
-shapes (seqs divisible by their blocks, head_dim in {64, 128, 256}, q
+Gating: the flash kernel path is taken where kernels compile through
+Mosaic (`pallas_common.interpret_default` is false: a TPU backend) with
+supported shapes (seqs divisible by their blocks, head_dim in {64, 128, 256}, q
 heads a multiple of kv heads).  Key-padding masks ([B, 1, 1, Sk] bool /
 [B, Sk]) ride the kernel's kv_mask input; attention dropout runs inside
 the kernel (per-block reseeded TPU PRNG).  Anything else — additive
@@ -18,34 +19,15 @@ fused XLA softmax(QK^T)V path, so the same model code runs everywhere.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
+from . import pallas_common, pallas_flash, pallas_moe
 from .registry import dispatch as _d, register_op
 
-try:
-    from . import pallas_flash
-except ImportError:  # pragma: no cover - jax build without pallas
-    pallas_flash = None
-
-try:
-    from . import pallas_moe
-except ImportError:  # pragma: no cover - jax build without pallas
-    pallas_moe = None
-
 __all__ = ["flash_attention", "flash_attention_available",
-           "as_kv_padding_mask", "moe_fused_available",
+           "as_kv_padding_mask",
            "moe_routing_indices", "moe_dispatch", "moe_combine"]
-
-
-@functools.cache
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def as_kv_padding_mask(attn_mask, B, Sk):
@@ -71,23 +53,23 @@ def as_kv_padding_mask(attn_mask, B, Sk):
 def flash_attention_available(q, k, v, mask=None) -> bool:
     """Shape/backend applicability; `mask` here means a mask the kernel
     CANNOT absorb (callers pass attn_mask only if as_kv_padding_mask
-    returned None for it)."""
-    if pallas_flash is None or getattr(pallas_flash, "pltpu", None) is None:
-        return False
+    returned None for it).  An interpreted flash kernel is a CI device,
+    not a path: off-TPU the fused XLA softmax is the implementation.
+    Callers that must know which path a program took read the kernel
+    claims (`flash_fwd` / `flash_bwd_*`), not this predicate."""
     if mask is not None:
         return False
-    if not _on_tpu():
+    if pallas_common.interpret_default():
         return False
     return pallas_flash.supported(tuple(q.shape), tuple(k.shape))
 
 
-if pallas_flash is not None:
-    def _fa_op(q, k, v, kv_mask, seed, *, causal, dropout_rate, mask_shape):
-        return pallas_flash.flash_attention(
-            q, k, v, causal, None, kv_mask, seed, mask_shape, dropout_rate)
+def _fa_op(q, k, v, kv_mask, seed, *, causal, dropout_rate, mask_shape):
+    return pallas_flash.flash_attention(
+        q, k, v, causal, None, kv_mask, seed, mask_shape, dropout_rate)
 
-    register_op("flash_attention", _fa_op,
-                tags=("mxu", "fused", "pallas"))
+
+register_op("flash_attention", _fa_op, tags=("mxu", "fused", "pallas"))
 
 
 def flash_attention(q, k, v, causal=False, dropout_p=0.0, kv_mask=None):
@@ -121,29 +103,20 @@ def flash_attention(q, k, v, causal=False, dropout_p=0.0, kv_mask=None):
 # ------------------------------------------------------- fused MoE routing
 # The dense (T,E,C) einsum dispatch/combine of the MoE layer replaced by
 # the one-pass index-form kernels of `pallas_moe.py` (ISSUE 18).  Unlike
-# flash attention these run everywhere pallas imports — interpret mode on
-# CPU (row moves, not matmuls, so interpret is not the liability it is
-# for attention grids) and Mosaic on TPU.
+# flash attention these run on every backend — interpret mode on CPU (row
+# moves, not matmuls, so interpret is not the liability it is for
+# attention grids) and Mosaic on TPU.
 
-def moe_fused_available() -> bool:
-    """The fused routing data plane can run (pallas imports; on CPU the
-    kernels run in interpret mode)."""
-    return pallas_moe is not None and \
-        getattr(pallas_moe, "pltpu", None) is not None
-
-
-if pallas_moe is not None:
-    register_op(
-        "moe_routing_indices",
-        lambda eid, slot, keep, *, num_experts, capacity:
-            pallas_moe.routing_indices(eid, slot, keep,
-                                       num_experts, capacity))
-    register_op("moe_dispatch",
-                lambda x, inv: pallas_moe.moe_dispatch(x, inv),
-                tags=("fused", "pallas"))
-    register_op("moe_combine",
-                lambda rows, w, flat: pallas_moe.moe_combine(rows, w, flat),
-                tags=("fused", "pallas"))
+register_op(
+    "moe_routing_indices",
+    lambda eid, slot, keep, *, num_experts, capacity:
+        pallas_moe.routing_indices(eid, slot, keep, num_experts, capacity))
+register_op("moe_dispatch",
+            lambda x, inv: pallas_moe.moe_dispatch(x, inv),
+            tags=("fused", "pallas"))
+register_op("moe_combine",
+            lambda rows, w, flat: pallas_moe.moe_combine(rows, w, flat),
+            tags=("fused", "pallas"))
 
 
 def moe_routing_indices(eid, slot, keep, num_experts, capacity):

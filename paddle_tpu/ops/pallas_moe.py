@@ -24,18 +24,21 @@ fuses multiply-add inside ``dot_general`` while the kernel rounds the
 ``w * row`` product before accumulating — so parity is pinned at
 ~1e-6 absolute, far inside the layer tests' tolerance.
 
-Kernel strategy (one Pallas kernel per direction, grid ``(B=1,)`` —
-a SINGLE grid step): the interpret executor copies every input buffer
-once per grid step, so the one-pass layout pays each buffer once
-(per-slot or per-expert grids would pay the full activation buffer per
-step — the same cost model that shaped the fused chunk-prefill kernel
-in `pallas_paged.py`).  Rows are moved with dynamically-indexed
-loads/stores inside a `fori_loop`, which Mosaic lowers to sequential
-DMA row moves and interpret mode to an XLA while loop of
-dynamic-slice updates.  Gradients are custom VJPs in plain XLA
-(gather <-> scatter-add transposes), so the ops sit on the tape like
-any registered op.  ``jax.experimental.pallas`` missing entirely falls
-back to the identical-math jnp reference (`*_reference`).
+Kernel strategy (one Pallas kernel per direction): rows move by DMA.
+The source buffer stays in HBM (``pl.ANY``) and a grid step issues one
+row copy per destination row of its tile, all in flight on one
+semaphore, then waits for them — the gather happens in the DMA
+engine's addressing, as in the paged attention kernels, and no more
+than one tile of rows is ever resident in VMEM (dispatch: none at all,
+its copies run HBM to HBM; combine: ``k`` source rows per token of the
+tile).  A single-row slice of a 2-D ``[N, M]`` buffer is not
+tile-aligned for Mosaic, so the wrappers present every buffer as
+``[N, p, M // p]`` with ``p = 4 // itemsize`` (1 for float32, 2 for
+bf16): one row is then exactly one ``(p, M // p)`` tile of its dtype's
+packing and ``ref.at[i]`` is a legal DMA operand.  The reshape is
+row-major, so it changes no element order.  Gradients are custom VJPs
+in plain XLA (gather <-> scatter-add transposes), so the ops sit on the
+tape like any registered op.
 """
 
 from __future__ import annotations
@@ -46,19 +49,68 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from . import pallas_common
 
 __all__ = ["routing_indices", "moe_dispatch", "moe_combine",
            "moe_dispatch_reference", "moe_combine_reference"]
 
 
-def _claim(name, mode):
-    from ..observability.xray import claim_kernel
-    claim_kernel(name, mode)
+# destination rows per grid step (that many row copies in flight, k times
+# as many for combine); combine shrinks its tile further so the k
+# gathered source rows per token stay within _COMBINE_VMEM of scratch
+_ROW_TILE = 128
+_COMBINE_VMEM = 2 << 20
+
+
+def _as_rows(x):
+    """[N, M] -> [N, p, M // p]: one row = one DMA-addressable tile
+    (module docstring)."""
+    N, M = x.shape
+    p = max(1, 4 // x.dtype.itemsize)
+    if M % p:
+        raise ValueError(
+            f"fused MoE routing needs the model width ({M}) to be a "
+            f"multiple of {p} for {x.dtype} rows")
+    return x.reshape(N, p, M // p)
+
+
+def _zero_row(rows):
+    """The one all-zero row, in the buffers' row layout."""
+    return jnp.zeros((1,) + rows.shape[1:], rows.dtype)
+
+
+def _pad_rows(a, n, value):
+    """Pad the leading dim of an index/weight array to n with `value`."""
+    if a.shape[0] == n:
+        return a
+    fill = jnp.full((n - a.shape[0],) + a.shape[1:], value, a.dtype)
+    return jnp.concatenate([a, fill], axis=0)
+
+
+def _start_row_copy(src_hbm, zero_hbm, idx, dst_row, sem):
+    """Start the copy of row `idx` of `src_hbm` into `dst_row`; the
+    reserved index one past the end (empty slot / dropped choice) copies
+    the zero row instead, so the buffers never need a padded copy."""
+    n = src_hbm.shape[0]
+
+    @pl.when(idx < n)
+    def _():
+        pltpu.make_async_copy(src_hbm.at[idx], dst_row, sem).start()
+
+    @pl.when(idx >= n)
+    def _():
+        pltpu.make_async_copy(zero_hbm.at[0], dst_row, sem).start()
+
+
+def _wait_all(zero_hbm, dst_row, sem, n):
+    """Wait for n row copies signalled on `sem` (every copy moves one
+    row, so one representative descriptor stands for each)."""
+    def body(_, c):
+        pltpu.make_async_copy(zero_hbm.at[0], dst_row, sem).wait()
+        return c
+    jax.lax.fori_loop(0, n, body, 0)
 
 
 def routing_indices(eid, slot, keep, num_experts, capacity):
@@ -82,16 +134,18 @@ def routing_indices(eid, slot, keep, num_experts, capacity):
     return flat, inv
 
 
-def _dispatch_kernel(inv_ref, x_ref, o_ref, *, rows):
-    """One grid step: pack every expert buffer row by the inverse map
-    (row i of the output is token ``inv[i]``'s activation; the padded
-    zero row of ``x`` fills empty slots)."""
-    def body(i, _):
-        src = inv_ref[i]
-        row = pl.load(x_ref, (pl.dslice(src, 1), slice(None)))
-        pl.store(o_ref, (pl.dslice(i, 1), slice(None)), row)
-        return 0
-    jax.lax.fori_loop(0, rows, body, 0)
+def _dispatch_kernel(inv_ref, x_hbm, zero_hbm, o_hbm, sem, *, tile):
+    """One grid step packs `tile` expert-buffer rows by the inverse map
+    (row i of the output is token ``inv[i]``'s activation; empty slots
+    take the zero row), HBM to HBM."""
+    base = pl.program_id(0) * tile
+
+    def start(r, c):
+        _start_row_copy(x_hbm, zero_hbm, inv_ref[base + r],
+                        o_hbm.at[base + r], sem)
+        return c
+    jax.lax.fori_loop(0, tile, start, 0)
+    _wait_all(zero_hbm, o_hbm.at[0], sem, tile)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -100,20 +154,25 @@ def _dispatch(x, inv, T, interpret):
     packed expert buffers as flat rows [E*C, M]."""
     M = x.shape[1]
     rows = inv.shape[0]
-    x_pad = jnp.concatenate(
-        [x, jnp.zeros((1, M), x.dtype)], axis=0)
+    x_rows = _as_rows(x)
+    tile = min(_ROW_TILE, rows)
+    n = pl.cdiv(rows, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[pl.BlockSpec(x_pad.shape, lambda b, inv: (0, 0))],
-        out_specs=pl.BlockSpec((rows, M), lambda b, inv: (0, 0)),
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        functools.partial(_dispatch_kernel, rows=rows),
+    out = pl.pallas_call(
+        functools.partial(_dispatch_kernel, tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, M), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n * tile,) + x_rows.shape[1:],
+                                       x.dtype),
         interpret=interpret,
-    )(inv, x_pad)
+    )(_pad_rows(inv, n * tile, T), x_rows, _zero_row(x_rows))
+    return out[:rows].reshape(rows, M)
 
 
 def _dispatch_fwd(x, inv, T, interpret):
@@ -130,46 +189,64 @@ def _dispatch_bwd(T, interpret, inv, g):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _combine_kernel(flat_ref, eo_ref, w_ref, o_ref, *, T, k):
-    """One grid step: each token's output row is the w-weighted sum of
-    its k routed expert-output rows (dummy row E*C is zero, so dropped
+def _combine_kernel(flat_ref, eo_hbm, zero_hbm, w_ref, o_ref, buf, sem,
+                    *, tile, k):
+    """One grid step: gather the k routed expert-output rows of each of
+    `tile` tokens into VMEM, then each token's output row is their
+    w-weighted sum (the dummy slot E*C reads the zero row, so dropped
     choices contribute exact zeros — the dense-einsum semantics)."""
-    def body(t, _):
-        wt = pl.load(w_ref, (pl.dslice(t, 1), slice(None)))[0]  # [k]
-        acc = None
-        for j in range(k):
-            row = pl.load(
-                eo_ref, (pl.dslice(flat_ref[t, j], 1), slice(None)))[0]
-            term = wt[j] * row.astype(jnp.float32)
-            acc = term if acc is None else acc + term
-        pl.store(o_ref, (pl.dslice(t, 1), slice(None)),
-                 acc[None].astype(o_ref.dtype))
-        return 0
-    jax.lax.fori_loop(0, T, body, 0)
+    base = pl.program_id(0) * tile * k
+
+    def start(i, c):
+        _start_row_copy(eo_hbm, zero_hbm, flat_ref[base + i],
+                        buf.at[i % k, i // k], sem)
+        return c
+    jax.lax.fori_loop(0, tile * k, start, 0)
+    _wait_all(zero_hbm, buf.at[0, 0], sem, tile * k)
+    acc = w_ref[0] * buf[0].astype(jnp.float32)
+    for j in range(1, k):
+        acc = acc + w_ref[j] * buf[j].astype(jnp.float32)
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _combine(expert_rows, w, flat, interpret):
     """expert_rows: [E*C, M]; w/flat: [T, k].  Returns [T, M]."""
     T, k = w.shape
-    M = expert_rows.shape[1]
-    eo_pad = jnp.concatenate(
-        [expert_rows, jnp.zeros((1, M), expert_rows.dtype)], axis=0)
+    EC, M = expert_rows.shape
+    eo_rows = _as_rows(expert_rows)
+    row_shape = eo_rows.shape[1:]
+    tile = max(1, min(T, _ROW_TILE, _COMBINE_VMEM
+                      // (k * M * expert_rows.dtype.itemsize)))
+    n = pl.cdiv(T, tile)
+    # weights ride as [k, T, 1, 1] float32 so w_ref[j] broadcasts over a
+    # [tile, p, M // p] row tile without an in-kernel relayout
+    w_col = jnp.transpose(_pad_rows(w.astype(jnp.float32), n * tile, 0.0)
+                          )[:, :, None, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(1,),
+        grid=(n,),
         in_specs=[
-            pl.BlockSpec(eo_pad.shape, lambda b, flat: (0, 0)),
-            pl.BlockSpec((T, k), lambda b, flat: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((k, tile, 1, 1), lambda i, flat: (0, i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((T, M), lambda b, flat: (0, 0)),
+        out_specs=pl.BlockSpec((tile,) + row_shape,
+                               lambda i, flat: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((k, tile) + row_shape, expert_rows.dtype),
+            pltpu.SemaphoreType.DMA(()),
+        ],
     )
-    return pl.pallas_call(
-        functools.partial(_combine_kernel, T=T, k=k),
+    out = pl.pallas_call(
+        functools.partial(_combine_kernel, tile=tile, k=k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, M), expert_rows.dtype),
+        out_shape=jax.ShapeDtypeStruct((n * tile,) + row_shape,
+                                       expert_rows.dtype),
         interpret=interpret,
-    )(flat, eo_pad, w)
+    )(_pad_rows(flat, n * tile, EC).reshape(-1), eo_rows,
+      _zero_row(eo_rows), w_col)
+    return out[:T].reshape(T, M)
 
 
 def _combine_fwd(expert_rows, w, flat, interpret):
@@ -198,11 +275,8 @@ def moe_dispatch(x, inv, interpret=None):
     x[inv[i]]`` (zeros for empty slots).  x: [T, M]; inv: [E*C] int32.
     Returns [E*C, M]; reshape to (E, C, M) for the batched experts."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if pltpu is None:
-        return moe_dispatch_reference(x, inv)
-    _claim("moe_fused_dispatch", "interpret" if interpret else
-           "custom_call")
+        interpret = pallas_common.interpret_default()
+    pallas_common.claim("moe_fused_dispatch", interpret)
     return _dispatch(x, inv, x.shape[0], interpret)
 
 
@@ -212,11 +286,8 @@ def moe_combine(expert_rows, w, flat, interpret=None):
     expert_rows: [E*C, M] (the experts' output, flattened); w/flat:
     [T, k].  Returns [T, M]."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if pltpu is None:
-        return moe_combine_reference(expert_rows, w, flat)
-    _claim("moe_fused_combine", "interpret" if interpret else
-           "custom_call")
+        interpret = pallas_common.interpret_default()
+    pallas_common.claim("moe_fused_combine", interpret)
     return _combine(expert_rows, w, flat, interpret)
 
 

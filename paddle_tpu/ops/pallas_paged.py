@@ -17,8 +17,10 @@ data-plane gather op.  Blocks past ceil(seq_len/bs) are skipped entirely
 (`pl.when`), so compute is proportional to the true context length, not
 the padded table width.
 
-Non-TPU backends run the same math as one jnp gather + masked softmax
-(`paged_attention_reference`), which is also the CI oracle.
+Non-TPU backends run the same kernels under the Pallas interpreter
+(`pallas_common.interpret_default`); `paged_attention_reference` and
+`paged_chunk_attention_reference` are the jnp oracles tests and
+`chip_smoke.py` compare against.
 """
 
 from __future__ import annotations
@@ -29,11 +31,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from . import pallas_common
 
 __all__ = ["paged_attention", "paged_attention_reference", "BlockKVCache",
            "paged_write_token", "paged_write_prefill",
@@ -41,17 +41,6 @@ __all__ = ["paged_attention", "paged_attention_reference", "BlockKVCache",
            "paged_verify_attention"]
 
 _NEG_INF = -1e30
-
-
-def _claim(name, mode):
-    """Record trace-time evidence that a Pallas kernel was emitted.
-
-    Interpret-mode `pallas_call` lowers to a plain `stablehlo.while`
-    with no custom-call marker, so the xray HLO scan cannot see it; the
-    claims channel is how the kernel-coverage audit learns which kernel
-    a program actually traced (no-op outside an audit capture)."""
-    from ..observability.xray import claim_kernel
-    claim_kernel(name, mode)
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -75,12 +64,15 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(blk < n_blocks)
     def _():
-        q = q_ref[:, :]                                   # [nh, hd]
         k = k_ref[:, :, :]                                # [nh, bs, hd]
         # batched matvec as [nh, 1, hd] x [nh, bs, hd]: Mosaic's dot
-        # lowering requires a non-empty lhs non-contracting dim set
+        # lowering requires a non-empty lhs non-contracting dim set.  The
+        # unit dim is inserted while the value is float32 and the cast to
+        # the pool dtype follows: Mosaic has no [nh, hd] -> [nh, 1, hd]
+        # shape cast for packed (bf16) vectors.
+        q = q_ref[:, :].astype(jnp.float32)[:, None, :].astype(k.dtype)
         s = jax.lax.dot_general(
-            q[:, None, :], k, (((2,), (2,)), ((0,), (0,))),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)[:, 0, :] * scale  # [nh, bs]
         pos = blk * bs + jax.lax.broadcasted_iota(
             jnp.int32, (nh, bs), 1)
@@ -91,7 +83,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         v = v_ref[:, :, :]                                # [nh, bs, hd]
         pv = jax.lax.dot_general(
-            p.astype(v.dtype)[:, None, :], v,
+            p[:, None, :].astype(v.dtype), v,
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)[:, 0, :]  # [nh, hd]
         acc_scr[:] = acc_scr[:] * alpha[:, None] + pv
@@ -120,11 +112,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
     Returns [B, nh, hd].
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if pltpu is None:  # no pallas TPU lowering available at all
-        return paged_attention_reference(q, k_cache, v_cache, block_tables,
-                                         seq_lens)
-    _claim("paged_decode", "interpret" if interpret else "custom_call")
+        interpret = pallas_common.interpret_default()
+    pallas_common.claim("paged_decode", interpret)
     B, nh, hd = q.shape
     _, _, bs, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
@@ -305,8 +294,8 @@ def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
     def body(i, carry):
         m, l, acc = carry
         blk = tables_ref[b, i]
-        k = pl.load(k_ref, (slice(None), pl.dslice(blk, 1)))[:, 0]
-        v = pl.load(v_ref, (slice(None), pl.dslice(blk, 1)))[:, 0]
+        k = k_ref[:, pl.ds(blk, 1)][:, 0]                 # [nh, bs, hd]
+        v = v_ref[:, pl.ds(blk, 1)][:, 0]
         sc = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale   # [nh, s, bs]
@@ -330,6 +319,25 @@ def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
                                (1, 0, 2)).astype(o_ref.dtype)
 
 
+def _chunk_q_tile(s, nh, hd):
+    """Query-tile length for the grid strategy: the largest halving of
+    the chunk that divides it and keeps the float32 [nh, q_blk, hd]
+    accumulator tile (padded to Mosaic's (8, 128) layout) within 1 MiB.
+    Mosaic's live set per grid instance came to about five such tiles
+    plus the double-buffered k/v blocks (AOT for TPU v5 lite, nh 3..32,
+    hd 64/128, bf16 and f32), so this stays inside the 16 MiB
+    scoped-VMEM default at every width; a whole 1024-token chunk as one
+    tile does not.  Ladder buckets are multiples of the block size, so
+    the halving stops at 64 or above for them; spec-verify chunks
+    (s = k) are one tile."""
+    nh_pad = -(-nh // 8) * 8
+    hd_pad = -(-hd // 128) * 128
+    q_blk = min(s, max(8, (1 << 18) // (nh_pad * hd_pad)))
+    while s % q_blk:
+        q_blk //= 2
+    return q_blk
+
+
 def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
                           interpret=None, strategy=None, q_blk=None,
                           _claim_name="paged_chunk_prefill"):
@@ -351,13 +359,10 @@ def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
     Returns [B, s, nh, hd].
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if pltpu is None:  # no pallas TPU lowering available at all
-        return paged_chunk_attention_reference(
-            q, k_cache, v_cache, block_tables, start_lens)
+        interpret = pallas_common.interpret_default()
     if strategy is None:
         strategy = "fused" if interpret else "grid"
-    _claim(_claim_name, "interpret" if interpret else "custom_call")
+    pallas_common.claim(_claim_name, interpret)
     B, s, nh, hd = q.shape
     bs = k_cache.shape[2]
     max_blocks = block_tables.shape[1]
@@ -382,7 +387,7 @@ def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
         )
     else:
         if q_blk is None:
-            q_blk = s
+            q_blk = _chunk_q_tile(s, nh, hd)
         if s % q_blk:
             raise ValueError(f"chunk length {s} not divisible by q tile "
                              f"{q_blk}")

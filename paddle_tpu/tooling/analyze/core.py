@@ -117,7 +117,7 @@ TRACE_WRAPPERS = {
     "scan", "cond", "while_loop", "fori_loop", "switch", "shard_map",
     "remat", "custom_jvp", "custom_vjp",
 }
-# suffix forms still recognized (e.g. a `_compat_shard_map` wrapper)
+# suffix forms still recognized (e.g. a module-local `_shard_map` alias)
 _TRACE_SUFFIXES = ("jit", "to_static", "shard_map")
 
 

@@ -867,10 +867,9 @@ class InterpretModeKernelInHotPath(Rule):
     hot path to the slow executor even on a real TPU (the exact
     regression the X-ray kernel-coverage audit exists to catch; its
     ``via`` column would still read "interpret" on a TPU build).
-    Compliant shapes: thread a computed flag
-    (``interpret=jax.default_backend() != "tpu"`` — the idiom of
-    `ops/pallas_paged.py` / `ops/pallas_moe.py`), a conditional
-    expression, or put the literal inside an ``if`` whose test probes
+    Compliant shapes: thread a computed flag (``interpret=None``
+    resolved by `ops/pallas_common.interpret_default()` — the one seat
+    every kernel wrapper in `ops/` asks), a conditional expression, or put the literal inside an ``if`` whose test probes
     the backend (a CPU-fallback branch).  Tests may hardcode it freely
     (the rule skips ``test_*`` files like the rest of the code rules)."""
 
@@ -912,10 +911,10 @@ class InterpretModeKernelInHotPath(Rule):
                     "interpret-mode executor: on a TPU build this pins "
                     "the kernel to the slow traced-XLA path (per-grid-"
                     "step buffer copies, no Mosaic lowering) and the "
-                    "X-ray audit keeps reporting via=interpret.  Compute "
-                    "the flag instead (`interpret=jax.default_backend() "
-                    '!= "tpu"`) or guard the literal with a backend '
-                    "check"))
+                    "X-ray audit keeps reporting via=interpret.  Ask the "
+                    "one seat instead (`interpret=pallas_common."
+                    "interpret_default()`) or guard the literal with a "
+                    "backend check"))
         return out
 
 

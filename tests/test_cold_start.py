@@ -101,6 +101,47 @@ def test_autotune_kernel_enable_routes_through_compile_cache(tmp_path):
     assert jax.config.jax_compilation_cache_dir is None
 
 
+def test_cache_dir_rule_env_beats_flag_beats_checkout_default(
+        tmp_path, monkeypatch):
+    """The one rule of core/compile_cache.py: JAX_COMPILATION_CACHE_DIR,
+    as given, even with the flag set; else the flag; else the fixed
+    `<checkout>/.jax_cache`.  The report and the hit/miss listeners are
+    live whichever chose the directory."""
+    env_dir, flag_dir = str(tmp_path / "from_env"), str(tmp_path / "flag")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    assert compile_cache.resolve_dir() == compile_cache.DEFAULT_DIR
+    misses = obs_metrics.get("compile.cache_misses_total")
+    try:
+        with flag_guard(compilation_cache_dir=flag_dir):
+            assert compile_cache.active_dir() == flag_dir
+            monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+            assert compile_cache.resolve_dir() == env_dir
+            assert compile_cache.configure() == env_dir
+            assert jax.config.jax_compilation_cache_dir == env_dir
+            m0 = misses.total()
+            x = paddle.to_tensor(np.ones((29, 31), np.float32))
+            np.asarray((x @ x.T).sum()._value)
+            rep = compile_cache.cache_report()
+            assert rep["enabled"] and rep["dir"] == env_dir
+            assert rep["entries"] > 0 and misses.total() > m0
+            assert not os.path.exists(flag_dir) or not os.listdir(flag_dir)
+        # the flag that never chose the directory is gone: env stays
+        assert compile_cache.active_dir() == env_dir
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        # an explicit configure() with neither set: the checkout default,
+        # which a later flag re-apply keeps (it is not a flag's to drop)
+        monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                            str(tmp_path / "checkout" / ".jax_cache"))
+        assert compile_cache.configure() == compile_cache.DEFAULT_DIR
+        compile_cache.flags_changed()
+        assert compile_cache.active_dir() == compile_cache.DEFAULT_DIR
+    finally:
+        compile_cache._apply_dir(None)
+    assert jax.config.jax_compilation_cache_dir is None
+
+
 # ---------------------------------------------------------- ladder rules
 
 def test_default_ladder_matches_legacy_pow2(model):

@@ -3,7 +3,6 @@ backend" strategy from SURVEY.md §4: real XLA collectives, no TPU pod)."""
 
 import jax
 
-from paddle_tpu.core.jax_compat import shard_map as compat_shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -105,9 +104,7 @@ def test_zero1_sharded_optimizer_state(hybrid_env):
     net.bias.grad = paddle.randn([2])
     hopt.step()
     m1 = opt._accumulators["moment1"][id(net.weight)]
-    # older jax keeps trailing Nones on PartitionSpec; compare normalized
-    assert tuple(s for s in m1.sharding.spec if s is not None) == \
-        ("sharding",)
+    assert tuple(m1.sharding.spec) == ("sharding",)
     # bias (size 2, not divisible by shard degree 2? it is) — just exists
     assert id(net.bias) in opt._accumulators["moment1"]
 
@@ -191,7 +188,7 @@ def test_collectives_inside_shard_map(hybrid_env):
             dist.all_reduce(t, group=g)
             return t._value
 
-    y = jax.jit(compat_shard_map(worker, mesh=m, in_specs=P("mp"),
+    y = jax.jit(jax.shard_map(worker, mesh=m, in_specs=P("mp"),
                               out_specs=P("mp")))(
         jnp.arange(8, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(y), [4, 6, 8, 10, 4, 6, 8, 10])
@@ -210,7 +207,7 @@ def test_allgather_reducescatter_inside_shard_map(hybrid_env):
             return summed._value
 
     x = jnp.arange(8, dtype=jnp.float32)
-    y = jax.jit(compat_shard_map(worker, mesh=m, in_specs=P("dp"),
+    y = jax.jit(jax.shard_map(worker, mesh=m, in_specs=P("dp"),
                               out_specs=P("dp")))(x)
     np.testing.assert_allclose(np.asarray(y), [4, 6, 8, 10, 4, 6, 8, 10])
 
@@ -234,7 +231,7 @@ def test_spmd_pipeline_matches_serial():
         local = jax.tree_util.tree_map(lambda a: a[0], params)
         return pipeline_forward(stage_fn, local, inputs, n_microbatches=M)
 
-    out = jax.jit(compat_shard_map(pipe, mesh=mesh, in_specs=(P("pp"), P()),
+    out = jax.jit(jax.shard_map(pipe, mesh=mesh, in_specs=(P("pp"), P()),
                                 out_specs=P()))(stacked, jnp.asarray(x))
     ref = x.copy()
     for W in Ws:
@@ -514,3 +511,26 @@ def test_stage2_rejects_rank_list_groups(hybrid_mesh):
     with pytest.raises(ValueError, match="mesh-axis"):
         GroupShardedOptimizerStage2(lin.parameters(), opt,
                                     group=new_group(ranks=[0, 1]))
+
+
+def test_next_key_is_accepted_beside_mesh_sharded_arguments():
+    """The framework RNG hands out UNCOMMITTED keys: a jit whose other
+    arguments live on a multi-device mesh takes them (a key committed to
+    device 0 is refused as "incompatible devices") — also after the
+    chain was restored from a key that arrived committed."""
+    import paddle_tpu as paddle
+    from paddle_tpu.framework import random as prandom
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    x = jax.device_put(jnp.ones((4, 4)), NamedSharding(mesh, P("dp", "mp")))
+    f = jax.jit(lambda x, k: x + jax.random.normal(k, x.shape))
+    paddle.seed(11)
+    key = prandom.next_key()
+    assert not key.committed
+    assert f(x, key).sharding.is_equivalent_to(x.sharding, 2)
+    with pytest.raises(ValueError, match="incompatible devices"):
+        f(x, jax.device_put(key, jax.devices()[1]))
+    prandom.set_rng_state(jax.device_put(prandom.get_rng_state(),
+                                         jax.devices()[1]))
+    key2 = prandom.next_key()
+    assert not key2.committed and not prandom.get_rng_state().committed
+    f(x, key2)

@@ -18,10 +18,9 @@ from paddle_tpu.distributed.fleet.hybrid_step import (
 
 
 # The full hybrid matrix is compile-heavy (20-60s per config on the
-# virtual CPU mesh) and was unrunnable before core/jax_compat.py made
-# shard_map available on this jax generation; the representative SP
-# parity config and the schedule accounting stay in the fast tier, the
-# rest of the matrix runs with -m slow.
+# virtual CPU mesh); the representative SP parity config and the schedule
+# accounting stay in the fast tier, the rest of the matrix runs with
+# -m slow.
 def _run_parity(cfg, n_devices, steps=3):
     if cfg.cp > 1:
         shape = (cfg.pp, cfg.dp, cfg.cp, cfg.mp)

@@ -11,7 +11,6 @@ import pytest
 
 import jax
 
-from paddle_tpu.core.jax_compat import shard_map as compat_shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -57,7 +56,7 @@ def test_interleaved_forward_matches_serial(pp, vpp, M):
     mesh = _mesh(pp)
 
     @functools.partial(
-        compat_shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(None, "pp"),
                                          chunk_stack),
                   P()),
@@ -91,7 +90,7 @@ def test_interleaved_training_matches_serial():
     pspec = jax.tree_util.tree_map(lambda _: P(None, "pp"), chunk_stack)
 
     def loss_pipeline(params_vp, inp, tgt):
-        @functools.partial(compat_shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(pspec, P(), P()), out_specs=P())
         def run(pl, i, t):
             local = jax.tree_util.tree_map(lambda l: l[:, 0], pl)
@@ -142,7 +141,7 @@ def test_gpipe_and_interleaved_agree():
     mesh4 = _mesh(4)
     stack4 = stack_stage_params(stages)
 
-    @functools.partial(compat_shard_map, mesh=mesh4,
+    @functools.partial(jax.shard_map, mesh=mesh4,
                        in_specs=(jax.tree_util.tree_map(
                            lambda _: P("pp"), stack4), P()),
                        out_specs=P())
@@ -158,7 +157,7 @@ def test_gpipe_and_interleaved_agree():
         [stack_stage_params([stages[v * pp + r] for r in range(pp)])
          for v in range(vpp)])
 
-    @functools.partial(compat_shard_map, mesh=mesh2,
+    @functools.partial(jax.shard_map, mesh=mesh2,
                        in_specs=(jax.tree_util.tree_map(
                            lambda _: P(None, "pp"), chunk_stack), P()),
                        out_specs=P())

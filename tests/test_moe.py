@@ -175,7 +175,7 @@ def test_all_to_all_dispatch_matches_serial():
     equivalent, ref moe_utils.py) must produce exactly the serial switch
     output when capacity admits every token."""
     from jax.sharding import Mesh
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.fleet.hybrid_step import (
         _moe_ffn_dist, _moe_ffn_serial, HybridConfig)
 
@@ -215,7 +215,7 @@ def test_all_to_all_dispatch_capacity_drops():
     """Over-capacity tokens are dropped (zero contribution), matching the
     reference's capacity semantics."""
     from jax.sharding import Mesh
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed.fleet.hybrid_step import (
         _moe_ffn_dist, HybridConfig)
 

@@ -244,7 +244,7 @@ def _fail_devices(monkeypatch):
     import jax
 
     def boom():
-        raise RuntimeError("no backend: simulated tunnel outage")
+        raise RuntimeError("no backend: simulated chip outage")
     monkeypatch.setattr(jax, "devices", boom)
 
 
@@ -252,7 +252,7 @@ def test_probe_backend_survives_raising_devices(monkeypatch):
     _fail_devices(monkeypatch)
     probe = harness.probe_backend()
     assert probe["ok"] is False
-    assert "simulated tunnel outage" in probe["error"]
+    assert "simulated chip outage" in probe["error"]
 
 
 def test_harness_degradation(monkeypatch):
@@ -392,11 +392,11 @@ def test_bench_backend_unavailable_exits_zero(monkeypatch, tmp_path,
         # ISSUE 2: every bench rung record self-evidences with its own
         # metrics delta
         assert isinstance(recs[name].get("metrics"), dict), name
-    # the telemetry rung embeds a StepTimeline summary with fractions +
-    # MFU from the shared FLOPs helper
+    # the telemetry rung embeds a StepTimeline summary with fractions;
+    # MFU is absent on this CPU run (no peak-table row for a CPU)
     summ = recs["telemetry_train"]["value"]["timeline"]
     assert set(summ["fractions"]) == {"compute", "comm", "host"}
-    assert "mfu" in summ and summ["steps"] >= 1
+    assert "mfu" not in summ and summ["steps"] >= 1
 
 
 @pytest.mark.slow   # tier-1 budget (R010): 30-100s bench child, env-flaky
@@ -448,8 +448,7 @@ def test_bench_cpu_smoke_subprocess(tmp_path):
     summ = recs["telemetry_train"]["value"]["timeline"]
     assert set(summ["fractions"]) == {"compute", "comm", "host"}
     assert abs(sum(summ["fractions"].values()) - 1.0) < 0.02
-    assert isinstance(summ.get("mfu"), float)
-    assert summ["flops_per_token"] > 0 and summ["peak_flops"] > 0
+    assert "mfu" not in summ and "peak_flops" not in summ
     # stderr carried one JSON line per rung
     stderr_rungs = {json.loads(line)["rung"]
                     for line in proc.stderr.splitlines()

@@ -199,10 +199,9 @@ def test_eager_dispatch_and_tape(monkeypatch):
     """The dispatched op differentiates through the kernel's custom VJP."""
     import paddle_tpu as paddle
     from paddle_tpu.ops import pallas_kernels as pk
-    import paddle_tpu.ops.pallas_flash as pf
-    # force the kernel path on CPU (interpret mode)
-    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
-    monkeypatch.setattr(pf, "_interpret_default", lambda: True)
+    # force the kernel path on CPU (it runs interpreted there)
+    monkeypatch.setattr(pk, "flash_attention_available",
+                        lambda *a, **kw: True)
     q, k, v = _qkv(1, 128, 2, 64, seed=4)
     tq = paddle.Tensor._wrap(q, stop_gradient=False)
     tk = paddle.Tensor._wrap(k, stop_gradient=False)
@@ -222,9 +221,8 @@ def test_sdpa_routes_padding_mask_to_kernel(monkeypatch):
     import paddle_tpu as paddle
     from paddle_tpu.nn import functional as F
     from paddle_tpu.ops import pallas_kernels as pk
-    import paddle_tpu.ops.pallas_flash as pf
-    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
-    monkeypatch.setattr(pf, "_interpret_default", lambda: True)
+    monkeypatch.setattr(pk, "flash_attention_available",
+                        lambda *a, **kw: True)
     q, k, v = _qkv(2, 128, 2, 64, seed=10)
     rng = np.random.RandomState(10)
     keep = (rng.rand(2, 128) > 0.25)

@@ -318,7 +318,7 @@ def test_ring_local_gqa_fallback_inside_shard_map():
     q = jnp.asarray(rng.randn(B, nh, S, D).astype(np.float32))
     k = jnp.asarray(rng.randn(B, nkv, S, D).astype(np.float32))
     v = jnp.asarray(rng.randn(B, nkv, S, D).astype(np.float32))
-    from paddle_tpu.core.jax_compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
     spec = P(None, None, "sp", None)
     run = shard_map(

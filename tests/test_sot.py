@@ -139,7 +139,7 @@ def test_guard_thrash_falls_back():
 @pytest.mark.slow   # tier-1 budget (ISSUE 9): heavy, not on the serving/training core path
 def test_status_reports_breaks_and_specs():
     """paddle.jit.status(): the break-reason report the reference SOT
-    logs (jit/sot/utils/exceptions.py taxonomy)."""
+    logs (jit/sot/utils/exceptions.py classes)."""
     def good(x):
         if x.mean() > 0:
             return x + 1

@@ -10,7 +10,6 @@ import pytest
 
 import jax
 
-from paddle_tpu.core.jax_compat import shard_map as compat_shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -248,7 +247,7 @@ def test_partial_to_replicate_matches_full_matmul():
 
     import functools
 
-    @functools.partial(compat_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(None, "mp"), P("mp", None)),
                        out_specs=P("mp"))
     def partial_mm(xl, wl):
@@ -269,7 +268,7 @@ def test_partial_to_shard_reduce_scatter():
 
     import functools
 
-    @functools.partial(compat_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(None, "mp"), P("mp", None)),
                        out_specs=P("mp"))
     def partial_mm(xl, wl):

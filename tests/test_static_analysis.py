@@ -1166,7 +1166,7 @@ R008_BAD = """\
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def body(x, w):
@@ -1226,7 +1226,7 @@ def test_r008_spec_tuple_concat_and_unknown_specs_skipped(tmp_path):
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def body(params, pools, x, w):

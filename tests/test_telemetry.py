@@ -71,9 +71,14 @@ def test_cost_model_uses_shared_flops():
 def test_peak_flops_table():
     assert flops.peak_flops("TPU v5 lite") == 197e12
     assert flops.peak_flops("TPU v4") == 275e12
-    assert flops.peak_flops("cpu") == 2e12
+    # a device the table does not hold is an error, never a default
+    # peak: there is no CPU row, so no MFU is ever computed on a CPU
+    for unknown in ("cpu", "no such device", "", None):
+        with pytest.raises(ValueError):
+            flops.peak_flops(unknown)
     assert flops.mfu(1000.0, 1e9, peak=2e12) == pytest.approx(0.5)
-    assert flops.mfu(1000.0, 1e9, device_kind="cpu") == pytest.approx(0.5)
+    with pytest.raises(ValueError):
+        flops.mfu(1000.0, 1e9, device_kind="cpu")
 
 
 # ------------------------------------------------------------- StepTimeline

@@ -113,3 +113,42 @@ def test_apply_pod_fills_args_and_worker_env():
                         "--master", "me:1234", "train.py"])
     apply_tpu_pod(args2, pod)
     assert (args2.nnodes, args2.rank, args2.master) == ("3", 0, "me:1234")
+
+
+# ------------------------------------------ one process for each chip
+
+def test_nproc_per_node_refused_where_workers_would_share_chips():
+    """A chip belongs to one process: on a host with TPU chips the
+    launcher refuses `--nproc_per_node > 1` (with the reason) unless the
+    workers are pinned off the chips; without chips, or with one worker,
+    it has nothing to say."""
+    from paddle_tpu.distributed.launch.main import check_nproc_for_chips
+    for env in ({}, {"JAX_PLATFORMS": "tpu"}, {"JAX_PLATFORMS": "tpu,cpu"}):
+        with pytest.raises(SystemExit, match="belongs to one process"):
+            check_nproc_for_chips(2, environ=env, chips=4)
+    check_nproc_for_chips(2, environ={"JAX_PLATFORMS": "cpu"}, chips=4)
+    check_nproc_for_chips(1, environ={}, chips=4)
+    check_nproc_for_chips(8, environ={}, chips=0)
+    # the default reads this host: the CPU sandbox has no chip
+    check_nproc_for_chips(2, environ={})
+
+
+def test_importing_package_and_launcher_initialises_no_backend():
+    """`import paddle_tpu` and the launcher module must not touch a
+    device: a parent that has initialised a backend HOLDS the chip, and
+    the worker it then starts fails or hangs.  Checked in a fresh
+    interpreter (this one initialised the CPU backend long ago)."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import paddle_tpu, paddle_tpu.distributed.launch.main\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    # one ~4 s interpreter, bounded: no in-process check can show that an
+    # import initialises nothing once a backend exists
+    out = subprocess.run(  # graft-lint: disable=R010  (see above)
+        [sys.executable, "-c", code], cwd=repo, env=env,
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-1500:]

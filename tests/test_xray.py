@@ -114,7 +114,8 @@ def test_warmed_engine_ledger_mfu_coverage_and_dump(model, capsys):
     """THE acceptance core on a fast 3-program grid: after warmup +
     traffic with sampling at interval 1, every warmed program appears
     in the ledger (and `dump --xray`) with dispatches, sampled device
-    seconds, cost-analysis FLOPs and a positive MFU; the coverage
+    seconds, cost-analysis FLOPs and achieved FLOP/s (no MFU: this is a
+    CPU, which has no peak-table row); the coverage
     table reports the dense (non-Pallas) status of every program on
     this CPU build; sampling triggered ZERO extra compiles (the
     warmup-grid pin extended); and the engine's health flips ready."""
@@ -157,7 +158,9 @@ def test_warmed_engine_ledger_mfu_coverage_and_dump(model, capsys):
         assert p["sampled_device_s"] > 0, name
         assert p["flops_per_dispatch"] > 0, name
         assert p["bytes_per_dispatch"] > 0, name
-        assert p["mfu"] > 0, name
+        # achieved FLOP/s is a count over a clock; MFU needs the chip's
+        # peak, and a CPU has no row in the table: absent, not made up
+        assert p["mfu"] is None, name
         assert p["achieved_gflops_per_s"] > 0, name
     # fractions are a distribution over the estimated device time
     fracs = [p["device_time_frac"] for p in rep["programs"]
@@ -375,9 +378,10 @@ def test_composition_spec_quant_tp2_chunked_ledger_pinned(model):
         assert sum(p["dispatches"] for p in cont) \
             == eng.prefill_chunks_total + len(eng.pad_ladder)
         assert info["programs"] == len(tp)
-        # sampled MFU present on the hot programs
+        # the hot programs were sampled (MFU itself is absent on CPU)
         hot = max(spec, key=lambda p: p["dispatches"])
-        assert hot["samples"] > 0 and hot["mfu"] and hot["mfu"] > 0
+        assert hot["samples"] > 0 and hot["achieved_gflops_per_s"] > 0
+        assert hot["mfu"] is None
         # both ROADMAP 5b suspects now run the paged Pallas kernels
         # (ISSUE 18): no custom call on this CPU build (interpret mode
         # is traced XLA), but the trace-time claims channel flips the
