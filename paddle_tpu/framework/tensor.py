@@ -169,6 +169,7 @@ class Tensor:
             self._accum_node = _engine.GradAccumulationNode(self)
         return self._accum_node
 
+    @jax.named_scope("backward")
     def backward(self, grad_tensor=None, retain_graph: bool = False):
         """Run the autograd engine from this tensor.
 

@@ -161,6 +161,7 @@ from ..observability import xray as _xray
 from ..observability import flight_recorder as _flight
 from ..observability import metrics as _metrics
 from ..observability import quantiles as _quantiles
+from ..observability import span as _span
 from . import quant as _squant
 from .prefix_cache import PrefixCache
 
@@ -374,12 +375,10 @@ class Request:
         # token stream listener (the SSE endpoint): harvest puts each
         # emitted token id, terminal states put None
         self._stream_q = None
-        # lifecycle trace timestamps (perf_counter; stamped only while
-        # FLAGS_enable_metrics is on — None means "not traced")
+        # lifecycle stamps (perf_counter), always on: four reads a
+        # request.  The sketches, `trace` records and /requests rows
+        # computed from them stay gated on FLAGS_enable_metrics
         self._t_enqueue: Optional[float] = None
-        # always-on twin of _t_enqueue for the fleet router's TTFT
-        # evidence (/healthz) — NOT part of the tracing surface
-        self._t_enqueue_ev: Optional[float] = None
         self._t_admit: Optional[float] = None
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
@@ -447,7 +446,7 @@ class _PendingTick:
     __slots__ = ("active", "k", "toks", "logits", "reqs", "t0",
                  "device_sampling", "overlapped", "step_no", "san",
                  "spec", "counts", "accepts", "new_lens", "new_last",
-                 "chunks", "kcap", "ph_sched", "ph_chunk", "ph_dispatch")
+                 "chunks", "kcap", "sched_s", "chunk_s", "dispatch_s")
 
     def __init__(self, active, k, toks, logits, reqs, t0,
                  device_sampling, step_no, san=None):
@@ -468,12 +467,12 @@ class _PendingTick:
         self.new_last = None
         self.chunks = 0     # prefill chunks run at this tick's boundary
         self.kcap = None    # per-slot emit caps of a spec dispatch
-        # per-tick phase breakdown (ISSUE 14): host seconds spent in
-        # boundary scheduling / chunk-prefill dispatch / tick dispatch,
-        # stamped at dispatch time; harvest/emit measured at harvest
-        self.ph_sched = 0.0
-        self.ph_chunk = 0.0
-        self.ph_dispatch = 0.0
+        # per-tick phase breakdown: seconds of the serve:schedule,
+        # serve:chunk_dispatch and serve:tick_dispatch spans of this
+        # tick's boundary; the harvest's two spans end at harvest
+        self.sched_s = 0.0
+        self.chunk_s = 0.0
+        self.dispatch_s = 0.0
 
 
 @jax.jit
@@ -989,6 +988,18 @@ class ServingEngine:
         return jax.shard_map(fn, mesh=self._tp_mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
 
+    def _program(self, name, fn, donate, *blame):
+        """Jit ``fn`` as the serving program ``name``.  The jitted
+        callable is named after the compile-tracker name with ``.`` as
+        ``_`` (``serving_tick``), so the trace's ``XLA Modules`` line,
+        X-ray's ledger and the compile tracker say the same word.
+        ``donate`` is dropped on the CPU, whose PJRT does not donate."""
+        fn.__name__ = fn.__qualname__ = name.replace(".", "_")
+        if jax.default_backend() == "cpu":
+            donate = ()
+        return _compile.wrap_first_call(
+            jax.jit(fn, donate_argnums=donate), name, self._blame(*blame))
+
     def _decode_program(self):
         if self._decode_fn is not None:
             return self._decode_fn
@@ -1009,10 +1020,8 @@ class ServingEngine:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                 logits, new_pools
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        self._decode_fn = _compile.wrap_first_call(
-            jax.jit(step, donate_argnums=donate), "serving.decode",
-            self._blame(("variant", "host_sampling_k1")))
+        self._decode_fn = self._program(
+            "serving.decode", step, (1,), ("variant", "host_sampling_k1"))
         return self._decode_fn
 
     def _tick_program(self, k: int):
@@ -1057,10 +1066,8 @@ class ServingEngine:
                 body, (pools, seq_lens, last_tok), jnp.arange(k))
             return jnp.transpose(toks), pools        # [B, k]
 
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        fn = self._tick_fns[k] = _compile.wrap_first_call(
-            jax.jit(tick, donate_argnums=donate), "serving.tick",
-            self._blame(("steps_per_tick", k)))
+        fn = self._tick_fns[k] = self._program(
+            "serving.tick", tick, (1,), ("steps_per_tick", k))
         return fn
 
     # ------------------------------------------------------ TP programs
@@ -1096,10 +1103,8 @@ class ServingEngine:
         body = self._shard_tp(
             tick, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 9,
             (_P(), _tp.pool_spec()))
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        return _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.tick",
-            self._blame(("steps_per_tick", k)))
+        return self._program(
+            "serving.tick", body, (1,), ("steps_per_tick", k))
 
     def _build_tp_decode(self):
         from jax.sharding import PartitionSpec as _P
@@ -1117,10 +1122,8 @@ class ServingEngine:
         body = self._shard_tp(
             step, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 3,
             (_P(), _P(), _tp.pool_spec()))
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        return _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.decode",
-            self._blame(("variant", "host_sampling_k1")))
+        return self._program(
+            "serving.decode", body, (1,), ("variant", "host_sampling_k1"))
 
     def _prefill_program(self, L_pad: int):
         fn = self._prefill_fns.get(L_pad)
@@ -1155,10 +1158,8 @@ class ServingEngine:
             body, donate = prefill_spec, (2, 3)
         else:
             body, donate = prefill, (1,)
-        donate = donate if jax.default_backend() != "cpu" else ()
-        fn = self._prefill_fns[L_pad] = _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.prefill",
-            self._blame(("L_pad", L_pad)))
+        fn = self._prefill_fns[L_pad] = self._program(
+            "serving.prefill", body, donate, ("L_pad", L_pad))
         return fn
 
     def _draft_prompt_write(self, dpools, table_row, prompt, start=None):
@@ -1215,10 +1216,8 @@ class ServingEngine:
                 (self._tp_specs, _tp.pool_spec(), _P(), _P(), _P()),
                 (_P(), _tp.pool_spec()))
             donate = (1,)
-        donate = donate if jax.default_backend() != "cpu" else ()
-        return _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.prefill",
-            self._blame(("L_pad", L_pad)))
+        return self._program(
+            "serving.prefill", body, donate, ("L_pad", L_pad))
 
     def _prefill_cont_program(self, L_pad: int):
         """Suffix prefill for a prefix-cache hit: the first ``start``
@@ -1266,10 +1265,8 @@ class ServingEngine:
                     cont, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 4,
                     (_P(), _tp.pool_spec()))
                 donate = (1,)
-            donate = donate if jax.default_backend() != "cpu" else ()
-            fn = self._prefill_cont_fns[L_pad] = _compile.wrap_first_call(
-                jax.jit(body, donate_argnums=donate),
-                "serving.prefill_cont", self._blame(("L_pad", L_pad)))
+            fn = self._prefill_cont_fns[L_pad] = self._program(
+                "serving.prefill_cont", body, donate, ("L_pad", L_pad))
             return fn
         from ..framework.dygraph import no_grad
 
@@ -1300,10 +1297,8 @@ class ServingEngine:
             body, donate = cont_spec, (2, 3)
         else:
             body, donate = cont, (1,)
-        donate = donate if jax.default_backend() != "cpu" else ()
-        fn = self._prefill_cont_fns[L_pad] = _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.prefill_cont",
-            self._blame(("L_pad", L_pad)))
+        fn = self._prefill_cont_fns[L_pad] = self._program(
+            "serving.prefill_cont", body, donate, ("L_pad", L_pad))
         return fn
 
     def _cow_program(self):
@@ -1339,10 +1334,7 @@ class ServingEngine:
             else:
                 body = self._shard_tp(body, (_tp.pool_spec(), _P(), _P()),
                                       _tp.pool_spec())
-        donate = donate if jax.default_backend() != "cpu" else ()
-        self._cow_fn = _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.cow",
-            self._blame())
+        self._cow_fn = self._program("serving.cow", body, donate)
         return self._cow_fn
 
     def _spec_program(self, k: int):
@@ -1371,10 +1363,9 @@ class ServingEngine:
                 (_P(),) * 5 + (_tp.pool_spec(), _P()))
         else:
             body = _spec.build_spec_tick(self, k)
-        donate = (2, 3) if jax.default_backend() != "cpu" else ()
-        fn = self._spec_fns[k] = _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.spec_tick",
-            self._blame(("spec_k", k), ("draft", "model")))
+        fn = self._spec_fns[k] = self._program(
+            "serving.spec_tick", body, (2, 3),
+            ("spec_k", k), ("draft", "model"))
         return fn
 
     def _spec_hd_program(self, k: int):
@@ -1398,10 +1389,8 @@ class ServingEngine:
                 (_P(),) * 5 + (_tp.pool_spec(),))
         else:
             body = _spec.build_hostdraft_tick(self, k)
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        fn = self._spec_hd_fns[k] = _compile.wrap_first_call(
-            jax.jit(body, donate_argnums=donate), "serving.spec_tick",
-            self._blame(("spec_k", k), ("draft", "ngram")))
+        fn = self._spec_hd_fns[k] = self._program(
+            "serving.spec_tick", body, (1,), ("spec_k", k), ("draft", "ngram"))
         return fn
 
     # -------------------------------------------------------------- warmup
@@ -1694,14 +1683,7 @@ class ServingEngine:
                 f"request needs {worst} blocks worst-case but the pool "
                 f"has {self.num_blocks}; raise num_blocks or lower "
                 "max_new_tokens")
-        # two enqueue stamps, deliberately separate: `_t_enqueue` stays
-        # metrics-gated (tracing off really does zero TRACING work —
-        # pinned), while `_t_enqueue_ev` is the always-on router
-        # evidence the /healthz TTFT predictor reads even on engines
-        # running with metrics disabled
-        if traced:
-            req._t_enqueue = time.perf_counter()
-        req._t_enqueue_ev = time.perf_counter()
+        req._t_enqueue = time.perf_counter()
         self.waiting.append(req)
         self._update_pressure()
         return req
@@ -2052,7 +2034,12 @@ class ServingEngine:
         # admission starts NOW: everything before this point was queue
         # wait (incl. pool-exhausted deferrals — the tail /metrics must
         # surface under overload)
-        t_admit = time.perf_counter() if _metrics.enabled() else None
+        t_admit = time.perf_counter()
+        # the launch of the prompt's first program: the whole prefill
+        # (legacy mode), or the blocks and the copy-on-write of a shared
+        # one (chunked mode; the chunks follow as serve:chunk_dispatch)
+        sp = _span("serve:prefill_dispatch", rid=req.trace_id or req.rid,
+                   prompt_tokens=L).begin()
         slot = self.free_slots.popleft()
         blocks = [self._alloc_block() for _ in range(need_now)]
         table_row = np.zeros((self.nb_per_seq,), np.int32)
@@ -2065,9 +2052,12 @@ class ServingEngine:
         if chunked:
             # chunked admission: the prompt is absorbed between decode
             # ticks by the per-tick scheduler, not here
-            return self._begin_chunked(req, slot, table_row, chain,
-                                       split_col, cow_src, cached_len,
-                                       t_admit)
+            try:
+                return self._begin_chunked(req, slot, table_row, chain,
+                                           split_col, cow_src, cached_len,
+                                           t_admit)
+            finally:
+                sp.end()
         self.tables[slot, :] = table_row
 
         try:
@@ -2152,6 +2142,8 @@ class ServingEngine:
             except Exception:   # exotic exception types without a dict
                 pass
             raise
+        finally:
+            sp.end()
         if cow_src is not None:
             self._release_block(cow_src)   # copy dispatched; pin over
         if not chain:
@@ -2197,36 +2189,27 @@ class ServingEngine:
         L = len(req.prompt_ids)
         _M_ADMISSIONS.inc()
         first = req._sample(np.asarray(row))
-        if t_admit is not None:
-            # np.asarray(row) above was the host sync: the first token
-            # really exists now, so this is TTFT, not enqueue time
-            t_first = time.perf_counter()
-            req._t_admit, req._t_first = t_admit, t_first
-            req._t_last = t_first
-            if req._t_enqueue is not None:
-                qwait = t_admit - req._t_enqueue
-                ttft = t_first - req._t_enqueue
-                _M_QWAIT.observe(qwait)
-                _M_TTFT.observe(ttft)
-                slo = _flags.get_flag("serving_ttft_slo_ms")
-                if slo > 0 and ttft * 1e3 > slo:
-                    _M_SLO.inc(metric="ttft")
+        # np.asarray(row) above was the host sync: the first token
+        # really exists now, so this is TTFT, not enqueue time
+        t_first = time.perf_counter()
+        req._t_admit, req._t_first = t_admit, t_first
+        req._t_last = t_first
+        ttft = t_first - req._t_enqueue
+        slo = _flags.get_flag("serving_ttft_slo_ms")
+        late = slo > 0 and ttft * 1e3 > slo
+        if _metrics.enabled():
+            _M_QWAIT.observe(t_admit - req._t_enqueue)
+            _M_TTFT.observe(ttft)
+            if late:
+                _M_SLO.inc(metric="ttft")
         # router evidence (always on, unlike the metrics-gated sketches
         # above): the /healthz TTFT predictor needs admission rate and
-        # recent TTFTs even on engines running with metrics disabled
-        t_now = req._t_first if req._t_first is not None \
-            else time.perf_counter()
-        self._admit_times.append(t_now)
-        t_enq = getattr(req, "_t_enqueue_ev", None)
-        if t_enq is not None:
-            ttft_ev = t_now - t_enq
-            self._ttft_recent.append(ttft_ev)
-            # always-on TTFT-SLO violation tally: the fleet burn-rate
-            # monitor's "bad event" input (the metrics-gated twin above
-            # feeds the scrape counter)
-            slo_ev = _flags.get_flag("serving_ttft_slo_ms")
-            if slo_ev > 0 and ttft_ev * 1e3 > slo_ev:
-                self._ev_slo_viol += 1
+        # recent TTFTs even on engines running with metrics disabled,
+        # and the fleet burn-rate monitor its tally of SLO violations
+        self._admit_times.append(t_first)
+        self._ttft_recent.append(ttft)
+        if late:
+            self._ev_slo_viol += 1
         req.output_ids.append(first)
         req._stream_push(first)
         req.slot = slot
@@ -2279,10 +2262,7 @@ class ServingEngine:
             self._ev_finished += 1
             self._ev_finished_tokens += len(req.output_ids)
             req._stream_push(None)      # close the SSE token stream
-            # _t_first may lag _t_enqueue if the metrics gate flipped
-            # between enqueue and admission; trace only complete timelines
-            if _metrics.enabled() and req._t_enqueue is not None \
-                    and req._t_first is not None:
+            if _metrics.enabled():
                 self._finish_trace(req)
 
     def _finish_trace(self, req: Request) -> None:
@@ -2573,43 +2553,41 @@ class ServingEngine:
         L_pad = self._pad_bucket(n)
         suffix = np.zeros((1, L_pad), np.int32)
         suffix[0, :n] = req.prompt_ids[off:off + n]
-        t_c0 = time.perf_counter() if _metrics.enabled() else None
-        try:
-            with self._params_for_call() as param_vals:
-                dpref = ((self._draft_vals(), self.pools, self.dpools)
-                         if self.spec_model else (self.pools,))
-                # private row copy: same R002 aliasing contract as the
-                # monolithic prefill's table-row argument
-                out = self._dispatch_call(
-                    "serving.prefill.dispatch",
-                    lambda: self._prefill_cont_program(L_pad)(
-                        param_vals, *dpref,
-                        jnp.asarray(req._chunk_row[None, :].copy()),
-                        jnp.asarray(suffix), jnp.int32(n),
-                        jnp.int32(off)))
-            if self.spec_model:
-                row, self.pools, self.dpools = out
-            else:
-                row, self.pools = out
-            if req._chunk_off + n >= L:
-                # last chunk: host-sync + NaN screen before the shadow
-                # row installs and the prefix registers (same contract
-                # as the monolithic path's _screen_row placement)
-                row = self._screen_row(row, slot, req)
-        except BaseException as e:
-            self._abort_prefill(req)
-            _M_REJECTIONS.inc(reason="error")
+        # the chunk's host side (async enqueue; the LAST chunk host-syncs
+        # its logits row inside): the boundary's chunk-prefill phase
+        with _span("serve:chunk_dispatch", rid=req.trace_id or req.rid,
+                   q_tokens=n, kv_tokens=off + n) as sp:
             try:
-                e._serving_req = req
-            except Exception:
-                pass
-            raise
-        if t_c0 is not None:
-            # host-side chunk dispatch time (async enqueue; a sampled
-            # chunk program blocks inside the call) — the boundary's
-            # chunk-prefill phase in the tick record
-            # graft-lint: disable=R006
-            self._chunk_s_this_boundary += time.perf_counter() - t_c0
+                with self._params_for_call() as param_vals:
+                    dpref = ((self._draft_vals(), self.pools, self.dpools)
+                             if self.spec_model else (self.pools,))
+                    # private row copy: same R002 aliasing contract as the
+                    # monolithic prefill's table-row argument
+                    out = self._dispatch_call(
+                        "serving.prefill.dispatch",
+                        lambda: self._prefill_cont_program(L_pad)(
+                            param_vals, *dpref,
+                            jnp.asarray(req._chunk_row[None, :].copy()),
+                            jnp.asarray(suffix), jnp.int32(n),
+                            jnp.int32(off)))
+                if self.spec_model:
+                    row, self.pools, self.dpools = out
+                else:
+                    row, self.pools = out
+                if req._chunk_off + n >= L:
+                    # last chunk: host-sync + NaN screen before the shadow
+                    # row installs and the prefix registers (same contract
+                    # as the monolithic path's _screen_row placement)
+                    row = self._screen_row(row, slot, req)
+            except BaseException as e:
+                self._abort_prefill(req)
+                _M_REJECTIONS.inc(reason="error")
+                try:
+                    e._serving_req = req
+                except Exception:
+                    pass
+                raise
+        self._chunk_s_this_boundary += sp.seconds
         req._chunk_off = off + n
         req._prefill_chunks += 1
         self.prefill_chunks_total += 1
@@ -2728,23 +2706,37 @@ class ServingEngine:
         dispatch means the returned `_PendingTick.toks` is a device
         handle nothing has blocked on; host seq_lens/tok_pos advance
         NOW so a second dispatch sees the in-flight state."""
-        timed = _metrics.enabled()
-        ph_sched = ph_chunk = 0.0
+        sched_s = chunk_s = 0.0
         if boundary:
-            t_b0 = time.perf_counter() if timed else 0.0
             self._chunk_s_this_boundary = 0.0
-            self._boundary_schedule()
-            if timed:
-                # the boundary's host phases (ISSUE 14): chunk-prefill
-                # dispatch time accumulated by _prefill_chunk_step,
-                # everything else (cancel/shed/admit/evict) = schedule
-                ph_chunk = self._chunk_s_this_boundary
-                ph_sched = max(
-                    0.0, time.perf_counter() - t_b0 - ph_chunk)
+            with _span("serve:schedule", waiting=len(self.waiting),
+                       running=self.B - len(self.free_slots)) as sp:
+                self._boundary_schedule()
+            # the boundary's host phases: the chunk dispatches nest
+            # inside serve:schedule (their seconds were summed by
+            # _prefill_chunk_step); the rest of it (cancel/shed/admit/
+            # evict) is the record's schedule phase
+            chunk_s = self._chunk_s_this_boundary
+            sched_s = max(0.0, sp.seconds - chunk_s)
         active = self._active_slots()
         if not active:
             return None
         t0 = time.perf_counter()
+        # host dispatch phase: enqueue cost by design (the compute lands
+        # in the harvest wait; a sampled program blocks inside the call)
+        with _span("serve:tick_dispatch", active=len(active),
+                   kv_tokens=int(self.seq_lens[active].sum())) as sp:
+            pend = self._launch_tick(active, t0, chain)
+            sp.set(steps=pend.k)
+        pend.dispatch_s = sp.seconds
+        pend.chunks = self._chunks_this_boundary
+        self._chunks_this_boundary = 0
+        pend.sched_s, pend.chunk_s = sched_s, chunk_s
+        return pend
+
+    def _launch_tick(self, active, t0, chain):
+        """Enqueue the tick program over ``active`` (the speculative
+        one where eligible) and advance the host's view of the slots."""
         device_sampling = _flags.get_flag("serving_device_sampling")
         # a chained dispatch continues its predecessor's kind (the
         # overlap gate matched them); at a boundary, spec eligibility is
@@ -2752,16 +2744,7 @@ class ServingEngine:
         use_spec = (bool(chain.spec) if chain is not None
                     else self._spec_eligible(active, device_sampling))
         if use_spec:
-            pend = self._dispatch_spec(active, t0, chain)
-            pend.chunks = self._chunks_this_boundary
-            self._chunks_this_boundary = 0
-            pend.ph_sched, pend.ph_chunk = ph_sched, ph_chunk
-            if timed:
-                # host dispatch phase: enqueue cost by design (the
-                # compute lands in the harvest wait; a sampled program
-                # blocks inside the call) — graft-lint: disable=R006
-                pend.ph_dispatch = time.perf_counter() - t0
-            return pend
+            return self._dispatch_spec(active, t0, chain)
         k = self._tick_size(active)
         # ensure a physical block exists for every position this tick
         # will write (all draws covered by the admission reservation)
@@ -2814,19 +2797,10 @@ class ServingEngine:
         for slot in active:
             self.seq_lens[slot] += k
             self.tok_pos[slot] += k
-        pend = _PendingTick(active=active, k=k, toks=toks, logits=logits,
+        return _PendingTick(active=active, k=k, toks=toks, logits=logits,
                             reqs=list(self.slot_req), t0=t0,
                             device_sampling=device_sampling,
                             step_no=self.steps, san=san)
-        pend.chunks = self._chunks_this_boundary
-        self._chunks_this_boundary = 0
-        pend.ph_sched, pend.ph_chunk = ph_sched, ph_chunk
-        if timed:
-            # host dispatch phase: enqueue cost by design (the compute
-            # lands in the harvest wait; a sampled program blocks
-            # inside the call) — graft-lint: disable=R006
-            pend.ph_dispatch = time.perf_counter() - t0
-        return pend
 
     def _spec_eligible(self, active, device_sampling) -> bool:
         """May this tick run draft/verify?  Needs the subsystem, on-
@@ -2989,9 +2963,10 @@ class ServingEngine:
         under overlap a request may have finished (EOS) while its next
         tick was already in flight; its overrun rows are discarded."""
         k = pend.k
-        timed = _metrics.enabled()
-        t_h0 = time.perf_counter() if timed else 0.0
-        with _flight.guard("serving.tick"):
+        # harvest-wait phase: the block below is where device compute
+        # not yet finished is actually waited for
+        with _span("serve:harvest_wait") as sp_wait, \
+                _flight.guard("serving.tick"):
             # first host block on the async result: a decode-execution
             # error (OOM, XlaRuntimeError) surfaces HERE, not at the
             # guarded dispatch — keep the post-mortem dump coverage.
@@ -2999,9 +2974,9 @@ class ServingEngine:
             # this block: a hung device program raises TickTimeout
             # instead of wedging the loop forever.
             toks = self._materialize(pend.toks)
-        # harvest-wait phase: the block above is where device compute
-        # not yet finished is actually waited for
-        t_wait_end = time.perf_counter() if timed else 0.0
+        # emit phase, to t_done: append, sample, stream (a harvest that
+        # raises abandons the span, which then records nothing)
+        sp_emit = _span("serve:emit").begin()
         # the program has materialized: every host buffer fed at dispatch
         # must still hash to its dispatch-time checksum (jaxsan; no-op
         # unless FLAGS_enable_jaxsan)
@@ -3119,23 +3094,26 @@ class ServingEngine:
         self._last_harvest_t = t_done
         dt = t_done - t_from
         harvested = self.tokens_out - toks_before
+        sp_emit.set(tokens=harvested)
+        sp_emit.end()
         if harvested > 0 and dt > 0:
             # always-on tick-level TPOT evidence for the fleet telescope
             # (one harvest gap imputed to the k tokens it yielded) —
             # deliberately NOT per-request timing, so the "metrics off
             # = zero per-request tracing work" pin stays intact
             self._ev_tpot.add(dt / max(k, 1), weight=harvested)
-        if _metrics.enabled():
-            # per-token inter-token latency (TPOT): tokens arrive k at a
-            # time, so each of this harvest's tokens is imputed an equal
-            # share of the gap since the request's previous token
-            tpot_slo = _flags.get_flag("serving_tpot_slo_ms")
-            for req, n_before in harvested_by:
-                n_new = len(req.output_ids) - n_before
-                if n_new <= 0 or req._t_last is None:
-                    continue
-                gap = (t_done - req._t_last) / n_new
-                req._t_last = t_done
+        # per-token inter-token latency (TPOT): tokens arrive k at a
+        # time, so each of this harvest's tokens is imputed an equal
+        # share of the gap since the request's previous token
+        sketch = _metrics.enabled()
+        tpot_slo = _flags.get_flag("serving_tpot_slo_ms") if sketch else 0
+        for req, n_before in harvested_by:
+            n_new = len(req.output_ids) - n_before
+            if n_new <= 0:
+                continue
+            gap = (t_done - req._t_last) / n_new
+            req._t_last = t_done
+            if sketch:
                 _M_TPOT.observe(gap, weight=n_new)
                 if tpot_slo > 0 and gap * 1e3 > tpot_slo:
                     _M_SLO.inc(n_new, metric="tpot")
@@ -3151,16 +3129,12 @@ class ServingEngine:
         if _metrics.enabled():
             # the flight ring keeps the last-K ticks, so a post-mortem
             # dump of a wedged/crashed engine shows what was in flight
-            # per-tick phase breakdown (ISSUE 14): dispatch-time host
-            # phases stamped on the pend + the harvest wait (device) /
-            # emit (host detokenize+stream) split measured here.  The
-            # phases need not sum to wall_s: an overlapped tick's wall
-            # clock starts at the previous harvest, and device compute
-            # overlaps the host phases by design.
-            # `timed` is the gate state at HARVEST ENTRY: a mid-tick
-            # flag flip must not difference against zero stamps
-            ph_wait = (t_wait_end - t_h0) if timed else 0.0
-            ph_emit = (t_done - t_wait_end) if timed else 0.0
+            # per-tick phase breakdown: the seconds of this tick's
+            # serve:* spans, each phase timed once.  The phases need not
+            # sum to wall_s: an overlapped tick's wall clock starts at
+            # the previous harvest, and device compute overlaps the host
+            # phases by design.
+            wait_s, emit_s = sp_wait.seconds, sp_emit.seconds
             rec = {
                 "timeline": "serving", "step": pend.step_no,
                 "t_unix": round(time.time(), 6),
@@ -3170,15 +3144,15 @@ class ServingEngine:
                 "active": len(pend.active), "waiting": len(self.waiting),
                 "free_blocks": self._free_capacity(),
                 "phases": {
-                    "schedule_ms": round(pend.ph_sched * 1e3, 4),
-                    "chunk_prefill_ms": round(pend.ph_chunk * 1e3, 4),
-                    "dispatch_ms": round(pend.ph_dispatch * 1e3, 4),
-                    "harvest_wait_ms": round(ph_wait * 1e3, 4),
-                    "emit_ms": round(ph_emit * 1e3, 4),
-                    "host_ms": round((pend.ph_sched + pend.ph_chunk
-                                      + pend.ph_dispatch + ph_emit)
+                    "schedule_ms": round(pend.sched_s * 1e3, 4),
+                    "chunk_prefill_ms": round(pend.chunk_s * 1e3, 4),
+                    "dispatch_ms": round(pend.dispatch_s * 1e3, 4),
+                    "harvest_wait_ms": round(wait_s * 1e3, 4),
+                    "emit_ms": round(emit_s * 1e3, 4),
+                    "host_ms": round((pend.sched_s + pend.chunk_s
+                                      + pend.dispatch_s + emit_s)
                                      * 1e3, 4),
-                    "device_wait_ms": round(ph_wait * 1e3, 4)}}
+                    "device_wait_ms": round(wait_s * 1e3, 4)}}
             if pend.spec:
                 rec["spec"] = True
                 rec["spec_kind"] = self.spec_kind
@@ -3334,7 +3308,7 @@ class ServingEngine:
         # fold the accounting into the chained tick's record: these
         # chunks belong to ITS dispatch window, not the next boundary's
         nxt.chunks += self._chunks_this_boundary
-        nxt.ph_chunk += self._chunk_s_this_boundary
+        nxt.chunk_s += self._chunk_s_this_boundary
         self._chunks_this_boundary = 0
         self._chunk_s_this_boundary = 0.0
 
@@ -3429,7 +3403,9 @@ class ServingEngine:
                         or self._active_slots():
                     self._guarded_step()
                 else:
-                    time.sleep(idle_s)
+                    # an empty engine is not a slow one
+                    with _span("serve:idle"):
+                        time.sleep(idle_s)
         finally:
             if old_handler is not None:
                 try:

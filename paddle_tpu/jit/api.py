@@ -31,6 +31,7 @@ an eager fallback — wrap those regions out of the jit or keep them host-side.
 from __future__ import annotations
 
 import gc
+import threading
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -42,6 +43,7 @@ from ..framework import random as _random
 from ..framework.tensor import Tensor
 from ..observability import compile_tracker as _compile_tracker
 from ..observability import metrics as _metrics
+from ..observability import span as _span
 from ..ops import registry as _registry
 from . import sot as _sot
 
@@ -49,15 +51,80 @@ _M_JIT_TRACES = _metrics.counter(
     "jit.traces", "to_static capture builds (record + trace passes)")
 _M_JIT_COMPILE_S = _metrics.histogram(
     "jit.compile_seconds",
-    "capture cost per program, by stage label: stage=trace is the _build "
-    "pass (eager state-discovery run + jaxpr capture), stage=compile is "
-    "the first call (XLA compile + run)")
+    "capture cost per program, by stage label, the seconds of the "
+    "to_static:<stage> spans: stage=discover is the eager state-discovery "
+    "run, trace_lower is jaxpr tracing + lowering, compile is the cache "
+    "load or backend compile, first_run the first execution")
 _M_SOT_GUARD = _metrics.counter(
     "jit.sot_guards", "SOT guarded-dispatch outcomes (kind=hit|miss)")
 _M_GRAPH_BREAKS = _metrics.counter(
     "jit.graph_breaks", "signatures that fell back to eager execution")
 
 __all__ = ["to_static", "StaticFunction", "not_to_static", "ignore_module"]
+
+
+class _FirstCall:
+    """The first call of a captured program, split into the spans
+    ``to_static:trace_lower``, ``to_static:compile`` and
+    ``to_static:first_run`` without changing how the step runs: `jax.jit`
+    is lazy, so its first call traces, lowers, loads or compiles, and
+    runs, and jax reports the end of each stage on its monitoring
+    channel (`fun_name` = ``jit(<name>)``).  Each span ends where the
+    next begins, so with ``to_static:discover`` they add up to the first
+    call's wall time."""
+
+    _MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    _BACKEND = "/jax/core/compile/backend_compile_duration"
+    _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+    current = threading.local()     # .call: this thread's first call
+
+    def __init__(self, fn: str):
+        self.fn, self.module = fn, f"jit({fn})"
+        self.cache_hit = False
+        self.seconds = {}
+        self._stage = "trace_lower"
+        self._span = _span("to_static:trace_lower", fn=fn)
+
+    def __enter__(self):
+        # a captured function may call another one's first call
+        self._outer = getattr(_FirstCall.current, "call", None)
+        _FirstCall.current.call = self
+        self._span.begin()
+        return self
+
+    def __exit__(self, *exc):
+        _FirstCall.current.call = self._outer
+        self._close()
+        return False
+
+    def _close(self):
+        self.seconds[self._stage] = self._span.end()
+
+    def _advance(self, stage):
+        self._close()
+        self._stage = stage
+        self._span = _span("to_static:" + stage, fn=self.fn).begin()
+
+    @classmethod
+    def on_duration(cls, event, duration, fun_name=None, **kw):
+        call = getattr(cls.current, "call", None)
+        if call is None or fun_name != call.module:
+            return
+        if event == cls._MLIR and call._stage == "trace_lower":
+            call._advance("compile")
+        elif event == cls._BACKEND and call._stage == "compile":
+            call._span.set(cache_hit=call.cache_hit)
+            call._advance("first_run")
+
+    @classmethod
+    def on_event(cls, event, **kw):
+        call = getattr(cls.current, "call", None)
+        if call is not None and event == cls._CACHE_HIT:
+            call.cache_hit = True
+
+
+jax.monitoring.register_event_duration_secs_listener(_FirstCall.on_duration)
+jax.monitoring.register_event_listener(_FirstCall.on_event)
 
 
 def _is_tracer(v) -> bool:
@@ -313,10 +380,9 @@ class StaticFunction:
         return slots, changed, burned
 
     def _build(self, args, kwargs, sot=False):
-        import time as _time
-        _t_build0 = _time.perf_counter()
-        slots, changed, burned = self._discover_state(args, kwargs,
-                                                      sot_record=sot)
+        with _span("to_static:discover", fn=self.__name__) as discover:
+            slots, changed, burned = self._discover_state(args, kwargs,
+                                                          sot_record=sot)
         mutable_idx = [i for i, c in enumerate(changed) if c]
         readonly_idx = [i for i, c in enumerate(changed) if not c]
         spec: Dict[str, Any] = {}
@@ -387,14 +453,18 @@ class StaticFunction:
         # run and re-executes, which needs the input buffers intact.
         donate = (0,) if self._donate_state and not sot and \
             jax.default_backend() != "cpu" else ()
+        # the program is named after the user's function: the trace's
+        # `XLA Modules` line reads jit_<name>, not jit_functional
+        functional.__name__ = functional.__qualname__ = self.__name__
         jitted = jax.jit(functional, donate_argnums=donate)
         self._stats["signatures"] += 1
         _M_JIT_TRACES.inc(fn=self.__name__)
-        build_s = _time.perf_counter() - _t_build0
-        _M_JIT_COMPILE_S.observe(build_s, fn=self.__name__, stage="trace")
+        _M_JIT_COMPILE_S.observe(discover.seconds, fn=self.__name__,
+                                 stage="discover")
         return {"slots": slots, "mutable_idx": mutable_idx,
                 "readonly_idx": readonly_idx, "jitted": jitted,
-                "spec": spec, "fresh": True, "build_s": build_s,
+                "spec": spec, "fresh": True,
+                "discover_s": discover.seconds,
                 "burned": tuple(burned) if burned is not None else None}
 
     # errors that mean "this function cannot trace as one graph" (value-
@@ -530,6 +600,14 @@ class StaticFunction:
         return self._run_prog(prog, args, kwargs)
 
     def _run_prog(self, prog, args, kwargs):
+        if prog.get("fresh", False):
+            return self._execute(prog, args, kwargs,
+                                 _FirstCall(self.__name__))
+        # every later call: state gather, dispatch, state commit
+        with _span("to_static:call"):
+            return self._execute(prog, args, kwargs, None)
+
+    def _execute(self, prog, args, kwargs, first_call):
         slots = prog["slots"]
         spec = prog["spec"]
         # build arg value list + proto mapping (order by traversal)
@@ -553,14 +631,17 @@ class StaticFunction:
         # cleared only after a successful observe, so a first call that
         # raises (GuardMiss, trace fallback) still gets its compile-stage
         # sample on the retry
-        first_call = prog.get("fresh", False)
-        if first_call:
-            import time as _time
-            _t_exec0 = _time.perf_counter()
         try:
+            if first_call is None:
+                outs = prog["jitted"](mutable_vals, readonly_vals,
+                                      _random.next_key(), arg_vals)
+            else:
+                with first_call:
+                    outs = jax.block_until_ready(prog["jitted"](
+                        mutable_vals, readonly_vals, _random.next_key(),
+                        arg_vals))
             (out_vals, new_mutable, grad_outs, arg_grad_outs,
-             guard_vals) = prog["jitted"](
-                mutable_vals, readonly_vals, _random.next_key(), arg_vals)
+             guard_vals) = outs
         finally:
             for s, v in saved:
                 s.set(v)
@@ -568,16 +649,15 @@ class StaticFunction:
                 t = s.ref()
                 if t is not None:
                     t._grad = g
-        if first_call:
+        if first_call is not None:
             prog.pop("fresh", None)
-            exec_s = _time.perf_counter() - _t_exec0
-            _M_JIT_COMPILE_S.observe(exec_s, fn=self.__name__,
-                                     stage="compile")
+            for stage, sec in first_call.seconds.items():
+                _M_JIT_COMPILE_S.observe(sec, fn=self.__name__, stage=stage)
             # recompile blame (ISSUE 6): one event per built program,
-            # seconds = trace pass + XLA compile/first run
+            # seconds = the four capture stages together
             _compile_tracker.record_compile(
                 self.__name__, _blame_signature(prog.get("sig")),
-                prog.get("build_s", 0.0) + exec_s)
+                prog["discover_s"] + sum(first_call.seconds.values()))
         if prog.get("burned"):
             # guard check BEFORE any state commit: a miss discards this
             # run (inputs were not donated) and re-dispatches

@@ -22,6 +22,8 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+import jax
+
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import functional as F
@@ -306,6 +308,7 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
         from ..ops.linalg import matmul
         return matmul(h, self.gpt.wte.weight, transpose_y=True), new_caches
 
+    @jax.named_scope("forward")
     def compute_loss(self, input_ids, labels):
         logits = self(input_ids)
         loss = F.cross_entropy(

@@ -7,11 +7,14 @@ Parts (ISSUE 1 + ISSUE 2 tentpoles):
   with labels; ``snapshot()`` / ``export_json()`` for readout, flag-gated
   (``FLAGS_enable_metrics``) so disabled instruments cost one boolean
   check.
-* :func:`span` — user-labelled timing span.  Always observed into the
-  ``spans.seconds`` histogram; when a :class:`paddle_tpu.profiler.Profiler`
-  is recording, the span also lands on the host timeline (the existing
-  ``_HostTracer``), so spans show up in exported chrome traces next to
-  per-op dispatch events.
+* :func:`span` — THE span primitive of the program (the serve loop's
+  phases, ``to_static``'s capture stages).  Always on: it enters a
+  ``jax.profiler.TraceAnnotation`` for its duration, so while
+  ``jax.profiler`` is tracing the span lies on ``/host:CPU`` on the
+  device trace's clock, and keeps count / total / max seconds per name in
+  memory (:func:`span_totals`) for spans that end before any profiler
+  starts.  While a :class:`paddle_tpu.profiler.Profiler` is recording it
+  also lands on that profiler's host timeline (``_HostTracer``).
 * :mod:`.telemetry` — per-training-step :class:`~.telemetry.StepTimeline`
   records (wall/compile/comm split, compute/comm/host fractions,
   tokens/sec, MFU via the shared :mod:`.flops` helper).
@@ -44,8 +47,13 @@ Usage::
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+from ..profiler import profiler as _prof
 
 from . import metrics  # noqa: F401
 from . import descriptions  # noqa: F401
@@ -59,42 +67,65 @@ from .metrics import (  # noqa: F401
     counter, gauge, histogram, quantile, snapshot, reset, export_json,
 )
 
-__all__ = ["metrics", "harness", "span", "telemetry", "flight_recorder",
+__all__ = ["metrics", "harness", "span", "span_totals", "telemetry",
+           "flight_recorder",
            "flops", "quantiles", "compile_tracker", "xray", "chrome",
            "descriptions", "export", "http",
            "counter", "gauge", "histogram", "quantile", "snapshot",
            "reset", "export_json"]
 
-_SPAN_SECONDS = metrics.histogram(
-    "spans.seconds", "wall time of observability.span regions")
+# name -> [count, total seconds, max seconds]; one small list a name,
+# updated under the lock (spans end on the serve loop's thread, on the
+# caller's thread in to_static, and on handler threads in tests)
+_SPAN_TOTALS: dict = {}
+_SPAN_LOCK = threading.Lock()
 
 
 class span:
-    """Timing span: context manager (or begin()/end()) that records wall
-    time into the ``spans.seconds`` histogram (labelled by name) and, when
-    a Profiler is recording, onto the host chrome-trace timeline."""
+    """Timing span: context manager (or begin()/end()).  For its duration
+    it holds a ``jax.profiler.TraceAnnotation(name, **attrs)`` — one
+    TraceMe check when no profiler runs — and at its end it adds its wall
+    time to the per-name totals (:func:`span_totals`) and leaves it in
+    ``seconds``.  ``set(**attrs)`` adds attrs known only later
+    (``tokens`` emitted, ``cache_hit``) to the running annotation."""
 
-    __slots__ = ("name", "_t0")
+    __slots__ = ("name", "attrs", "seconds", "_t0", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **attrs):
         self.name = name
+        self.attrs = attrs
+        self.seconds = 0.0
         self._t0: Optional[float] = None
 
     def begin(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set(self, **attrs) -> None:
+        self._ann.set_metadata(**attrs)
 
     def end(self) -> Optional[float]:
         if self._t0 is None:
             return None
-        t0, self._t0 = self._t0, None
         t1 = time.perf_counter()
-        _SPAN_SECONDS.observe(t1 - t0, name=self.name)
-        from ..profiler import profiler as _prof
+        self._ann.__exit__(None, None, None)
+        t0, self._t0 = self._t0, None
+        dt = self.seconds = t1 - t0
+        with _SPAN_LOCK:
+            rec = _SPAN_TOTALS.get(self.name)
+            if rec is None:
+                _SPAN_TOTALS[self.name] = [1, dt, dt]
+            else:
+                rec[0] += 1
+                rec[1] += dt
+                if dt > rec[2]:
+                    rec[2] = dt
         tracer = _prof.active_tracer()
         if tracer is not None:
             tracer.add(self.name, t0, t1, category="span")
-        return t1 - t0
+        return dt
 
     def __enter__(self) -> "span":
         return self.begin()
@@ -102,6 +133,15 @@ class span:
     def __exit__(self, *exc) -> bool:
         self.end()
         return False
+
+
+def span_totals(prefix: str = "") -> dict:
+    """``{name: {"count", "total_s", "max_s"}}`` of every span ended so
+    far in this process (names starting with ``prefix``)."""
+    with _SPAN_LOCK:
+        return {n: {"count": c, "total_s": t, "max_s": m}
+                for n, (c, t, m) in _SPAN_TOTALS.items()
+                if n.startswith(prefix)}
 
 
 def __getattr__(name):

@@ -284,6 +284,7 @@ def flash_attention_fwd(q, k, v, causal=False, interpret=None,
             jax.ShapeDtypeStruct((B, nh, Sq, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(_seed_arr(seed), _bnsh(q), _bnsh(k), _bnsh(v), _mask_arr(kv_mask, B, Sk))
     return jnp.transpose(out, (0, 2, 1, 3)), lse
 
@@ -483,6 +484,7 @@ def _flash_bwd(causal, interpret, kv_mask_shape, rate, res, g,
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, Sq, hd), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(seed_arr, qb, kb, vb, ob, gb, lse, mask_arr)
 
     # dkv: grid ordered (bh, ki, qi) — q is the sequential axis
@@ -529,6 +531,7 @@ def _flash_bwd(causal, interpret, kv_mask_shape, rate, res, g,
             jax.ShapeDtypeStruct((B, nh, Sk, hd), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(seed_arr, qb, kb, vb, ob, gb, lse, mask_arr)
     if group > 1:
         # GQA: reduce per-q-head grads over each kv head's group

@@ -148,6 +148,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(block_tables, seq_lens, q, k_cache, v_cache)
 
 
@@ -420,6 +421,7 @@ def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, s, nh, hd), q.dtype),
         interpret=interpret,
+        name=_claim_name,
     )(block_tables, start_lens, q, k_cache, v_cache)
 
 

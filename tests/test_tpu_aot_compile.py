@@ -158,3 +158,99 @@ def test_graft_entry_compiles_for_v5e(v5e, monkeypatch):
     lowered = jax.jit(fwd).lower([spec(v) for v in vals], spec(ids))
     assert "tpu_custom_call" in lowered.as_text()     # flash, S=512
     lowered.compile()
+
+
+# ------------------------------------------------ names (ISSUE 26 §3)
+# The per-layer metrics find a kernel's events in the chip's trace by the
+# custom call's instruction name, and a program's launches by its module
+# name: both are read off the v5e programs here, so a refactor that drops
+# a name fails on the CPU and not in a chip run.
+
+_FLASH = "flash fwd+bwd causal nh16 hd128 S2048 bfloat16"
+KERNEL_CASES = {
+    "flash_fwd": _FLASH, "flash_bwd_dq": _FLASH, "flash_bwd_dkv": _FLASH,
+    "paged_decode": "paged_attention nh16 hd128 bfloat16",
+    "paged_chunk_prefill": "paged_chunk_attention s1024 nh16 hd128 bfloat16",
+    "paged_spec_verify": "paged_verify_attention k4 nh16 hd128 bfloat16",
+}
+
+
+def _custom_call_names(hlo_text: str) -> list:
+    return [ln.split(" = ")[0].split()[-1]
+            for ln in hlo_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_CASES))
+def test_custom_call_is_named_for_its_kernel(compiled, kernel):
+    names = _custom_call_names(
+        compiled[KERNEL_CASES[kernel]].result().as_text())
+    assert names and any(kernel in n for n in names), names
+    # ...and one kernel's name does not match another's pattern
+    others = [k for k in KERNEL_CASES if k != kernel and kernel in k]
+    assert not others
+
+
+@pytest.fixture(scope="module")
+def serve_modules(v5e):
+    """The serving programs of a one-layer engine with the 1.3B head
+    shape, lowered for the v5e with Mosaic kernels: `{compile-tracker
+    name: lowered text}`."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    saved = pallas_common.interpret_default
+    pallas_common.interpret_default = lambda: False
+    try:
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=256, hidden_size=128, num_layers=1, num_heads=1,
+            max_seq_len=256, intermediate_size=256))
+        model.eval()
+        model.bfloat16()
+        eng = ServingEngine(model, max_batch=8, max_context=256,
+                            block_size=64, steps_per_tick=4,
+                            prefill_chunk=128)
+        sh = SingleDeviceSharding(v5e)
+        spec = lambda tree: jax.tree_util.tree_map(    # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=sh), tree)
+        B, nb = eng.B, eng.nb_per_seq
+        i32 = lambda *s: np.zeros(s, np.int32)         # noqa: E731
+        sched = (i32(B, nb), i32(B), i32(B))
+        samp = (np.zeros((B,), np.bool_), np.ones((B,), np.float32), i32(B),
+                np.ones((B,), np.float32), np.zeros((B,), np.uint32), i32(B))
+        out = {}
+        with eng._params_for_call() as params:
+            for fn, args in (
+                    (eng._tick_program(4),
+                     (params, eng.pools) + sched + samp),
+                    (eng._decode_program(), (params, eng.pools) + sched),
+                    (eng._prefill_cont_program(128),
+                     (params, eng.pools, i32(1, nb), i32(1, 128),
+                      np.int32(1), np.int32(0))),
+                    (eng._prefill_program(128),
+                     (params, eng.pools, i32(1, nb), i32(1, 128),
+                      np.int32(1))),
+                    (eng._cow_program(),
+                     (eng.pools, np.int32(0), np.int32(0)))):
+                out[fn._compile_name] = fn.__wrapped__.lower(
+                    *spec(args)).as_text()
+        return out
+    finally:
+        pallas_common.interpret_default = saved
+
+
+@pytest.mark.parametrize("name, kernel", [
+    ("serving.tick", "paged_decode"), ("serving.decode", "paged_decode"),
+    ("serving.prefill_cont", "paged_chunk_prefill"),
+    ("serving.prefill", None), ("serving.cow", None)])
+def test_serve_program_is_named_for_its_compile_tracker_entry(
+        serve_modules, name, kernel):
+    text = serve_modules[name]
+    assert f"module @jit_{name.replace('.', '_')} " in text, text[:200]
+    if kernel is not None:
+        assert "tpu_custom_call" in text and kernel in text
