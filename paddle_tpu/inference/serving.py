@@ -1311,12 +1311,11 @@ class ServingEngine:
         if self._cow_fn is not None:
             return self._cow_fn
 
+        from ..ops.pallas_paged import paged_copy_block
+
         def cow(pools, src, dst):
-            out = []
-            for kk, vv in pools:
-                out.append((kk.at[:, dst].set(kk[:, src]),
-                            vv.at[:, dst].set(vv[:, src])))
-            return out
+            return [(paged_copy_block(kk, src, dst),
+                     paged_copy_block(vv, src, dst)) for kk, vv in pools]
 
         if self.spec_model:
             def body(pools, dpools, src, dst):

@@ -154,12 +154,10 @@ class PagedKVCache:
         new = PagedKVCache.__new__(PagedKVCache)
         new.bs, new.tables = self.bs, self.tables
         if s == 1:
-            new.k, new.v = pallas_paged.paged_write_token(
-                self.k, self.v, self.tables, self.seq_lens,
-                k[:, 0], v[:, 0])
+            out, new.k, new.v = pallas_paged.paged_decode_step(
+                q[:, 0], k[:, 0], v[:, 0], self.k, self.v, self.tables,
+                self.seq_lens)
             new.seq_lens = self.seq_lens + 1
-            out = pallas_paged.paged_attention(
-                q[:, 0], new.k, new.v, self.tables, new.seq_lens)
             return new, out[:, None]
         if not isinstance(self.seq_lens, jax.core.Tracer):
             # prefill writes into each sequence's FIRST blocks and attends
@@ -188,7 +186,9 @@ class PagedChunkView(PagedKVCache):
     only its SUFFIX) and chunked prefill (ISSUE 11: every arriving
     prompt is absorbed as bounded chunks between decode ticks) run on —
     `update_and_attend` writes token j of the chunk at absolute
-    position ``seq_lens + j`` through the block table and runs dense
+    position ``seq_lens + j`` through the block table (an in-place
+    read-modify-write of the blocks the chunk touches, not a scatter:
+    XLA:TPU would re-lay-out the whole pool around one) and runs dense
     attention of the chunk queries against the table's linearized
     blocks with an offset causal mask.  Positions beyond the table's
     capacity route their writes to the reserved pad block 0 (same
@@ -210,8 +210,10 @@ class PagedChunkView(PagedKVCache):
         return new, self._attend_chunk(q, new, pos)
 
     def _write_chunk(self, q, k, v):
-        """Scatter the chunk through the block table at absolute
-        positions ``seq_lens + j``; returns (advanced view, pos[B, s])."""
+        """Write the chunk through the block table at absolute positions
+        ``seq_lens + j`` (`pallas_paged.paged_write_chunk`: in place, a
+        block at a time); returns (advanced view, pos[B, s])."""
+        from ..ops import pallas_paged
         nh = q.shape[2]
         s = q.shape[1]
         if k.shape[2] != nh:
@@ -222,23 +224,13 @@ class PagedChunkView(PagedKVCache):
             rep = nh // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        nb = self.tables.shape[1]
         start = self.seq_lens                          # [B] cached tokens
         pos = start[:, None] + jnp.arange(s, dtype=start.dtype)  # [B, s]
-        cols = pos // self.bs
-        blk = jnp.take_along_axis(self.tables,
-                                  jnp.clip(cols, 0, nb - 1), axis=1)
-        # positions past the table write the pad block (never a clipped
-        # read of the LAST column, which would corrupt a real block)
-        blk = jnp.where(cols < nb, blk, 0)
-        slot = (pos % self.bs).astype(jnp.int32)
         cls = type(self)
         new = cls.__new__(cls)
         new.bs, new.tables = self.bs, self.tables
-        new.k = self.k.at[:, blk, slot].set(
-            jnp.transpose(k.astype(self.k.dtype), (2, 0, 1, 3)))
-        new.v = self.v.at[:, blk, slot].set(
-            jnp.transpose(v.astype(self.v.dtype), (2, 0, 1, 3)))
+        new.k, new.v = pallas_paged.paged_write_chunk(
+            self.k, self.v, self.tables, start, k, v)
         new.seq_lens = self.seq_lens + s
         return new, pos
 
@@ -267,7 +259,7 @@ class PagedChunkKernelView(PagedChunkView):
     """`PagedChunkView` with the dense linearized-table attend replaced
     by the chunked paged-prefill Pallas kernel
     (`ops/pallas_paged.paged_chunk_attention`).  The write path — GQA
-    head repeat, table-routed scatter, pad-block overflow — is inherited
+    head repeat, table-routed block writes, pad-block overflow — is inherited
     unchanged, so the two views differ only in how the attend lowers.
     Selected by the serving engine when `FLAGS_serving_pallas_prefill`
     is on (snapshotted at engine init, never read under trace)."""

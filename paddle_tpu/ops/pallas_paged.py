@@ -36,20 +36,32 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_common
 
 __all__ = ["paged_attention", "paged_attention_reference", "BlockKVCache",
-           "paged_write_token", "paged_write_prefill",
+           "paged_decode_step", "paged_write_prefill",
+           "paged_write_chunk", "paged_copy_block",
            "paged_chunk_attention", "paged_chunk_attention_reference",
            "paged_verify_attention"]
 
 _NEG_INF = -1e30
 
 
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, bs, max_blocks, nh):
+def _decode_kernel(tables_ref, lens_ref, q_ref, *refs, scale, bs, max_blocks,
+                   nh, write):
     """One grid instance = ALL heads of one sequence against one physical
     block: grid (B, max_blocks), k/v blocks [nh, bs, hd].  Processing the
     whole head dim per instance cuts the sequential grid by nh× and makes
     each DMA nh× larger — the per-iteration launch overhead dominated the
-    per-head variant (round 3's kernel) at decode sizes."""
+    per-head variant (round 3's kernel) at decode sizes.
+
+    With `write` (`paged_decode_step`) the step's own k/v row rides in:
+    it is merged into the sequence's last live block as that block passes
+    through VMEM, attended from there, and the merged block leaves as an
+    output aliased onto the pool — the decode store costs one block of
+    write-back a sequence and no op of its own."""
+    if write:
+        (knew_ref, vnew_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+         m_scr, l_scr, acc_scr) = refs
+    else:
+        k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     blk = pl.program_id(1)
 
@@ -62,9 +74,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     seq_len = lens_ref[b]
     n_blocks = (seq_len + bs - 1) // bs
 
-    @pl.when(blk < n_blocks)
-    def _():
-        k = k_ref[:, :, :]                                # [nh, bs, hd]
+    def attend(k, v):                                     # [nh, bs, hd]
         # batched matvec as [nh, 1, hd] x [nh, bs, hd]: Mosaic's dot
         # lowering requires a non-empty lhs non-contracting dim set.  The
         # unit dim is inserted while the value is float32 and the cast to
@@ -81,7 +91,6 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])                   # [nh, bs]
         alpha = jnp.exp(m_prev - m_new)
-        v = v_ref[:, :, :]                                # [nh, bs, hd]
         pv = jax.lax.dot_general(
             p[:, None, :].astype(v.dtype), v,
             (((2,), (1,)), ((0,), (0,))),
@@ -91,11 +100,109 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.sum(p, axis=1)[:, None], l_scr.shape)
         m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
 
+    if not write:
+        @pl.when(blk < n_blocks)
+        def _():
+            attend(k_ref[:, :, :], v_ref[:, :, :])
+    else:
+        # the new token is position seq_len - 1 (seq_len counts it)
+        last = _write_col(seq_len, bs, max_blocks)
+
+        @pl.when(blk < last)
+        def _():
+            attend(k_ref[:, :, :], v_ref[:, :, :])
+
+        @pl.when(blk == last)
+        def _():
+            hit = jax.lax.broadcasted_iota(
+                jnp.int32, k_ref.shape, 1) == (seq_len - 1) % bs
+
+            def merged(new_ref, old_ref):
+                # through float32 for the same packed-shape-cast reason
+                new = new_ref[:, :].astype(jnp.float32)[:, None, :]
+                return jnp.where(hit, new, old_ref[:, :, :].astype(
+                    jnp.float32)).astype(old_ref.dtype)
+
+            k, v = merged(knew_ref, k_ref), merged(vnew_ref, v_ref)
+            ko_ref[:, :, :] = k
+            vo_ref[:, :, :] = v
+            attend(k, v)
+
     @pl.when(blk == max_blocks - 1)
     def _():
         l = l_scr[:, 0]                                   # [nh]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[:, :] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+
+
+def _write_col(seq_len, bs, max_blocks):
+    """Table column of the block that takes position seq_len - 1 (a
+    position past the table clamps to the last column, as a gather of the
+    table would)."""
+    return jnp.minimum((seq_len - 1) // bs, max_blocks - 1)
+
+
+def _decode_call(q, k_cache, v_cache, block_tables, seq_lens, interpret,
+                 new_rows=None):
+    """`paged_decode` as a pallas_call; with `new_rows` = (k_step, v_step)
+    the writing variant, whose pools come back updated."""
+    if interpret is None:
+        interpret = pallas_common.interpret_default()
+    pallas_common.claim("paged_decode", interpret)
+    return _decode_pallas(q, k_cache, v_cache, block_tables, seq_lens,
+                          new_rows, interpret=interpret)
+
+
+# jitted on its own so that a program of L layers traces the kernel and
+# lowers it through Mosaic once, and calls it L times
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decode_pallas(q, k_cache, v_cache, block_tables, seq_lens, new_rows, *,
+                   interpret):
+    B, nh, hd = q.shape
+    _, _, bs, _ = k_cache.shape
+    max_blocks = block_tables.shape[1]
+    write = new_rows is not None
+    kern = functools.partial(_decode_kernel, scale=1.0 / math.sqrt(hd),
+                             bs=bs, max_blocks=max_blocks, nh=nh,
+                             write=write)
+
+    def qmap(b, blk, tables, lens):
+        return (b, 0, 0)
+
+    def kvmap(b, blk, tables, lens):
+        return (0, tables[b, blk], 0, 0)
+
+    def wmap(b, blk, tables, lens):
+        return (0, tables[b, _write_col(lens[b], bs, max_blocks)], 0, 0)
+
+    row = pl.BlockSpec((None, nh, hd), qmap)
+    out_specs, out_shape = row, jax.ShapeDtypeStruct((B, nh, hd), q.dtype)
+    if write:
+        pool = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
+        out_specs = [row] + [pl.BlockSpec((nh, None, bs, hd), wmap)] * 2
+        out_shape = [out_shape, pool, pool]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, max_blocks),
+        in_specs=[row] * (3 if write else 1)
+        + [pl.BlockSpec((nh, None, bs, hd), kvmap)] * 2,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((nh, 128), jnp.float32),
+            pltpu.VMEM((nh, 128), jnp.float32),
+            pltpu.VMEM((nh, hd), jnp.float32),
+        ],
+    )
+    rows = tuple(x.astype(k_cache.dtype) for x in new_rows) if write else ()
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        # operands count the two scalar-prefetch ones: the pools are 5, 6
+        input_output_aliases={5: 1, 6: 2} if write else {},
+        interpret=interpret,
+        name="paged_decode",
+    )(block_tables, seq_lens, q, *rows, k_cache, v_cache)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
@@ -111,45 +218,37 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
     seq_lens:     [B] int32 current context length per sequence
     Returns [B, nh, hd].
     """
-    if interpret is None:
-        interpret = pallas_common.interpret_default()
-    pallas_common.claim("paged_decode", interpret)
-    B, nh, hd = q.shape
-    _, _, bs, _ = k_cache.shape
-    max_blocks = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    return _decode_call(q, k_cache, v_cache, block_tables, seq_lens,
+                        interpret)
 
-    kern = functools.partial(_decode_kernel, scale=scale, bs=bs,
-                             max_blocks=max_blocks, nh=nh)
 
-    def qmap(b, blk, tables, lens):
-        return (b, 0, 0)
+def paged_decode_step(q, k_step, v_step, k_pool, v_pool, block_tables,
+                      seq_lens, interpret=None):
+    """One decode step over a paged KV cache, store and attention in the
+    one kernel (the in-place decode store of the reference's
+    `fused_multi_transformer_op.cu.h:942-999`): each sequence's new k/v
+    row lands at position ``seq_lens[b]`` through its block table and its
+    query attends positions 0..seq_lens[b].
 
-    def kvmap(b, blk, tables, lens):
-        return (0, tables[b, blk], 0, 0)
+    q/k_step/v_step: [B, nh, hd]; k_pool/v_pool: [nh, num_blocks, bs, hd];
+    block_tables: [B, max_blocks] int32; seq_lens: [B] lengths BEFORE the
+    step.  Inactive slots (length 0 over a zero table row) write the pad
+    block 0 and attend it; their output is discarded upstream.  Returns
+    (out [B, nh, hd], k_pool, v_pool): the pools are aliased outputs, so a
+    donated pool — or a `lax.scan` carry — is updated where it lies, one
+    block of write-back a sequence.
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, max_blocks),
-        in_specs=[
-            pl.BlockSpec((None, nh, hd), qmap),
-            pl.BlockSpec((nh, None, bs, hd), kvmap),
-            pl.BlockSpec((nh, None, bs, hd), kvmap),
-        ],
-        out_specs=pl.BlockSpec((None, nh, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-        interpret=interpret,
-        name="paged_decode",
-    )(block_tables, seq_lens, q, k_cache, v_cache)
+    Why the kernel and not `pool.at[:, blk, off].set(...)` beside it:
+    XLA:TPU gives a scatter's operand the layout that makes the scattered
+    dims major while Mosaic pins the default one, so the scatter
+    re-laid-out the WHOLE pool before and after itself, every step
+    (`_put`); and 2 x B `dynamic_update_slice` rows a layer, the form that
+    needs no kernel, was no slower on the v5e (a 5.7-5.9 ms decode step of
+    the 1.3B serve cell against 6.1 here) but, unrolled 768 times a
+    program, took the cell's warm-up from 24 to 49 s (PERF.md §6,
+    PR 27)."""
+    return tuple(_decode_call(q, k_pool, v_pool, block_tables, seq_lens + 1,
+                              interpret, new_rows=(k_step, v_step)))
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables, seq_lens):
@@ -174,31 +273,36 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables, seq_lens):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def paged_write_token(k_pool, v_pool, tables, seq_lens, k_step, v_step):
-    """Traced single-token cache write (the in-place decode store of the
-    reference's `fused_multi_transformer_op.cu.h:942-999`, as a
-    functional XLA scatter so it can live inside a `lax.scan` carry).
+def _put(pool, update, blk):
+    """`update` ([nh, 1, bs, hd]) over physical block `blk` of `pool`, as
+    a `dynamic_update_slice`.
 
-    k_pool/v_pool: [nh, num_blocks, bs, hd]; tables: [B, max_blocks]
-    int32; seq_lens: [B] current lengths (write position); k_step/v_step:
-    [B, nh, hd].  Returns the updated pools."""
-    bs = k_pool.shape[2]
-    B = k_step.shape[0]
-    slot = seq_lens // bs                                   # [B]
-    off = seq_lens % bs                                     # [B]
-    blk = tables[jnp.arange(B), slot]                       # [B]
-    k_pool = k_pool.at[:, blk, off].set(
-        jnp.moveaxis(k_step, 0, 1).astype(k_pool.dtype))
-    v_pool = v_pool.at[:, blk, off].set(
-        jnp.moveaxis(v_step, 0, 1).astype(v_pool.dtype))
-    return k_pool, v_pool
+    Every traced write into a pool is this or `paged_decode_step`'s
+    aliased output, and none is an XLA scatter.  XLA:TPU gives a scatter's
+    operand the layout that makes the scattered dims major (`{3,0,2,1}`
+    for a scatter over blocks and rows), while the Mosaic kernels that
+    read the pool pin the default `{3,2,1,0}`: a program that scatters
+    into a pool and attends through it re-lays-out the WHOLE pool on each
+    side of every write — two copies of every pool a launch and one a
+    scan step, 70% of the 1.3B serve cell's device time (PERF.md §6,
+    PR 27).  A `dynamic_update_slice` has no layout preference, so a
+    donated pool is updated where it lies."""
+    return jax.lax.dynamic_update_slice(
+        pool, update.astype(pool.dtype), (0, blk, 0, 0))
 
 
+def _block_of(pool, blk):
+    """Physical block `blk` of `pool`, heads leading: [nh, 1, bs, hd]."""
+    nh, _, bs, hd = pool.shape
+    return jax.lax.dynamic_slice(pool, (0, blk, 0, 0), (nh, 1, bs, hd))
+
+
+@jax.jit
 def paged_write_prefill(k_pool, v_pool, tables, k, v):
     """Traced bulk prefill write from empty sequences: k/v [B, S, nh, hd]
-    scatter into each sequence's first ceil(S/bs) table blocks (one
-    scatter per pool, not per token).  The pad tail of the last block
-    stays zero and is masked by seq_lens at attend time."""
+    go into each sequence's first ceil(S/bs) table blocks, one whole-block
+    in-place update a block (`_put`).  The pad tail of the last block is
+    written as zeros and is masked by seq_lens at attend time."""
     bs = k_pool.shape[2]
     B, S, nh, hd = k.shape
     nb = (S + bs - 1) // bs
@@ -211,9 +315,68 @@ def paged_write_prefill(k_pool, v_pool, tables, k, v):
     # [B, nb*bs, nh, hd] -> [nh, B*nb, bs, hd]
     kb = jnp.moveaxis(k.reshape(B * nb, bs, nh, hd), 2, 0)
     vb = jnp.moveaxis(v.reshape(B * nb, bs, nh, hd), 2, 0)
-    k_pool = k_pool.at[:, blks].set(kb.astype(k_pool.dtype))
-    v_pool = v_pool.at[:, blks].set(vb.astype(v_pool.dtype))
-    return k_pool, v_pool
+
+    def put_block(i, pools):
+        kp, vp = pools
+        return (_put(kp, jax.lax.dynamic_slice_in_dim(kb, i, 1, 1), blks[i]),
+                _put(vp, jax.lax.dynamic_slice_in_dim(vb, i, 1, 1), blks[i]))
+
+    return jax.lax.fori_loop(0, B * nb, put_block, (k_pool, v_pool))
+
+
+@jax.jit
+def paged_write_chunk(k_pool, v_pool, tables, start_lens, k, v):
+    """Traced chunk write at an offset: token j of stream b's chunk
+    (k/v [B, s, nh, hd], heads already repeated to the pool's) lands at
+    absolute position ``start_lens[b] + j`` through the block table.
+
+    A chunk of s tokens from a traced, unaligned start touches at most
+    n = ceil((s + bs - 1) / bs) consecutive table columns.  Each of those
+    blocks is read, merged with the chunk's rows under a position mask
+    and put back in place (`_put`): a block the chunk does not reach is
+    rewritten as it was.  Columns past the table go to the pad block 0
+    (never a clipped read of the LAST column, which would corrupt a real
+    block), as do inactive streams (length 0 over a zero table row).  One
+    `fori_loop` over the B x n blocks, traced once a program (`jax.jit`),
+    not B x n unrolled updates a layer: unrolled, the six chunk programs
+    of the 1.3B serve cell took 11 s longer to trace and lower, against
+    `setup_s` (PERF.md §6, PR 27)."""
+    nh, _, bs, hd = k_pool.shape
+    B, s = k.shape[0], k.shape[1]
+    nb = tables.shape[1]
+    n = (s + 2 * bs - 2) // bs
+    r = start_lens % bs                                     # [B]
+    cols = (start_lens // bs)[:, None] + jnp.arange(
+        n, dtype=start_lens.dtype)                          # [B, n]
+    blks = jnp.where(cols < nb, jnp.take_along_axis(
+        tables, jnp.clip(cols, 0, nb - 1), axis=1), 0).reshape(B * n)
+    row = jnp.arange(n * bs, dtype=start_lens.dtype)
+    live = ((row >= r[:, None]) & (row < r[:, None] + s)).reshape(B * n, bs)
+
+    def as_blocks(x, pool):
+        # each chunk shifted to its offset in its first block, cut into
+        # blocks: [B*n, nh, bs, hd]
+        buf = jax.vmap(lambda xb, rb: jax.lax.dynamic_update_slice(
+            jnp.zeros((n * bs, nh, hd), pool.dtype), xb, (rb, 0, 0)))(
+                x.astype(pool.dtype), r)
+        return jnp.transpose(buf.reshape(B * n, bs, nh, hd), (0, 2, 1, 3))
+
+    kb, vb = as_blocks(k, k_pool), as_blocks(v, v_pool)
+
+    def merge(i, pools):
+        m = live[i][None, None, :, None]
+        return tuple(
+            _put(pool, jnp.where(m, new[i][:, None], _block_of(pool, blks[i])),
+                 blks[i])
+            for pool, new in zip(pools, (kb, vb)))
+
+    return jax.lax.fori_loop(0, B * n, merge, (k_pool, v_pool))
+
+
+def paged_copy_block(pool, src, dst):
+    """Physical block `src` of `pool` copied over block `dst`, in place
+    (the serving engine's copy-on-write; src/dst traced scalars)."""
+    return _put(pool, _block_of(pool, src), dst)
 
 
 def _chunk_grid_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
@@ -347,7 +510,8 @@ def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
     q:            [B, s, nh, hd]  chunk queries (s > 1 typical; post-RoPE)
     k_cache/v_cache: [nh, num_blocks, bs, hd] physical block pool with
         the chunk ALREADY WRITTEN at positions start..start+s-1 (the
-        write stays the caller's single scatter — `PagedChunkView`)
+        write stays the caller's — `PagedChunkView` through
+        `paged_write_chunk`, in place and in this layout)
     block_tables: [B, max_blocks] int32 physical block ids (pad with 0)
     start_lens:   [B] int32 cached-prefix length per sequence; query j
         sits at absolute position start + j and attends keys 0..start+j

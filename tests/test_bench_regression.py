@@ -631,6 +631,12 @@ def _load_bench(modname):
 def _kernel_coverage_record(bench, smoke):
     from types import SimpleNamespace
 
+    from paddle_tpu.observability import xray
+
+    # the audit's entries are process-global: an engine that another test
+    # file warmed in this worker with the kernels flagged off would leave
+    # its dense rows in this rung's audit
+    xray.reset()
     ctx = SimpleNamespace(smoke=smoke, on_tpu=False, probe={"ok": True},
                           device_kind="cpu")
     val = bench.bench_kernel_coverage(ctx)

@@ -137,6 +137,164 @@ def test_verify_kernel_matches_chunk_semantics():
     assert ("paged_spec_verify", "interpret") in claims
 
 
+# ------------------------------------------- pool writes (ISSUE 27)
+# Every traced write into a pool is in place - the decode row inside the
+# `paged_decode` kernel, blocks by `dynamic_update_slice` - because
+# XLA:TPU re-lays-out the whole pool around a scatter.  The scatters they
+# replaced are kept here, word for word, as the oracle: the same rows
+# must land in the same slots, bit for bit.
+
+def _scatter_token(k_pool, tables, seq_lens, k_step):
+    bs = k_pool.shape[2]
+    B = k_step.shape[0]
+    blk = tables[jnp.arange(B), seq_lens // bs]
+    return k_pool.at[:, blk, seq_lens % bs].set(
+        jnp.moveaxis(k_step, 0, 1).astype(k_pool.dtype))
+
+
+def _scatter_chunk(k_pool, tables, seq_lens, k):
+    bs, nb, s = k_pool.shape[2], tables.shape[1], k.shape[1]
+    pos = seq_lens[:, None] + jnp.arange(s, dtype=seq_lens.dtype)
+    cols = pos // bs
+    blk = jnp.take_along_axis(tables, jnp.clip(cols, 0, nb - 1), axis=1)
+    blk = jnp.where(cols < nb, blk, 0)
+    return k_pool.at[:, blk, (pos % bs).astype(jnp.int32)].set(
+        jnp.transpose(k.astype(k_pool.dtype), (2, 0, 1, 3)))
+
+
+def _scatter_prefill(k_pool, tables, k):
+    bs = k_pool.shape[2]
+    B, S, nh, hd = k.shape
+    nb = (S + bs - 1) // bs
+    k = jnp.concatenate(
+        [k, jnp.zeros((B, nb * bs - S, nh, hd), k.dtype)], axis=1)
+    kb = jnp.moveaxis(k.reshape(B * nb, bs, nh, hd), 2, 0)
+    return k_pool.at[:, tables[:, :nb].reshape(-1)].set(
+        kb.astype(k_pool.dtype))
+
+
+def _tables(rows, width):
+    t = np.zeros((len(rows), width), np.int32)
+    for b, r in enumerate(rows):
+        t[b, :len(r)] = r
+    return t
+
+
+# bs = 8, tables 3 wide (24 positions), 12 physical blocks, block 0 = pad.
+# kind, lens (write positions), table rows, chunk length
+WRITE_CASES = {
+    "token_offset_0_and_last": ("token", [8, 15], [[1, 2], [3, 4]], 1),
+    "token_first_of_a_fresh_block": ("token", [16, 0], [[1, 2, 3], [4]], 1),
+    "token_inactive_slot_to_pad": ("token", [5, 0, 9],
+                                   [[1], [], [6, 7]], 1),
+    "token_two_inactive_slots": ("token", [0, 3, 0], [[], [5], []], 1),
+    "chunk_from_mid_block": ("chunk", [5], [[1, 2, 3]], 12),
+    "chunk_ends_on_a_block_edge": ("chunk", [5], [[4, 2, 9]], 11),
+    "chunk_aligned_whole_blocks": ("chunk", [8], [[4, 2, 9]], 16),
+    "chunk_overflows_the_table": ("chunk", [20], [[1, 2, 3]], 8),
+    "verify_k4_two_streams_no_shared_block": (
+        "chunk", [6, 13], [[1, 2], [3, 4]], 4),
+    "verify_k4_with_inactive_stream": (
+        "chunk", [7, 0, 21], [[1, 2], [], [3, 4, 5]], 4),
+    "chunk_gqa_repeat": ("gqa", [3, 10], [[1, 2], [3, 4, 5]], 6),
+    "prefill_ragged_tail": ("prefill", [0, 0], [[1, 2, 3], [4, 5, 6]], 19),
+    "prefill_whole_blocks": ("prefill", [0], [[7, 3]], 16),
+    "cow_block": ("cow", [], [], 0),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_pool_write_matches_the_scatter_it_replaced(case, dtype):
+    from paddle_tpu.models.kv_cache import PagedChunkView
+    kind, lens, rows, s = WRITE_CASES[case]
+    nh, hd, bs, width, nblocks = 4, 16, 8, 3, 12
+    dt = jnp.dtype(dtype)
+    rng = np.random.RandomState(len(case))
+    rnd = lambda *sh: jnp.asarray(                     # noqa: E731
+        rng.standard_normal(sh), jnp.float32).astype(dt)
+    k_pool, v_pool = rnd(nh, nblocks, bs, hd), rnd(nh, nblocks, bs, hd)
+    if kind == "cow":
+        src, dst = jnp.int32(7), jnp.int32(2)
+        got = jax.jit(pp.paged_copy_block)(k_pool, src, dst)
+        want = k_pool.at[:, dst].set(k_pool[:, src])
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        return
+    B = len(lens)
+    tables = jnp.asarray(_tables(rows, width))
+    lens = jnp.asarray(lens, jnp.int32)
+    # the new rows come in float32: the write casts to the pool's dtype
+    if kind == "token":
+        q, k, v = (jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+                   for _ in range(3))
+        out, *got = jax.jit(pp.paged_decode_step)(
+            q.astype(dt), k, v, k_pool, v_pool, tables, lens)
+        want = (_scatter_token(k_pool, tables, lens, k),
+                _scatter_token(v_pool, tables, lens, v))
+        # ...and a live slot attends through the pools the step leaves
+        # behind (an inactive one attends the pad block: discarded)
+        ref = pp.paged_attention_reference(q.astype(dt), *got, tables,
+                                           lens + 1)
+        live = np.asarray(lens) > 0
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[live],
+            np.asarray(ref, np.float32)[live],
+            atol=2e-2 if dtype == "bfloat16" else 2e-6)
+    elif kind == "prefill":
+        k, v = (jnp.asarray(rng.standard_normal((B, s, nh, hd)),
+                            jnp.float32) for _ in range(2))
+        got = jax.jit(pp.paged_write_prefill)(k_pool, v_pool, tables, k, v)
+        want = (_scatter_prefill(k_pool, tables, k),
+                _scatter_prefill(v_pool, tables, v))
+    else:
+        nkv = nh // 2 if kind == "gqa" else nh
+        k, v = (jnp.asarray(rng.standard_normal((B, s, nkv, hd)),
+                            jnp.float32) for _ in range(2))
+        q = jnp.zeros((B, s, nh, hd), jnp.float32)
+
+        @jax.jit
+        def write(kp, vp, tables, lens, q, k, v):       # lens traced
+            new, pos = PagedChunkView.from_parts(
+                kp, vp, tables, lens, bs)._write_chunk(q, k, v)
+            return (new.k, new.v), pos, new.seq_lens
+
+        got, pos, new_lens = write(k_pool, v_pool, tables, lens, q, k, v)
+        np.testing.assert_array_equal(
+            np.asarray(pos), np.asarray(lens)[:, None] + np.arange(s))
+        np.testing.assert_array_equal(np.asarray(new_lens),
+                                      np.asarray(lens) + s)
+        rep = nh // nkv
+        want = (_scatter_chunk(k_pool, tables, lens,
+                               jnp.repeat(k, rep, axis=2)),
+                _scatter_chunk(v_pool, tables, lens,
+                               jnp.repeat(v, rep, axis=2)))
+    for g, w, old in zip(got, want, (k_pool, v_pool)):
+        g, w, old = (np.asarray(a, np.float32) for a in (g, w, old))
+        assert g.dtype == w.dtype and g.shape == w.shape
+        # every real block, bit for bit
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+        assert (w != old).any()                  # the case writes something
+        if case == "token_two_inactive_slots":
+            # two rows aimed at one pad slot: a scatter leaves it
+            # unspecified which lands; the in-place form writes the last
+            np.testing.assert_array_equal(g[:, 0, 1:], old[:, 0, 1:])
+            assert any(np.array_equal(
+                g[:, 0, 0], np.asarray(x[b].astype(dt), np.float32))
+                for x in (k, v) for b in (0, 2))
+        else:
+            np.testing.assert_array_equal(g[:, 0], w[:, 0])
+    if case == "chunk_overflows_the_table":
+        # positions 24..27 fell off the 3-column table: they sit in the
+        # pad block's rows 0..3, and the last real block (3) kept its
+        # rows 0..3 while 4..7 took positions 20..23
+        g, old = np.asarray(got[0], np.float32), np.asarray(k_pool, np.float32)
+        np.testing.assert_array_equal(g[:, 3, :4], old[:, 3, :4])
+        assert (g[:, 3, 4:] != old[:, 3, 4:]).all()
+        assert (g[:, 0, :4] != old[:, 0, :4]).all()
+        np.testing.assert_array_equal(g[:, 0, 4:], old[:, 0, 4:])
+
+
 def test_chunk_kernel_claims_its_audit_name():
     q, k, v, tables, starts = _case(B=1, s=4, start=5, nh_q=2, nh_kv=2)
     with xray.capture_kernel_claims() as claims:

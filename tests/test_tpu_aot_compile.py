@@ -97,6 +97,9 @@ def _cases():
             cases[f"paged_attention nh{nh} hd{hd} {n}"] = (
                 functools.partial(pp.paged_attention, interpret=False),
                 (((B, nh, hd), dt), pool, pool) + tl)
+            cases[f"paged_decode_step nh{nh} hd{hd} {n}"] = (
+                functools.partial(pp.paged_decode_step, interpret=False),
+                (((B, nh, hd), dt),) * 3 + (pool, pool) + tl)
             cases[f"paged_verify_attention k4 nh{nh} hd{hd} {n}"] = (
                 functools.partial(pp.paged_verify_attention,
                                   interpret=False),
@@ -191,57 +194,71 @@ def test_custom_call_is_named_for_its_kernel(compiled, kernel):
     assert not others
 
 
-@pytest.fixture(scope="module")
-def serve_modules(v5e):
-    """The serving programs of a one-layer engine with the 1.3B head
-    shape, lowered for the v5e with Mosaic kernels: `{compile-tracker
-    name: lowered text}`."""
+def _lower_engine_programs(v5e, chunk, max_batch=8, **cfg):
+    """The programs of a bf16 `ServingEngine` over a small GPT, lowered
+    for the v5e with Mosaic kernels: `(pool shape, {label: (compile-tracker
+    name, Lowered)})`.  `_program` drops donation on this sandbox's CPU
+    backend, so each program's own Python body is jitted again with the
+    donation the chip gets."""
     import numpy as np
 
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
+    sh = SingleDeviceSharding(v5e)
+    spec = lambda tree: jax.tree_util.tree_map(        # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sh),
+        tree)
+    i32 = lambda *s: np.zeros(s, np.int32)             # noqa: E731
     saved = pallas_common.interpret_default
     pallas_common.interpret_default = lambda: False
     try:
         paddle.seed(0)
         model = GPTForCausalLM(GPTConfig(
-            vocab_size=256, hidden_size=128, num_layers=1, num_heads=1,
-            max_seq_len=256, intermediate_size=256))
+            vocab_size=256, intermediate_size=256, **cfg))
         model.eval()
         model.bfloat16()
-        eng = ServingEngine(model, max_batch=8, max_context=256,
-                            block_size=64, steps_per_tick=4,
-                            prefill_chunk=128)
-        sh = SingleDeviceSharding(v5e)
-        spec = lambda tree: jax.tree_util.tree_map(    # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
-                                           sharding=sh), tree)
+        eng = ServingEngine(model, max_batch=max_batch,
+                            max_context=cfg["max_seq_len"], block_size=64,
+                            steps_per_tick=4, prefill_chunk=chunk)
         B, nb = eng.B, eng.nb_per_seq
-        i32 = lambda *s: np.zeros(s, np.int32)         # noqa: E731
         sched = (i32(B, nb), i32(B), i32(B))
         samp = (np.zeros((B,), np.bool_), np.ones((B,), np.float32), i32(B),
                 np.ones((B,), np.float32), np.zeros((B,), np.uint32), i32(B))
+        row = (i32(1, nb), i32(1, chunk), np.int32(1))
         out = {}
         with eng._params_for_call() as params:
-            for fn, args in (
-                    (eng._tick_program(4),
-                     (params, eng.pools) + sched + samp),
-                    (eng._decode_program(), (params, eng.pools) + sched),
-                    (eng._prefill_cont_program(128),
-                     (params, eng.pools, i32(1, nb), i32(1, 128),
-                      np.int32(1), np.int32(0))),
-                    (eng._prefill_program(128),
-                     (params, eng.pools, i32(1, nb), i32(1, 128),
-                      np.int32(1))),
-                    (eng._cow_program(),
-                     (eng.pools, np.int32(0), np.int32(0)))):
-                out[fn._compile_name] = fn.__wrapped__.lower(
-                    *spec(args)).as_text()
-        return out
+            for label, fn, args, donated in (
+                    ("tick k1", eng._tick_program(1),
+                     (params, eng.pools) + sched + samp, 1),
+                    ("tick k4", eng._tick_program(4),
+                     (params, eng.pools) + sched + samp, 1),
+                    ("decode", eng._decode_program(),
+                     (params, eng.pools) + sched, 1),
+                    ("prefill_cont", eng._prefill_cont_program(chunk),
+                     (params, eng.pools) + row + (np.int32(0),), 1),
+                    ("prefill", eng._prefill_program(chunk),
+                     (params, eng.pools) + row, 1),
+                    ("cow", eng._cow_program(),
+                     (eng.pools, np.int32(0), np.int32(0)), 0)):
+                body = fn.__wrapped__.__wrapped__
+                out[label] = (fn._compile_name, jax.jit(
+                    body, donate_argnums=(donated,)).lower(*spec(args)))
+        return eng.pools[0][0].shape, out
     finally:
         pallas_common.interpret_default = saved
+
+
+@pytest.fixture(scope="module")
+def serve_modules(v5e):
+    """The serving programs of a one-layer engine with the 1.3B head
+    shape, lowered for the v5e with Mosaic kernels: `{compile-tracker
+    name: lowered text}`."""
+    _, lowered = _lower_engine_programs(
+        v5e, 128, hidden_size=128, num_layers=1, num_heads=1,
+        max_seq_len=256)
+    return {name: low.as_text() for name, low in lowered.values()}
 
 
 @pytest.mark.parametrize("name, kernel", [
@@ -254,3 +271,117 @@ def test_serve_program_is_named_for_its_compile_tracker_entry(
     assert f"module @jit_{name.replace('.', '_')} " in text, text[:200]
     if kernel is not None:
         assert "tpu_custom_call" in text and kernel in text
+
+
+# ------------------------------------- pools stay put (ISSUE 27)
+# XLA:TPU gives a scatter's operand the layout that makes the scattered
+# dims major; the paged kernels pin the default one.  While the pool
+# writes were scatters, every serving program re-laid-out every pool on
+# each side of each write: 2 copies of every pool a launch and one a scan
+# step, 70% of the 1.3B serve cell's device time.  The engine's own
+# programs on a two-layer model at the cell's pool geometry (batch 16,
+# context 1536, 384 blocks of 64 + the pad block, bf16, pools donated) are
+# compiled here and their HLO read: no instruction that copies, scatters
+# or transposes a whole pool, and less temp memory than one pool.
+
+POOL_PROGRAMS = ("tick k1", "tick k4", "prefill_cont", "prefill", "cow")
+
+
+@pytest.fixture(scope="module")
+def pool_programs(v5e):
+    """`{(heads, head_dim, program): (compiled, pool shape)}`, the chunk
+    program's start traced."""
+    lowered = {}
+    for nh, hd in WIDTHS:
+        shape, progs = _lower_engine_programs(
+            v5e, 256, max_batch=16, hidden_size=nh * hd, num_layers=2,
+            num_heads=nh, max_seq_len=1536)
+        assert shape == (nh, 385, 64, hd)
+        lowered.update({(nh, hd, label): (progs[label][1], shape)
+                        for label in POOL_PROGRAMS})
+    with concurrent.futures.ThreadPoolExecutor(max_workers=8) as ex:
+        futs = {key: (ex.submit(low.compile), shape)
+                for key, (low, shape) in lowered.items()}
+    return {key: (f.result(), shape) for key, (f, shape) in futs.items()}
+
+
+def _pool_relayouts(hlo_text: str, shape) -> list:
+    """(computation, instruction line) of every `copy`, `scatter` or
+    `transpose` whose result has the pool's shape."""
+    import re
+    dims = ",".join(str(d) for d in shape)
+    op = re.compile(rf"= bf16\[{dims}\]\S* (copy|scatter|transpose)\(")
+    out, comp = [], None
+    for ln in hlo_text.splitlines():
+        if ln.endswith("{") and not ln.startswith(" "):
+            comp = "ENTRY" if ln.startswith("ENTRY") else ln.split()[0]
+        elif op.search(ln):
+            out.append((comp, ln.strip()[:160]))
+    return out
+
+
+@pytest.mark.parametrize("program", POOL_PROGRAMS)
+@pytest.mark.parametrize("nh, hd", WIDTHS)
+def test_serving_program_leaves_the_pools_in_place(pool_programs, nh, hd,
+                                                   program):
+    compiled, shape = pool_programs[nh, hd, program]
+    text = compiled.as_text()
+    if program not in ("cow", "prefill"):
+        assert "tpu_custom_call" in text         # the kernel reads the pool
+    hits = _pool_relayouts(text, shape)
+    assert not [h for h in hits if " copy(" not in h[1]], hits
+    dims = ",".join(str(d) for d in shape)
+    row_major = f"bf16[{dims}]{{3,2,1,0:" in text.splitlines()[0]
+    if row_major:
+        # 16 heads of 128: the chip hands the program its pools in the
+        # kernels' layout, and nothing moves them
+        assert not hits, hits
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 2 * nh * 385 * 64 * hd, temp
+    else:
+        # heads of 64: the v5e's own layout for such an array is
+        # {1,3,2,0} (64 lanes would waste half a tile), so a program that
+        # calls a kernel copies each pool on the way in (twice where the
+        # chunk write's loop comes first and wants a third layout) and
+        # once on the way out (PERF.md §7) - never inside a loop, and none
+        # where no kernel runs
+        assert all(comp == "ENTRY" for comp, _ in hits), hits
+        assert len(hits) <= 3 * 4, hits           # 2 layers x (k, v)
+        if "tpu_custom_call" not in text:
+            assert not hits, hits
+
+
+def test_copy_metric_reads_the_opcode_copy_and_no_other(pool_programs):
+    """`copy_time_pct.serve` (a data-only metric: a pattern for the
+    accepted `xplane:matching_time_pct`) finds a re-layout by the
+    instruction's text, which is what the chip's trace names an op by:
+    held here to XLA:TPU's own output (the 12 x 64 tick copies its pools
+    at the program's edges, above) and to the recorded train trace, where
+    it must read what the breakdown's `copy` class reads."""
+    import json
+    import os
+    import re
+
+    from benchmark.reducers import xplane
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "copy_time_pct.serve.json")) as f:
+        lm = json.load(f)
+    rx = re.compile(lm["args"]["pattern"])
+    compiled, shape = pool_programs[12, 64, "tick k4"]
+    text = compiled.as_text()
+    assert _pool_relayouts(text, shape)
+    kinds = set()
+    for ln in text.splitlines():
+        ln = ln.strip()
+        if ln.startswith("%") and " = " in ln:
+            kind = xplane.op_kind(ln)
+            kinds.add(kind)
+            assert bool(rx.search(ln)) == (kind == "copy"), ln
+    assert {"copy", "copy-start", "copy-done", "fusion"} <= kinds, kinds
+    trace = xplane.load(os.path.join(
+        root, "tests", "benchmark", "data", "train_350m_3steps.xplane.pb.gz"))
+    got = xplane.matching_time_pct(trace, {}, lm["args"])
+    want = 100.0 * dict(xplane.top_ops(trace, limit=100))["copy"] \
+        / xplane.busy_s(trace)
+    assert 0 < got < 100 and abs(got - want) < 1e-6, (got, want)
