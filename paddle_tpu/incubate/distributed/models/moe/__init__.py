@@ -2,6 +2,7 @@
 
 from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate, capacity
 from .moe_layer import ExpertMLP, MoELayer
+from .dropless import HeldExpertsLayer, SigmoidTopKGate
 
 __all__ = ["MoELayer", "ExpertMLP", "BaseGate", "NaiveGate", "SwitchGate",
-           "GShardGate", "capacity"]
+           "GShardGate", "capacity", "SigmoidTopKGate", "HeldExpertsLayer"]

@@ -446,7 +446,8 @@ class _PendingTick:
     __slots__ = ("active", "k", "toks", "logits", "reqs", "t0",
                  "device_sampling", "overlapped", "step_no", "san",
                  "spec", "counts", "accepts", "new_lens", "new_last",
-                 "chunks", "kcap", "sched_s", "chunk_s", "dispatch_s")
+                 "chunks", "kcap", "sched_s", "chunk_s", "dispatch_s",
+                 "state")
 
     def __init__(self, active, k, toks, logits, reqs, t0,
                  device_sampling, step_no, san=None):
@@ -465,6 +466,7 @@ class _PendingTick:
         self.accepts = None
         self.new_lens = None
         self.new_last = None
+        self.state = ()     # the cache's per-layer state after this tick
         self.chunks = 0     # prefill chunks run at this tick's boundary
         self.kcap = None    # per-slot emit caps of a spec dispatch
         # per-tick phase breakdown: seconds of the serve:schedule,
@@ -567,9 +569,24 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = max_batch * self.nb_per_seq
         self.num_blocks = num_blocks
-        nh = cfg.num_heads
-        hd = cfg.hidden_size // nh
-        self.nh, self.hd = nh, hd
+        # what a layer caches, and through which views the programs reach
+        # it, is the model's to say (a (K, V) pair of pools, or a latent
+        # pool with an index-key pool beside it): every program, the
+        # copy-on-write and the prefix cache's export go over "the pools
+        # of a layer", all under the one block table
+        self.cache = model.cache_spec()
+        asked = {"tp_degree": int(
+                     tp_degree if tp_degree is not None
+                     else _flags.get_flag("serving_tp_degree")) > 1,
+                 "draft_model": draft_model is not None,
+                 "spec_decode": bool(
+                     spec_decode if spec_decode is not None
+                     else _flags.get_flag("serving_spec_decode")),
+                 "quant": bool(quant if quant is not None
+                               else _flags.get_flag("serving_quant"))}
+        for mech, why in self.cache.unsupported.items():
+            if asked.get(mech):
+                raise ValueError(f"ServingEngine({mech}=...): {why}")
         self._sd = model.state_dict()
         self._keys = sorted(self._sd)
         dtype = self._sd[self._keys[0]]._value.dtype
@@ -636,15 +653,18 @@ class ServingEngine:
         # physical pools per layer; block 0 is the pad/scratch block
         # (TP: sharded along the head axis so each rank holds its heads'
         # blocks — the KV-memory scale-out)
-        def _pool():
-            z = jnp.zeros((nh, num_blocks + 1, block_size, hd), dtype)
+        def _place(spec):
             if self._tp_mesh is None:
-                return z
+                return None
             from jax.sharding import NamedSharding
+            return lambda row, z: jax.device_put(
+                z, NamedSharding(self._tp_mesh, spec))
+        main_place = None
+        if self._tp_mesh is not None:
             from . import tp as _tp
-            return jax.device_put(
-                z, NamedSharding(self._tp_mesh, _tp.pool_spec()))
-        self.pools = [(_pool(), _pool()) for _ in range(cfg.num_layers)]
+            main_place = _place(_tp.pool_spec())
+        self.pools = self.cache.init_pools(num_blocks, block_size, dtype,
+                                           main_place)
         # --- speculative decoding (ISSUE 10): the draft model proposes
         # spec_k tokens per slot inside one compiled program; the target
         # judges all k proposals in one chunk verify forward
@@ -727,22 +747,13 @@ class ServingEngine:
                     self._dkeys,
                     [self._dsd[k]._value for k in self._dkeys],
                     self.quant_mode)
-            dnh = dcfg.num_heads
-            dhd = dcfg.hidden_size // dnh
             ddtype = self._dsd[self._dkeys[0]]._value.dtype
-
-            def _dpool():
-                z = jnp.zeros((dnh, num_blocks + 1, block_size, dhd),
-                              ddtype)
-                if self._tp_mesh is None:
-                    return z
-                from jax.sharding import NamedSharding, PartitionSpec
-                # draft pools replicate: every rank runs the full
-                # (small) draft forward; only the verify is sharded
-                return jax.device_put(
-                    z, NamedSharding(self._tp_mesh, PartitionSpec()))
-            self.dpools = [(_dpool(), _dpool())
-                           for _ in range(dcfg.num_layers)]
+            self.draft_cache = draft_model.cache_spec()
+            from jax.sharding import PartitionSpec
+            # draft pools replicate: every rank runs the full (small)
+            # draft forward; only the verify is sharded
+            self.dpools = self.draft_cache.init_pools(
+                num_blocks, block_size, ddtype, _place(PartitionSpec()))
             if self._tp_mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
                 rep = NamedSharding(self._tp_mesh, PartitionSpec())
@@ -772,6 +783,7 @@ class ServingEngine:
         self.waiting: deque = deque()
         self.finished: List[Request] = []
         self.steps = 0
+        self._cache_state = None   # (steps, state rows) of the last harvest
         self.ticks = 0
         self.tokens_out = 0
         self.steps_per_tick = max(1, int(steps_per_tick))
@@ -832,17 +844,14 @@ class ServingEngine:
         # attend through.  Snapshotted here like the pad ladder — the
         # flags must never be read under trace (graft-lint R004), and a
         # running engine's compiled grid must not shift under it.
-        from ..models.kv_cache import (PagedChunkKernelView,
-                                       PagedChunkView,
-                                       PagedVerifyKernelView)
         self._chunk_view_cls = (
-            PagedChunkKernelView
+            self.cache.chunk_kernel_view
             if _flags.get_flag("serving_pallas_prefill")
-            else PagedChunkView)
+            else self.cache.chunk_view)
         self._verify_view_cls = (
-            PagedVerifyKernelView
+            self.cache.verify_kernel_view
             if _flags.get_flag("serving_pallas_verify")
-            else PagedChunkView)
+            else self.cache.chunk_view)
         self.prefill_chunks_total = 0
         self.overlap_chunks_total = 0
         self.slo_sheds = 0
@@ -907,10 +916,22 @@ class ServingEngine:
             self._import_prefix_cache(self._export_dir)
 
     # ------------------------------------------------------------ programs
-    def _views(self, pools, tables, seq_lens):
-        from ..models.kv_cache import PagedKVCache
-        return [PagedKVCache.from_parts(k, v, tables, seq_lens, self.bs)
-                for k, v in pools]
+    def _views(self, pools, tables, seq_lens, cls=None):
+        """One view a layer over its pools (`cls`: the decode / prefill-
+        from-empty view unless a chunk view is asked for)."""
+        cls = cls or self.cache.view
+        return [cls.from_parts(*layer, tables, seq_lens, self.bs)
+                for layer in pools]
+
+    def _state_rows(self, pools) -> tuple:
+        """A copy of the layers' per-layer state (the rows of the cache
+        that are not paged), one `[num_layers, ...]` array a row: the
+        tick program returns it beside its tokens, so the harvest brings
+        it to the host with them and nothing reads a donated pool.  `()`
+        for a cache without such rows."""
+        return tuple(jnp.stack([layer[i] for layer in pools])
+                     for i, row in enumerate(self.cache.rows)
+                     if not row.paged)
 
     def _bind(self, param_vals):
         for k, v in zip(self._keys, param_vals):
@@ -1016,7 +1037,7 @@ class ServingEngine:
                     Tensor._wrap(last_tok[:, None]), views,
                     pos_offset=Tensor._wrap(seq_lens[:, None]))
             logits = logits_t._value[:, -1, :]
-            new_pools = [(c.k, c.v) for c in new_views]
+            new_pools = [c.pools for c in new_views]
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
                 logits, new_pools
 
@@ -1059,12 +1080,13 @@ class ServingEngine:
                 active = lens > 0
                 nxt = jnp.where(active, nxt, 0)
                 lens = jnp.where(active, lens + 1, 0)
-                new_pools = [(c.k, c.v) for c in new_views]
+                new_pools = [c.pools for c in new_views]
                 return (new_pools, lens, nxt), nxt
 
             (pools, _, _), toks = jax.lax.scan(
                 body, (pools, seq_lens, last_tok), jnp.arange(k))
-            return jnp.transpose(toks), pools        # [B, k]
+            return jnp.transpose(toks), pools, \
+                self._state_rows(pools)              # [B, k]
 
         fn = self._tick_fns[k] = self._program(
             "serving.tick", tick, (1,), ("steps_per_tick", k))
@@ -1098,11 +1120,11 @@ class ServingEngine:
 
             (pools, _, _), toks = jax.lax.scan(
                 body, (pools, seq_lens, last_tok), jnp.arange(k))
-            return jnp.transpose(toks), pools
+            return jnp.transpose(toks), pools, ()
 
         body = self._shard_tp(
             tick, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 9,
-            (_P(), _tp.pool_spec()))
+            (_P(), _tp.pool_spec(), ()))
         return self._program(
             "serving.tick", body, (1,), ("steps_per_tick", k))
 
@@ -1144,7 +1166,7 @@ class ServingEngine:
             # last REAL token's logits (prompt is right-padded to L_pad)
             row = jax.lax.dynamic_index_in_dim(
                 logits_t._value[0], true_len - 1, axis=0, keepdims=False)
-            new_pools = [(c.k, c.v) for c in new_views]
+            new_pools = [c.pools for c in new_views]
             return row, new_pools
 
         if self.spec_model:
@@ -1170,18 +1192,19 @@ class ServingEngine:
         hit; the shared blocks already hold the prefix's draft KV from
         the admission that registered them)."""
         from ..framework.dygraph import no_grad
-        from ..models.kv_cache import PagedKVCache
         if start is None:
-            lens, cls, off = jnp.zeros((1,), jnp.int32), PagedKVCache, 0
+            lens, cls, off = jnp.zeros((1,), jnp.int32), \
+                self.draft_cache.view, 0
         else:
-            lens, cls, off = jnp.reshape(start, (1,)), \
-                self._chunk_view_cls, Tensor._wrap(start)
-        dviews = [cls.from_parts(kk, vv, table_row, lens, self.bs)
-                  for kk, vv in dpools]
+            lens, off = jnp.reshape(start, (1,)), Tensor._wrap(start)
+            cls = (self.draft_cache.chunk_kernel_view
+                   if _flags.get_flag("serving_pallas_prefill")
+                   else self.draft_cache.chunk_view)
+        dviews = self._views(dpools, table_row, lens, cls)
         with no_grad():
             _, dnew = self.draft.forward_with_cache(
                 Tensor._wrap(prompt), dviews, pos_offset=off)
-        return [(c.k, c.v) for c in dnew]
+        return [c.pools for c in dnew]
 
     def _build_tp_prefill(self, L_pad: int):
         from jax.sharding import PartitionSpec as _P
@@ -1273,16 +1296,14 @@ class ServingEngine:
         def cont(param_vals, pools, table_row, suffix, true_len, start):
             self._bind_params(param_vals)
             lens = jnp.reshape(start, (1,))
-            views = [chunk_view_cls.from_parts(kk, vv, table_row, lens,
-                                               self.bs)
-                     for kk, vv in pools]
+            views = self._views(pools, table_row, lens, chunk_view_cls)
             with no_grad():
                 logits_t, new_views = self.model.forward_with_cache(
                     Tensor._wrap(suffix), views,
                     pos_offset=Tensor._wrap(start))
             row = jax.lax.dynamic_index_in_dim(
                 logits_t._value[0], true_len - 1, axis=0, keepdims=False)
-            new_pools = [(c.k, c.v) for c in new_views]
+            new_pools = [c.pools for c in new_views]
             return row, new_pools
 
         if self.spec_model:
@@ -1313,13 +1334,16 @@ class ServingEngine:
 
         from ..ops.pallas_paged import paged_copy_block
 
-        def cow(pools, src, dst):
-            return [(paged_copy_block(kk, src, dst),
-                     paged_copy_block(vv, src, dst)) for kk, vv in pools]
+        def cow(pools, src, dst, rows=self.cache.rows):
+            return [tuple(paged_copy_block(p, src, dst, row.block_axis)
+                          if row.paged else p
+                          for row, p in zip(rows, layer))
+                    for layer in pools]
 
         if self.spec_model:
             def body(pools, dpools, src, dst):
-                return cow(pools, src, dst), cow(dpools, src, dst)
+                return cow(pools, src, dst), \
+                    cow(dpools, src, dst, self.draft_cache.rows)
             donate = (0, 1)
         else:
             body, donate = cow, (0,)
@@ -2555,7 +2579,9 @@ class ServingEngine:
         # the chunk's host side (async enqueue; the LAST chunk host-syncs
         # its logits row inside): the boundary's chunk-prefill phase
         with _span("serve:chunk_dispatch", rid=req.trace_id or req.rid,
-                   q_tokens=n, kv_tokens=off + n) as sp:
+                   q_tokens=n, kv_tokens=off + n,
+                   selected_tokens=self._selected(
+                       off + 1 + np.arange(n))) as sp:
             try:
                 with self._params_for_call() as param_vals:
                     dpref = ((self._draft_vals(), self.pools, self.dpools)
@@ -2724,7 +2750,9 @@ class ServingEngine:
         # host dispatch phase: enqueue cost by design (the compute lands
         # in the harvest wait; a sampled program blocks inside the call)
         with _span("serve:tick_dispatch", active=len(active),
-                   kv_tokens=int(self.seq_lens[active].sum())) as sp:
+                   kv_tokens=int(self.seq_lens[active].sum()),
+                   selected_tokens=self._selected(
+                       self.seq_lens[active])) as sp:
             pend = self._launch_tick(active, t0, chain)
             sp.set(steps=pend.k)
         pend.dispatch_s = sp.seconds
@@ -2732,6 +2760,28 @@ class ServingEngine:
         self._chunks_this_boundary = 0
         pend.sched_s, pend.chunk_s = sched_s, chunk_s
         return pend
+
+    def cache_state(self) -> dict:
+        """Per-layer device state the programs thread with the pools (an
+        expert layer's row counts), `[num_layers, ...]` a row by its
+        name, as the last harvested tick brought it to the host, and
+        `steps`, the decode steps run up to and with that tick.  Host
+        data, safe from any thread: the pools themselves are donated to
+        the program in flight and never read.  Empty for a cache with no
+        such rows, and before the first harvest."""
+        if self._cache_state is None:
+            return {}
+        steps, arrays = self._cache_state
+        names = [r.name for r in self.cache.rows if not r.paged]
+        return dict(zip(names, arrays), steps=steps)
+
+    def _selected(self, contexts) -> int:
+        """Cached tokens the queries of these contexts attend to, summed:
+        all of a context, or the cache's `attend_limit` of it under
+        sparse selection (the spans' `selected_tokens`)."""
+        limit = self.cache.attend_limit
+        c = np.asarray(contexts)
+        return int((np.minimum(c, limit) if limit else c).sum())
 
     def _launch_tick(self, active, t0, chain):
         """Enqueue the tick program over ``active`` (the speculative
@@ -2768,7 +2818,7 @@ class ServingEngine:
         dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
         last = _last_column(chain.toks) if chain is not None \
             else dev(self.last_tok)
-        logits = None
+        logits, state = None, ()
         with self._params_for_call() as param_vals, \
                 _flight.guard("serving.tick"):
             if not device_sampling and k == 1:
@@ -2784,7 +2834,7 @@ class ServingEngine:
                 # the one k-step tick program; with sampling off the
                 # demotion guarantees no sampled row is active, the
                 # all-False mask takes the greedy cond branch
-                toks, self.pools = self._dispatch_call(
+                toks, self.pools, state = self._dispatch_call(
                     "serving.tick.dispatch",
                     lambda: self._tick_program(k)(
                         param_vals, self.pools, dev(self.tables),
@@ -2796,10 +2846,12 @@ class ServingEngine:
         for slot in active:
             self.seq_lens[slot] += k
             self.tok_pos[slot] += k
-        return _PendingTick(active=active, k=k, toks=toks, logits=logits,
+        pend = _PendingTick(active=active, k=k, toks=toks, logits=logits,
                             reqs=list(self.slot_req), t0=t0,
                             device_sampling=device_sampling,
                             step_no=self.steps, san=san)
+        pend.state = state
+        return pend
 
     def _spec_eligible(self, active, device_sampling) -> bool:
         """May this tick run draft/verify?  Needs the subsystem, on-
@@ -2973,6 +3025,11 @@ class ServingEngine:
             # this block: a hung device program raises TickTimeout
             # instead of wedging the loop forever.
             toks = self._materialize(pend.toks)
+        if pend.state:
+            # the tokens are here, so the program is done and its state
+            # rows are ready: a copy of a few hundred bytes, no wait
+            self._cache_state = (pend.step_no,
+                                 [np.asarray(a) for a in pend.state])
         # emit phase, to t_done: append, sample, stream (a harvest that
         # raises abandons the span, which then records nothing)
         sp_emit = _span("serve:emit").begin()
@@ -3511,8 +3568,10 @@ class ServingEngine:
         operator's contract, exactly like the persistent compile
         cache)."""
         cfg = self.model.cfg
-        fp = {"num_layers": int(cfg.num_layers), "nh": self.nh,
-              "hd": self.hd, "block_size": self.bs,
+        fp = {"num_layers": int(cfg.num_layers),
+              "rows": [[r.name, list(r.lead), list(r.trail)]
+                       for r in self.cache.rows if r.paged],
+              "block_size": self.bs,
               "vocab_size": int(cfg.vocab_size),
               "dtype": str(np.dtype(
                   np.asarray(self.pools[0][0]).dtype)),
@@ -3521,8 +3580,8 @@ class ServingEngine:
         if self.spec_model:
             dcfg = self.draft.cfg
             fp["draft_layers"] = int(dcfg.num_layers)
-            fp["draft_nh"] = int(dcfg.num_heads)
-            fp["draft_hd"] = int(dcfg.hidden_size // dcfg.num_heads)
+            fp["draft_rows"] = [[r.name, list(r.lead), list(r.trail)]
+                                for r in self.draft_cache.rows if r.paged]
         return fp
 
     def export_prefix_cache(self, root: str) -> dict:
@@ -3542,13 +3601,15 @@ class ServingEngine:
         blocks = sorted({e["block"] for e in index["entries"]})
         ids = np.asarray(blocks, np.int64)
         arrays = {"block_ids": ids}
-        for li, (kk, vv) in enumerate(self.pools):
-            arrays[f"k{li}"] = np.asarray(kk)[:, ids]
-            arrays[f"v{li}"] = np.asarray(vv)[:, ids]
+        def gather(pools, rows, prefix):
+            for li, layer in enumerate(pools):
+                for row, p in zip(rows, layer):
+                    if row.paged:
+                        arrays[f"{prefix}{row.name}{li}"] = np.take(
+                            np.asarray(p), ids, axis=row.block_axis)
+        gather(self.pools, self.cache.rows, "")
         if self.dpools is not None:
-            for li, (kk, vv) in enumerate(self.dpools):
-                arrays[f"dk{li}"] = np.asarray(kk)[:, ids]
-                arrays[f"dv{li}"] = np.asarray(vv)[:, ids]
+            gather(self.dpools, self.draft_cache.rows, "d")
         index["meta"] = self._prefix_fingerprint()
         step = max(_ckpt.all_steps(root), default=0) + 1
 
@@ -3664,31 +3725,36 @@ class ServingEngine:
         if not mapping:
             return 0
 
-        def install(pools, prefix, sharded):
+        def install(pools, rows, prefix, sharded):
             out = []
-            for li, (kk, vv) in enumerate(pools):
-                hk = np.zeros(kk.shape, np.asarray(kk).dtype)
-                hv = np.zeros(vv.shape, hk.dtype)
-                src_k = data[f"{prefix}k{li}"]
-                src_v = data[f"{prefix}v{li}"]
-                for old, new in mapping.items():
-                    hk[:, new] = src_k[:, pos[old]]
-                    hv[:, new] = src_v[:, pos[old]]
-                jk, jv = jnp.asarray(hk), jnp.asarray(hv)
-                if self._tp_mesh is not None:
-                    from jax.sharding import NamedSharding, PartitionSpec
-                    from . import tp as _tp
-                    spec = _tp.pool_spec() if sharded else PartitionSpec()
-                    jk = jax.device_put(
-                        jk, NamedSharding(self._tp_mesh, spec))
-                    jv = jax.device_put(
-                        jv, NamedSharding(self._tp_mesh, spec))
-                out.append((jk, jv))
+            for li, layer in enumerate(pools):
+                new_layer = []
+                for row, p in zip(rows, layer):
+                    if not row.paged:
+                        new_layer.append(p)
+                        continue
+                    host = np.zeros(p.shape, np.asarray(p).dtype)
+                    src = data[f"{prefix}{row.name}{li}"]
+                    at = (slice(None),) * row.block_axis
+                    for old, new in mapping.items():
+                        host[at + (new,)] = src[at + (pos[old],)]
+                    j = jnp.asarray(host)
+                    if self._tp_mesh is not None:
+                        from jax.sharding import (NamedSharding,
+                                                  PartitionSpec)
+                        from . import tp as _tp
+                        spec = _tp.pool_spec() if sharded \
+                            else PartitionSpec()
+                        j = jax.device_put(
+                            j, NamedSharding(self._tp_mesh, spec))
+                    new_layer.append(j)
+                out.append(tuple(new_layer))
             return out
 
-        self.pools = install(self.pools, "", sharded=True)
+        self.pools = install(self.pools, self.cache.rows, "", sharded=True)
         if self.dpools is not None:
-            self.dpools = install(self.dpools, "d", sharded=False)
+            self.dpools = install(self.dpools, self.draft_cache.rows, "d",
+                                  sharded=False)
         return n
 
     def _mark_ready(self) -> None:
@@ -3834,10 +3900,14 @@ class ServingEngine:
                 "misses": self.prefix.misses,
                 "blocks_shared": self.prefix.blocks_shared,
                 "evictions": self.prefix.evictions,
-                "reclaimable_blocks": reclaimable}
+                "reclaimable_blocks": reclaimable,
+                "hit_tokens": self.prefix.blocks_shared * self.bs}
             if self._prefix_import_info is not None:
                 out["prefix_cache"]["import"] = \
                     dict(self._prefix_import_info)
+        state = self.cache_state()
+        if state:
+            out["cache_state"] = state
         if self._warmup_info is not None:
             out["warmup"] = {k: self._warmup_info[k] for k in
                              ("warmup_s", "programs", "aot_programs")}

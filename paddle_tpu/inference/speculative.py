@@ -168,8 +168,8 @@ def _draft_phase(eng, dpools, tables, seq_lens, last_tok, do_sample,
 
     def body(carry, _):
         pools, lens, last = carry
-        views = [PagedKVCache.from_parts(kk, vv, tables, lens, eng.bs)
-                 for kk, vv in pools]
+        views = [PagedKVCache.from_parts(*layer, tables, lens, eng.bs)
+                 for layer in pools]
         with no_grad():
             logits_t, new_views = eng.draft.forward_with_cache(
                 Tensor._wrap(last[:, None]), views,
@@ -191,7 +191,7 @@ def _draft_phase(eng, dpools, tables, seq_lens, last_tok, do_sample,
         active = lens > 0
         nxt = jnp.where(active, nxt, 0)
         lens = jnp.where(active, lens + 1, 0)
-        new_pools = [(c.k, c.v) for c in new_views]
+        new_pools = [c.pools for c in new_views]
         return (new_pools, lens, nxt), (nxt, probs)
 
     (dpools, _, _), (toks, probs) = jax.lax.scan(
@@ -248,14 +248,14 @@ def build_spec_tick(eng, k):
         # other positions' logits bit-identical either way.
         chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
                                 axis=1)
-        views = [verify_view_cls.from_parts(kk, vv, tables, seq_lens,
+        views = [verify_view_cls.from_parts(*layer, tables, seq_lens,
                                             eng.bs)
-                 for kk, vv in pools]
+                 for layer in pools]
         with no_grad():
             logits_t, new_views = eng.model.forward_with_cache(
                 Tensor._wrap(chunk), views,
                 pos_offset=Tensor._wrap(seq_lens[:, None]))
-        pools = [(c.k, c.v) for c in new_views]
+        pools = [c.pools for c in new_views]
         out = _finish(eng, logits_t._value, dtoks, dprobs, do_sample,
                       temperature, top_k, top_p, seeds, seq_lens, kcap)
         return out + (pools, dpools)
@@ -314,14 +314,14 @@ def build_hostdraft_tick(eng, k):
         eng._bind_params(param_vals)
         chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
                                 axis=1)
-        views = [verify_view_cls.from_parts(kk, vv, tables, seq_lens,
+        views = [verify_view_cls.from_parts(*layer, tables, seq_lens,
                                             eng.bs)
-                 for kk, vv in pools]
+                 for layer in pools]
         with no_grad():
             logits_t, new_views = eng.model.forward_with_cache(
                 Tensor._wrap(chunk), views,
                 pos_offset=Tensor._wrap(seq_lens[:, None]))
-        pools = [(c.k, c.v) for c in new_views]
+        pools = [c.pools for c in new_views]
         logits = logits_t._value
         dprobs = jax.nn.one_hot(dtoks, logits.shape[-1],
                                 dtype=jnp.float32)

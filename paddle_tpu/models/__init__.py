@@ -5,3 +5,5 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,  # noqa: F401
 from .bert import (BertConfig, BertForMaskedLM,  # noqa: F401
                    BertForSequenceClassification, BertModel, bert_base,
                    bert_tiny)
+from .glm_moe_dsa import (GlmMoeDsaConfig, GlmMoeDsaForCausalLM,  # noqa: F401
+                          glm_moe_dsa_tiny)

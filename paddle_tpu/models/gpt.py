@@ -302,6 +302,15 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             (batch_size, 0, cfg.num_heads, hd), dtype))
         return [(empty(), empty()) for _ in range(cfg.num_layers)]
 
+    def cache_spec(self):
+        """What `ServingEngine` caches a layer: a (K, V) pair of pools,
+        one row of `head_dim` a query head and token (GQA models cache
+        the repeated heads)."""
+        from .kv_cache import kv_cache_spec
+        cfg = self.cfg
+        return kv_cache_spec(cfg.num_layers, cfg.num_heads,
+                             cfg.hidden_size // cfg.num_heads)
+
     def forward_with_cache(self, input_ids, caches, pos_offset=0):
         h, new_caches = self.gpt(input_ids, kv_caches=caches,
                                  pos_offset=pos_offset)

@@ -20,6 +20,7 @@ is the bandwidth-optimal variant of the same idea.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -27,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["StaticKVCache", "PagedKVCache", "PagedChunkView",
-           "PagedChunkKernelView", "PagedVerifyKernelView"]
+           "PagedChunkKernelView", "PagedVerifyKernelView", "PoolRow",
+           "CacheSpec", "kv_cache_spec", "LatentPagedCache"]
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -143,6 +145,11 @@ class PagedKVCache:
         c.k, c.v, c.tables, c.seq_lens, c.bs = k, v, tables, seq_lens, \
             block_size
         return c
+
+    @property
+    def pools(self):
+        """The layer's device arrays, in its `CacheSpec.rows` order."""
+        return (self.k, self.v)
 
     def update_and_attend(self, q, k, v):
         """q/k/v: jnp [B, s, nh, hd] (post-RoPE).  s == 1 -> paged decode
@@ -280,6 +287,144 @@ class PagedVerifyKernelView(PagedChunkKernelView):
         from ..ops import pallas_paged
         return pallas_paged.paged_verify_attention(
             q, new.k, new.v, self.tables, self.seq_lens)
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolRow:
+    """One device array of a layer's cache.  A paged row is a pool
+    `lead + (num_blocks + 1, block_size) + trail` under the layer's block
+    table (heads lead a (K, V) pool; a latent pool has none).  A row with
+    `paged=False` is per-layer device state of the fixed shape `lead`
+    that the programs thread and donate with the pools but no block
+    refers to: copy-on-write and the prefix cache pass it over, the tick
+    program hands a copy of it back with its tokens and the engine keeps
+    that copy on the host (`stats()["cache_state"]`)."""
+    name: str
+    lead: tuple = ()
+    trail: tuple = ()
+    dtype: object = None          # None: the model's parameter dtype
+    paged: bool = True
+
+    @property
+    def block_axis(self) -> int:
+        return len(self.lead)
+
+    def shape(self, num_blocks: int, block_size: int) -> tuple:
+        if not self.paged:
+            return tuple(self.lead)
+        return tuple(self.lead) + (num_blocks + 1, block_size) \
+            + tuple(self.trail)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What `ServingEngine` needs to know of a model's cache, from
+    `model.cache_spec()`: the arrays a layer keeps (`rows`, the same for
+    every layer) and the views its programs hand `forward_with_cache`.
+    Every view class is built by `from_parts(*layer_arrays, tables,
+    seq_lens, block_size)` and gives the arrays back as `.pools`.  A
+    cache with one attention path for every program names `view` alone.
+    `unsupported` names the engine mechanisms this cache cannot run
+    under, with the reason: the engine raises at construction.
+    `attend_limit` is the size of a sparse selection, for the spans'
+    `selected_tokens`."""
+    num_layers: int
+    rows: tuple
+    view: type                    # decode step, prefill from empty
+    chunk_view: type = None       # a chunk at an offset, plain XLA
+    chunk_kernel_view: type = None   # ... through the Pallas chunk kernel
+    verify_kernel_view: type = None  # spec-decode verify
+    unsupported: dict = dataclasses.field(default_factory=dict)
+    attend_limit: int = 0         # tokens a query attends at most; 0: all
+
+    def __post_init__(self):
+        for name in ("chunk_view", "chunk_kernel_view",
+                     "verify_kernel_view"):
+            if getattr(self, name) is None:
+                object.__setattr__(self, name, self.view)
+
+    def init_pools(self, num_blocks, block_size, dtype, place=None):
+        """Zeroed arrays of every layer; `place(row, array)` may move or
+        shard one."""
+        def one(row):
+            z = jnp.zeros(row.shape(num_blocks, block_size),
+                          row.dtype or dtype)
+            return z if place is None else place(row, z)
+        return [tuple(one(r) for r in self.rows)
+                for _ in range(self.num_layers)]
+
+
+def kv_cache_spec(num_layers: int, num_heads: int, head_dim: int):
+    """The (K, V) cache of the GPT / Llama families: two pools a layer,
+    heads leading, one row of `head_dim` a head and token."""
+    row = dict(lead=(num_heads,), trail=(head_dim,))
+    return CacheSpec(num_layers, (PoolRow("k", **row), PoolRow("v", **row)),
+                     PagedKVCache, PagedChunkView, PagedChunkKernelView,
+                     PagedVerifyKernelView)
+
+
+class LatentPagedCache:
+    """A layer's cache under latent attention with a learned sparse
+    index (MLA + DSA): a pool of latent rows (`c_kv | k_rope`, one a
+    token, shared by every head), a pool of the indexer's keys beside it
+    under the SAME block table, and `moe_rows`, the layer's count of the
+    rows its held experts were given (device state, not paged).
+
+    One class serves every program: a decode step, a chunk at an offset
+    and a prefill from empty all append `s` rows at `seq_lens` and attend
+    each query over its own selection (`ops/sparse_mla.py`)."""
+
+    def __init__(self, ckv, kidx, moe_rows, tables, seq_lens, block_size):
+        self.ckv, self.kidx, self.moe_rows = ckv, kidx, moe_rows
+        self.tables, self.seq_lens, self.bs = tables, seq_lens, block_size
+
+    from_parts = classmethod(lambda cls, *a: cls(*a))
+
+    @property
+    def pools(self):
+        return (self.ckv, self.kidx, self.moe_rows)
+
+    @property
+    def active(self):
+        """`[B]`: which sequences of the batch are real.  An idle slot of
+        a decode step has a zero table row (its writes go to the pad
+        block 0); a chunk's or a prefill's row begins with a real block."""
+        return self.tables[:, 0] != 0
+
+    def replace(self, **kw):
+        new = LatentPagedCache(*self.pools, self.tables, self.seq_lens,
+                               self.bs)
+        for k, v in kw.items():
+            setattr(new, k, v)
+        return new
+
+    def append_and_attend(self, q_cat, q_idx, w_idx, row, kidx_row, *,
+                          topk, scale, d_latent):
+        """Append `row` `[B, s, d_latent + rope]` and `kidx_row`
+        `[B, s, Di]` at `seq_lens`, then attend `q_cat` `[B, s, nh,
+        d_latent + rope]` over the rows the indexer (`q_idx` `[B, s, Hi,
+        Di]`, `w_idx` `[B, s, Hi]`) selects.  Returns (advanced view,
+        `sum p c_kv` `[B, s, nh, d_latent]` float32, selected positions
+        `[B, s, k]` with -1 where fewer than k exist)."""
+        from ..ops import sparse_mla
+        s = row.shape[1]
+        start = self.seq_lens
+        new = self.replace(
+            ckv=sparse_mla.write_rows(self.ckv, self.tables, start, row),
+            kidx=sparse_mla.write_rows(self.kidx, self.tables, start,
+                                       kidx_row),
+            seq_lens=start + s)
+        pos = start[:, None] + jnp.arange(s, dtype=start.dtype)
+        o, idx, valid = sparse_mla.sparse_latent_attention(
+            q_cat, q_idx, w_idx, new.ckv, new.kidx, self.tables, pos,
+            topk=topk, scale=scale, d_latent=d_latent)
+        return new, o, jnp.where(valid, idx, -1)
+
+
+jax.tree_util.register_pytree_node(
+    LatentPagedCache,
+    lambda c: ((c.ckv, c.kidx, c.moe_rows, c.tables, c.seq_lens), c.bs),
+    lambda bs, ch: LatentPagedCache(*ch, bs))
 
 
 def _dense_causal(q, k, v):

@@ -54,7 +54,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_common
 
 __all__ = ["routing_indices", "moe_dispatch", "moe_combine",
-           "moe_dispatch_reference", "moe_combine_reference"]
+           "moe_dispatch_reference", "moe_combine_reference",
+           "grouped_matmul", "grouped_matmul_reference"]
 
 
 # destination rows per grid step (that many row copies in flight, k times
@@ -307,3 +308,53 @@ def moe_combine_reference(expert_rows, w, flat):
     out = jnp.sum(w[:, :, None].astype(jnp.float32)
                   * gathered.astype(jnp.float32), axis=1)
     return out.astype(expert_rows.dtype)
+
+
+# ---- grouped matmul over the experts held (dropless, expert-parallel)
+
+_GMM_TILE = (128, 1024, 1024)      # rows, contraction, columns
+
+
+def grouped_matmul(lhs, rhs, group_sizes, group_offset: int = 0,
+                   interpret=None):
+    """Rows sorted by group times their group's matrix, for the groups
+    held here: `lhs` `[m, k]` holds `group_sizes[0]` rows of group 0, then
+    group 1's, ... over ALL `G` groups (the router's width, and any
+    trailing group of rows that belong to no expert); `rhs`
+    `[held, k, n]` holds the matrices of groups `group_offset ..
+    group_offset + held`.  Returns `[m, n]` float32 with zeros in the
+    rows of groups not held.  The kernel is jax's own Pallas grouped
+    matmul (`pallas.ops.tpu.megablox.gmm`): its grid visits only the
+    row tiles of non-empty held groups, so an expert that got no row
+    costs no read of its weights."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    if interpret is None:
+        interpret = pallas_common.interpret_default()
+    pallas_common.claim("moe_grouped_matmul", interpret)
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm = _GMM_TILE[0] if m >= _GMM_TILE[0] else -(-m // 16) * 16
+    pad = -m % tm
+    if pad:
+        # pad rows join the last group: zero rows, cut off below
+        lhs = jnp.concatenate([lhs, jnp.zeros((pad, k), lhs.dtype)])
+        group_sizes = group_sizes.at[-1].add(pad)
+    out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+              preferred_element_type=jnp.float32,
+              tiling=(tm, min(k, _GMM_TILE[1]), min(n, _GMM_TILE[2])),
+              group_offset=jnp.int32(group_offset), interpret=interpret)
+    return out[:m]
+
+
+def grouped_matmul_reference(lhs, rhs, group_sizes, group_offset: int = 0):
+    """Pure-XLA oracle of `grouped_matmul`: every held matrix against
+    every row, masked."""
+    ends = jnp.cumsum(group_sizes)
+    gid = jnp.searchsorted(ends, jnp.arange(lhs.shape[0]), side="right")
+    local = gid - group_offset
+    ok = (local >= 0) & (local < rhs.shape[0])
+    w = jnp.take(rhs, jnp.clip(local, 0, rhs.shape[0] - 1), axis=0)
+    out = jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32),
+                     w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(ok[:, None], out, 0.0)

@@ -373,10 +373,13 @@ def paged_write_chunk(k_pool, v_pool, tables, start_lens, k, v):
     return jax.lax.fori_loop(0, B * n, merge, (k_pool, v_pool))
 
 
-def paged_copy_block(pool, src, dst):
+def paged_copy_block(pool, src, dst, block_axis: int = 1):
     """Physical block `src` of `pool` copied over block `dst`, in place
-    (the serving engine's copy-on-write; src/dst traced scalars)."""
-    return _put(pool, _block_of(pool, src), dst)
+    (the serving engine's copy-on-write; src/dst traced scalars).
+    `block_axis` is where the pool keeps its blocks: 1 behind the heads
+    of a (K, V) pool, 0 in a latent pool."""
+    block = jax.lax.dynamic_slice_in_dim(pool, src, 1, block_axis)
+    return jax.lax.dynamic_update_slice_in_dim(pool, block, dst, block_axis)
 
 
 def _chunk_grid_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,

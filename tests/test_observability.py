@@ -184,22 +184,25 @@ def test_dispatch_instrumentation():
 def test_jit_compile_metrics():
     from paddle_tpu.jit import to_static
 
+    # a name of its own: the compile tracker is kept by name for the whole
+    # process, and other test files capture functions called `f`
     @to_static
-    def f(a):
+    def obs_compile_probe(a):
         return a * 2 + 1
 
+    fn = "obs_compile_probe"
     x = paddle.to_tensor(np.ones((3,), np.float32))
-    f(x)
-    f(x)  # cache hit: no new trace
+    obs_compile_probe(x)
+    obs_compile_probe(x)  # cache hit: no new trace
     traces = metrics.get("jit.traces")
-    assert traces.value(fn="f") == 1
+    assert traces.value(fn=fn) == 1
     comp = metrics.get("jit.compile_seconds")
     # the four capture stages, each once, and the compile tracker's
     # seconds are their sum
     stages = ("discover", "trace_lower", "compile", "first_run")
-    assert [comp.count(fn="f", stage=s) for s in stages] == [1, 1, 1, 1]
-    assert obs.compile_tracker.get("f")["seconds_total"] == pytest.approx(
-        sum(comp.sum(fn="f", stage=s) for s in stages), rel=1e-6)
+    assert [comp.count(fn=fn, stage=s) for s in stages] == [1, 1, 1, 1]
+    assert obs.compile_tracker.get(fn)["seconds_total"] == pytest.approx(
+        sum(comp.sum(fn=fn, stage=s) for s in stages), rel=1e-6)
 
 
 def test_collective_instrumentation():

@@ -355,7 +355,8 @@ def test_corrupt_export_skipped_with_counter_and_fallback(model):
     probe = ServingEngine(model, max_batch=2, max_context=64,
                           block_size=16, prefix_cache=True)
     meta = probe._prefix_fingerprint()
-    nh, bs, hd = probe.nh, probe.bs, probe.hd
+    (nh,), (hd,), bs = probe.cache.rows[0].lead, probe.cache.rows[0].trail, \
+        probe.bs
     layers = probe.model.cfg.num_layers
     dtype = np.asarray(probe.pools[0][0]).dtype
 

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops import pallas_common, pallas_flash, pallas_moe
+from paddle_tpu.ops import (pallas_common, pallas_dsa, pallas_flash,
+                            pallas_moe, sparse_mla)
 from paddle_tpu.ops import pallas_paged as pp
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -76,6 +77,21 @@ def _flash_loss(causal, rate):
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
+def _mosaic(fn):
+    """`fn` traced with every kernel it reaches compiled by Mosaic (the
+    wrappers ask `interpret_default()`, which says "interpret" on this
+    sandbox's CPU backend)."""
+    @functools.wraps(fn)
+    def traced(*args):
+        saved = pallas_common.interpret_default
+        pallas_common.interpret_default = lambda: False
+        try:
+            return fn(*args)
+        finally:
+            pallas_common.interpret_default = saved
+    return traced
+
+
 def _cases():
     """name -> (function, argument shapes): every default-on kernel at
     Phase 3's shapes.  bf16 pools are what a deployment serves (the
@@ -122,6 +138,29 @@ def _cases():
             functools.partial(pp.paged_chunk_attention, interpret=False),
             (((1, s, nh, hd), dt), pool, pool,
              ((1, s // 64), i32), ((1,), i32)))
+    # GLM-5's expert layer at its published widths: a decode step's rows
+    # (16 slots x 8 choices) and a 512-token chunk's, over 16 held of 256
+    # (257 groups: the rows of idle slots sort into one that no chip holds)
+    for rows in (128, 4096):
+        for k, n in ((6144, 2048), (2048, 6144)):
+            cases[f"moe_grouped_matmul m{rows} k{k} n{n} bf16"] = (
+                functools.partial(pallas_moe.grouped_matmul,
+                                  group_offset=80, interpret=False),
+                (((rows, k), BF16), ((16, k, n), BF16), ((257,), i32)))
+    # ... and its sparse selection inside paged attention: a decode step
+    # over 16 contexts of 32k through both pools (the indexer's scores by
+    # the `dsa_index_scores` kernel, the rest plain XLA), and the kernel
+    nb = 512
+    cases["dsa_index_scores B16 ctx32k bf16"] = (
+        functools.partial(pallas_dsa.index_scores_decode, interpret=False),
+        (((16, 1, 32, 128), BF16), ((16, 1, 32), BF16),
+         ((6145, 64, 128), BF16), ((16, nb), i32), ((16, 1), i32)))
+    cases["sparse_latent_attention decode B16 ctx32k bf16"] = (
+        _mosaic(functools.partial(sparse_mla.sparse_latent_attention,
+                                  topk=2048, scale=1 / 16, d_latent=512)),
+        (((16, 1, 64, 576), BF16), ((16, 1, 32, 128), BF16),
+         ((16, 1, 32), BF16), ((6145, 64, 640), BF16),
+         ((6145, 64, 128), BF16), ((16, nb), i32), ((16, 1), i32)))
     B, S, nh, hd = 2, 1024, 12, 64
     kv = ((B, S, nh // 4, hd), BF16)            # GQA: 3 kv heads for 12
     cases["flash fwd+bwd kv_mask dropout gqa bf16"] = (
@@ -175,6 +214,7 @@ KERNEL_CASES = {
     "paged_decode": "paged_attention nh16 hd128 bfloat16",
     "paged_chunk_prefill": "paged_chunk_attention s1024 nh16 hd128 bfloat16",
     "paged_spec_verify": "paged_verify_attention k4 nh16 hd128 bfloat16",
+    "dsa_index_scores": "dsa_index_scores B16 ctx32k bf16",
 }
 
 
