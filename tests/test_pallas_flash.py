@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.pallas_flash import (flash_attention,
+from paddle_tpu.ops import pallas_flash
+from paddle_tpu.ops.pallas_flash import (block_plan, flash_attention,
                                          flash_attention_fwd, supported)
 
 
@@ -239,3 +240,200 @@ def test_sdpa_routes_padding_mask_to_kernel(monkeypatch):
     want = ref_attn(q, k, v, False, jnp.asarray(keep.astype(np.int32)))
     np.testing.assert_allclose(np.asarray(out._value), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------ bf16 operands (PR 29)
+
+def _xla_bf16_attn(q, k, v):
+    """Causal attention as `sdpa_xla` computes it under amp O1: the dots
+    take the bf16 operands and accumulate in float32, the softmax is
+    float32, the probabilities are rounded to bf16 for `p @ v`."""
+    S, hd = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) / np.sqrt(hd)
+    s = jnp.where(jnp.arange(S)[:, None] >= jnp.arange(S)[None, :],
+                  s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+# The parent's kernels (float32 operands in three of the backward's
+# matmuls, the scale on the scores) on these inputs, blocks of 128,
+# against the float32 reference, as ||got - want|| / ||want|| over three
+# seeds: out 0.00197-0.00201, dq 0.00246-0.00258, dk 0.00178-0.00187,
+# dv 0.00162-0.00168 at both head widths.  This PR's: the same out and dq
+# at heads of 64 (a scale of 1/8 folds into bf16 q exactly), dk
+# 0.00240-0.00252, dv 0.00222-0.00232: `ds` and `p` are rounded to bf16
+# before `ds^T q` and `p^T dO`, as `flash_fwd` rounds `p` and
+# `flash_bwd_dq` rounds `ds`.  At heads of 128 q * 128^-1/2 is one more
+# bf16 rounding of q: out 0.00239-0.00247, dq 0.00276-0.00304, dk
+# 0.00274-0.00300, dv 0.00268-0.00276.  The XLA form above reads
+# 0.0022-0.0024 on all four.  A limit is the parent's largest plus one
+# bf16 rounding, 2^-9.
+_PARENT_ERR = {"out": 0.00201, "dq": 0.00258, "dk": 0.00187, "dv": 0.00168}
+_BF16_ULP = 2.0 ** -9
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_bf16_gradients_keep_the_parents_distance(hd):
+    """bf16 operands into every matmul, float32 accumulation: S=512 in
+    blocks of 128 has 6 interior, 4 diagonal and 6 skipped blocks a head."""
+    plan = block_plan(512, 512, hd, True, "dkv", False, 128, 128)
+    assert plan == (128, 128, 6, 4, 6)
+    rng = np.random.RandomState(hd)
+    mk = lambda: jnp.asarray(rng.randn(1, 512, 2, hd), jnp.bfloat16)
+    q, k, v, g = mk(), mk(), mk(), mk()
+    out, lse = flash_attention_fwd(q, k, v, True, True,
+                                   block_q=128, block_k=128)
+    res = (q, k, v, out, lse, pallas_flash._mask_arr(None, 1, 512),
+           pallas_flash._seed_arr(None))
+    got = (out,) + tuple(pallas_flash._flash_bwd(
+        True, True, None, 0.0, res, g, block_q=128, block_k=128)[:3])
+    assert all(x.dtype == jnp.bfloat16 for x in got)
+    f32 = lambda x: x.astype(jnp.float32)
+    o32, vjp32 = jax.vjp(lambda q, k, v: ref_attn(q, k, v, True),
+                         f32(q), f32(k), f32(v))
+    o16, vjp16 = jax.vjp(_xla_bf16_attn, q, k, v)
+    for name, a, w32, w16 in zip(("out", "dq", "dk", "dv"), got,
+                                 (o32,) + vjp32(f32(g)),
+                                 (o16,) + vjp16(g)):
+        assert _rel(a, w32) <= _PARENT_ERR[name] + _BF16_ULP, name
+        # two computations in bf16 operands differ by both's roundings
+        assert _rel(a, w16) <= 2.5 * _BF16_ULP, name
+
+
+# ------------------------------------------------ block_plan (PR 29)
+
+_PLAN_CASES = [
+    # Sq, Sk, hd, causal, forced (block_q, block_k) or None
+    (2048, 2048, 64, True, None),
+    (2048, 2048, 128, True, None),
+    (2048, 2048, 256, True, None),
+    (2048, 2048, 64, False, None),
+    (1024, 1024, 64, True, None),
+    (512, 512, 128, True, (128, 128)),
+    (512, 512, 64, True, (128, 256)),       # bq != bk
+    (512, 512, 64, True, (256, 128)),
+    (256, 1024, 64, True, (128, 256)),      # Sq < Sk: offset > 0
+    (1024, 256, 64, True, (256, 128)),      # Sq > Sk: offset < 0
+    (64, 256, 64, True, None),
+    (384, 384, 128, True, None),            # 128-multiple, not a power of 2
+    (8, 8, 64, True, None),
+    (8, 128, 64, True, None),
+    (4096, 4096, 128, True, None),
+]
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("Sq,Sk,hd,causal,forced", _PLAN_CASES)
+def test_block_plan_divides_and_classifies(Sq, Sk, hd, causal, forced, kind):
+    assert supported((1, Sq, 2, hd), (1, Sk, 2, hd))
+    plan = block_plan(Sq, Sk, hd, causal, kind, False, *(forced or ()))
+    bq, bk = plan.bq, plan.bk
+    assert Sq % bq == 0 and Sk % bk == 0
+    assert bq % 8 == 0 and (bk % 128 == 0 or bk == Sk)
+    if forced:
+        assert (bq, bk) == forced
+    nq, nk = Sq // bq, Sk // bk
+    assert plan.interior + plan.diagonal + plan.skipped == nq * nk
+    # brute force: the end-aligned causal mask, pair by pair
+    valid = np.ones((Sq, Sk), bool)
+    if causal:
+        valid = (np.arange(Sq)[:, None] + (Sk - Sq)
+                 >= np.arange(Sk)[None, :])
+    counts = {"interior": 0, "diagonal": 0, "skipped": 0}
+    for qi in range(nq):
+        for ki in range(nk):
+            blk = valid[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            cls = pallas_flash.block_class(qi * bq, ki * bk, bq, bk,
+                                           Sk - Sq, causal)
+            counts[cls] += 1
+            if cls == "interior":
+                assert blk.all()
+            elif cls == "skipped":
+                assert not blk.any()
+            else:       # the mask is needed, and the block has work
+                assert blk.any() and not blk.all()
+    assert counts == {"interior": plan.interior, "diagonal": plan.diagonal,
+                      "skipped": plan.skipped}
+
+
+@pytest.fixture
+def strips(monkeypatch):
+    """Set the strip width for one test.  The three kernel calls are
+    jitted and the width is no argument of theirs, so their traces are
+    dropped on the way in and on the way out."""
+    calls = (pallas_flash._fwd_call, pallas_flash._dq_call,
+             pallas_flash._dkv_call)
+
+    def set_width(w):
+        monkeypatch.setattr(pallas_flash, "_STRIP", w)
+        monkeypatch.setattr(pallas_flash, "_STRIP_INTERIOR", 2 * w)
+        for c in calls:
+            c.clear_cache()
+    yield set_width
+    for c in calls:
+        c.clear_cache()
+
+
+@pytest.mark.parametrize("Sq,Sk,bq,bk,strip", [
+    (256, 256, 128, 128, 256), (256, 512, 128, 256, 256),
+    (512, 256, 256, 128, 256), (256, 256, 128, 256, 256),
+    (256, 256, 256, 128, 256),
+    # strips of 128 inside blocks of 256 / 512: a block squarely on the
+    # diagonal is multiplied without its upper triangle (offset 0, > 0,
+    # < 0), any other in strips over all its rows
+    (512, 512, 256, 256, 128), (512, 512, 512, 512, 128),
+    (256, 512, 256, 256, 128), (512, 256, 256, 256, 128),
+    (512, 512, 256, 512, 128), (512, 512, 512, 256, 128)])
+def test_forced_blocks_match_reference(Sq, Sk, bq, bk, strip, strips):
+    """Interior, diagonal and skipped blocks with offset 0, > 0 and < 0 and
+    bq != bk: forward and both backward kernels against the reference
+    (float32: the tolerances of the cases above)."""
+    strips(strip)
+    tri = pallas_flash._on_diagonal(bq, bk, Sk - Sq, True)
+    assert tri == (bq == bk)
+    assert len(pallas_flash._col_strips(bq, bk, Sk - Sq, True)) == \
+        max(bk // strip, 1)
+    assert len(pallas_flash._row_strips(bq, bk, Sk - Sq, True)) == \
+        (bq // strip if tri and bq > strip else max(bq // (2 * strip), 1))
+    rng = np.random.RandomState(Sq + Sk + bq)
+    mk = lambda S: jnp.asarray(rng.randn(1, S, 2, 64).astype(np.float32))
+    q, k, v, g = mk(Sq), mk(Sk), mk(Sk), mk(Sq)
+    out, lse = flash_attention_fwd(q, k, v, True, True,
+                                   block_q=bq, block_k=bk)
+    want, vjp = jax.vjp(lambda q, k, v: ref_attn(q, k, v, True), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    res = (q, k, v, out, lse, pallas_flash._mask_arr(None, 1, Sk),
+           pallas_flash._seed_arr(None))
+    got = pallas_flash._flash_bwd(True, True, None, 0.0, res, g,
+                                  block_q=bq, block_k=bk)[:3]
+    for a, b in zip(got, vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_strips_keep_the_padding_mask(strips):
+    """A key-padding mask with causality in one block of 256 cut into
+    strips of 128: the strips slice the mask with the keys."""
+    strips(128)
+    q, k, v = _qkv(2, 256, 2, 64, seed=11)
+    rng = np.random.RandomState(11)
+    kv_mask = jnp.asarray((rng.rand(2, 256) > 0.3).astype(np.int32))
+    kv_mask = kv_mask.at[:, 0].set(1)     # no query loses every key
+    f = lambda q, k, v: jnp.sum(jnp.square(flash_attention(
+        q, k, v, True, True, kv_mask, None, (2, 256), 0.0)))
+    g = lambda q, k, v: jnp.sum(jnp.square(ref_attn(q, k, v, True, kv_mask)))
+    np.testing.assert_allclose(float(f(q, k, v)), float(g(q, k, v)),
+                               rtol=2e-5)
+    for a, b in zip(jax.grad(f, argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(g, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)

@@ -106,6 +106,13 @@ def _cases():
             qkv = ((2, S, nh, hd), dt)
             cases[f"flash fwd+bwd causal nh{nh} hd{hd} S{S} {n}"] = (
                 _flash_loss(True, 0.0), (qkv, qkv, qkv))
+        if dt is BF16:
+            # the train cell's heads (16 x 64) and the widest head
+            # `supported` admits, at the cell's sequence
+            for nh, hd in ((16, 64), (8, 256)):
+                qkv = ((1, 2048, nh, hd), dt)
+                cases[f"flash fwd+bwd causal nh{nh} hd{hd} S2048 {n}"] = (
+                    _flash_loss(True, 0.0), (qkv, qkv, qkv))
         for nh, hd in WIDTHS:
             B, bs, nb = 8, 64, 16
             pool = ((nh, B * nb + 1, bs, hd), dt)
@@ -209,6 +216,7 @@ def test_graft_entry_compiles_for_v5e(v5e, monkeypatch):
 # a name fails on the CPU and not in a chip run.
 
 _FLASH = "flash fwd+bwd causal nh16 hd128 S2048 bfloat16"
+_FLASH64 = "flash fwd+bwd causal nh16 hd64 S2048 bfloat16"   # the train cell
 KERNEL_CASES = {
     "flash_fwd": _FLASH, "flash_bwd_dq": _FLASH, "flash_bwd_dkv": _FLASH,
     "paged_decode": "paged_attention nh16 hd128 bfloat16",
@@ -224,10 +232,11 @@ def _custom_call_names(hlo_text: str) -> list:
             if 'custom_call_target="tpu_custom_call"' in ln]
 
 
-@pytest.mark.parametrize("kernel", sorted(KERNEL_CASES))
-def test_custom_call_is_named_for_its_kernel(compiled, kernel):
-    names = _custom_call_names(
-        compiled[KERNEL_CASES[kernel]].result().as_text())
+@pytest.mark.parametrize("kernel,case", sorted(
+    list(KERNEL_CASES.items())
+    + [(k, _FLASH64) for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")]))
+def test_custom_call_is_named_for_its_kernel(compiled, kernel, case):
+    names = _custom_call_names(compiled[case].result().as_text())
     assert names and any(kernel in n for n in names), names
     # ...and one kernel's name does not match another's pattern
     others = [k for k in KERNEL_CASES if k != kernel and kernel in k]
