@@ -137,13 +137,15 @@ tracker records ZERO events once ``run()`` admits traffic.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
 import threading
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from functools import partial
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import time
 
@@ -530,6 +532,56 @@ def _bucket(n: int, minimum: int) -> int:
     return b
 
 
+# What a positional argument or result of a serving program IS.  A program
+# says it once, beside its body (`_Decl`); the shard_map specs, the donated
+# positions and warm-up's inert arguments are all read off that.
+_PARAMS, _POOLS, _DPARAMS, _DPOOLS, _REP = (
+    "params", "pools", "draft_params", "draft_pools", "replicated")
+
+
+class _In(NamedTuple):
+    """A replicated scheduler input of a program (the rank-0 broadcast
+    under TP), by shape and dtype."""
+    shape: tuple
+    dtype: Any
+    ones: bool = False      # warm-up's dummy: zeros unless this says ones
+
+    def inert(self):
+        """Warm-up's argument.  Inert: all-zero tables and seq_lens route
+        every write to the reserved scratch block 0 and hold every slot
+        inactive, so warm-up is safe even mid-flight."""
+        return (jnp.ones if self.ones else jnp.zeros)(self.shape, self.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Decl:
+    """One serving program: its compile-tracker name, its body, what
+    each positional argument is (a kind above, or an `_In`) and what
+    each result is (a tuple of kinds, or the one kind of a lone result),
+    the cache and key its built callable lives under, and what a
+    compile of it is blamed on."""
+    name: str
+    body: Callable
+    args: tuple
+    outs: Any
+    cache: dict
+    key: Any
+    blame: tuple = ()
+    grid_entry: Optional[dict] = None
+
+    @property
+    def grid(self) -> dict:
+        """The program's line in `stats()["warmup"]["grid"]`."""
+        return self.grid_entry or {
+            "program": self.name.split(".", 1)[1], **dict(self.blame)}
+
+    @property
+    def donated(self) -> tuple:
+        """`donate_argnums`: the pools, which every program threads."""
+        return tuple(i for i, a in enumerate(self.args)
+                     if a in (_POOLS, _DPOOLS))
+
+
 class ServingEngine:
     """Continuous batching over a model with `forward_with_cache` +
     paged caches (GPT/Llama families).
@@ -787,6 +839,16 @@ class ServingEngine:
         self.ticks = 0
         self.tokens_out = 0
         self.steps_per_tick = max(1, int(steps_per_tick))
+        # the scheduler inputs the tick programs declare: (tables,
+        # seq_lens, last_tok), and the per-slot sampling (do_sample,
+        # temperature, top_k, top_p, seed), whose free-slot identity has
+        # ones where warm-up's dummies have them
+        B, i32 = (max_batch,), jnp.int32
+        self._sched_in = (_In((max_batch, self.nb_per_seq), i32),
+                          _In(B, i32), _In(B, i32))
+        self._samp_in = (_In(B, jnp.bool_), _In(B, jnp.float32, ones=True),
+                         _In(B, i32), _In(B, jnp.float32, ones=True),
+                         _In(B, jnp.uint32))
         self._decode_fn = None
         self._tick_fns = {}
         self._prefill_fns = {}
@@ -844,10 +906,14 @@ class ServingEngine:
         # attend through.  Snapshotted here like the pad ladder — the
         # flags must never be read under trace (graft-lint R004), and a
         # running engine's compiled grid must not shift under it.
+        pallas_prefill = _flags.get_flag("serving_pallas_prefill")
         self._chunk_view_cls = (
-            self.cache.chunk_kernel_view
-            if _flags.get_flag("serving_pallas_prefill")
+            self.cache.chunk_kernel_view if pallas_prefill
             else self.cache.chunk_view)
+        # ... and the draft model's, for its share of a chunk
+        self._draft_chunk_view_cls = None if not self.spec_model else (
+            self.draft_cache.chunk_kernel_view if pallas_prefill
+            else self.draft_cache.chunk_view)
         self._verify_view_cls = (
             self.cache.verify_kernel_view
             if _flags.get_flag("serving_pallas_verify")
@@ -998,16 +1064,44 @@ class ServingEngine:
             base = base + (("tp", self.tp),)
         return extra + base
 
-    def _shard_tp(self, fn, in_specs, out_specs):
-        """Wrap a program body in shard_map over the tp mesh.  By
-        convention the params arg takes the plan's spec tree, the pools
-        arg P('tp') (head axis), and every scheduler input P() — the
-        rank-0 broadcast.  check_vma off: replication of the outputs is
-        guaranteed by construction (every rank computes the full logits
-        after the vocab all-gather), which the rep-checker cannot always
-        prove through the sampling primitives."""
-        return jax.shard_map(fn, mesh=self._tp_mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+    def _forward(self, params):
+        """THE FORWARD SEAM: the one place that knows how this engine
+        runs its model.  Called once a program with the program's
+        parameter input, it returns ``forward(ids, pools, tables, lens,
+        pos_offset, view_cls=None) -> (logits [B, s, V], new_pools)``,
+        which the body may call any number of times (the tick calls it
+        inside its scan).
+
+        Degree 1 binds the parameters into the live model (a quantized
+        payload dequantizes here, once, outside any scan) and each call
+        goes through views over the pools.  With a TP mesh the forward
+        is `tp.forward_tp` on this rank's weight and pool shards, the
+        logits replicated by its vocab all-gather: token choice sees the
+        FULL logits, so the streams are bit-identical to degree 1.  (The
+        draft model keeps its own bind + forward: it runs replicated in
+        every mode.)"""
+        if self._tp_mesh is not None:
+            from . import tp as _tp
+
+            def forward(ids, pools, tables, lens, pos_offset,
+                        view_cls=None):
+                return _tp.forward_tp(
+                    self._tp_meta, params, ids, pools, tables, lens,
+                    pos_offset, self.bs,
+                    view_cls=view_cls or self.cache.view)
+            return forward
+        from ..framework.dygraph import no_grad
+        self._bind_params(params)
+
+        def forward(ids, pools, tables, lens, pos_offset, view_cls=None):
+            views = self._views(pools, tables, lens, view_cls)
+            if not isinstance(pos_offset, int):
+                pos_offset = Tensor._wrap(pos_offset)
+            with no_grad():
+                logits_t, new_views = self.model.forward_with_cache(
+                    Tensor._wrap(ids), views, pos_offset=pos_offset)
+            return logits_t._value, [c.pools for c in new_views]
+        return forward
 
     def _program(self, name, fn, donate, *blame):
         """Jit ``fn`` as the serving program ``name``.  The jitted
@@ -1021,29 +1115,53 @@ class ServingEngine:
         return _compile.wrap_first_call(
             jax.jit(fn, donate_argnums=donate), name, self._blame(*blame))
 
+    def _build(self, decl: _Decl):
+        """A declared program, built and cached: under a TP mesh its
+        body becomes a shard_map whose specs are the declaration's kinds
+        — the target parameters take the plan's spec tree, the target
+        pools P('tp') (the head axis), everything else P(), the rank-0
+        broadcast — and the pools are what it donates.  check_vma off:
+        replication of the outputs is guaranteed by construction (every
+        rank computes the full logits after the vocab all-gather), which
+        the rep-checker cannot always prove through the sampling
+        primitives."""
+        body = decl.body
+        if self._tp_mesh is not None:
+            from jax.sharding import PartitionSpec as _P
+            from . import tp as _tp
+            spec = {_PARAMS: self._tp_specs, _POOLS: _tp.pool_spec()}
+            of = lambda kind: spec.get(kind, _P())  # noqa: E731
+            body = jax.shard_map(
+                body, mesh=self._tp_mesh,
+                in_specs=tuple(map(of, decl.args)),
+                out_specs=(tuple(map(of, decl.outs))
+                           if isinstance(decl.outs, tuple)
+                           else of(decl.outs)),
+                check_vma=False)
+        fn = self._program(decl.name, body, decl.donated, *decl.blame)
+        fn.decl = decl
+        decl.cache[decl.key] = fn
+        return fn
+
     def _decode_program(self):
+        """The host-sampling fallback's k=1 step: it returns the logits
+        the per-row host sampler needs beside the greedy tokens."""
         if self._decode_fn is not None:
             return self._decode_fn
-        if self._tp_mesh is not None:
-            self._decode_fn = self._build_tp_decode()
-            return self._decode_fn
-        from ..framework.dygraph import no_grad
 
-        def step(param_vals, pools, tables, seq_lens, last_tok):
-            self._bind_params(param_vals)
-            views = self._views(pools, tables, seq_lens)
-            with no_grad():
-                logits_t, new_views = self.model.forward_with_cache(
-                    Tensor._wrap(last_tok[:, None]), views,
-                    pos_offset=Tensor._wrap(seq_lens[:, None]))
-            logits = logits_t._value[:, -1, :]
-            new_pools = [c.pools for c in new_views]
+        def step(params, pools, tables, seq_lens, last_tok):
+            logits, pools = self._forward(params)(
+                last_tok[:, None], pools, tables, seq_lens,
+                seq_lens[:, None])
+            logits = logits[:, -1, :]
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                logits, new_pools
+                logits, pools
 
-        self._decode_fn = self._program(
-            "serving.decode", step, (1,), ("variant", "host_sampling_k1"))
-        return self._decode_fn
+        return self._build(_Decl(
+            "serving.decode", step, (_PARAMS, _POOLS) + self._sched_in,
+            (_REP, _REP, _POOLS), vars(self), "_decode_fn",
+            (("variant", "host_sampling_k1"),),
+            {"program": "decode", "steps_per_tick": 1}))
 
     def _tick_program(self, k: int):
         """The fast-path k-step tick with ON-DEVICE sampling.
@@ -1058,29 +1176,21 @@ class ServingEngine:
         fn = self._tick_fns.get(k)
         if fn is not None:
             return fn
-        if self._tp_mesh is not None:
-            fn = self._tick_fns[k] = self._build_tp_tick(k)
-            return fn
-        from ..framework.dygraph import no_grad
 
-        def tick(param_vals, pools, tables, seq_lens, last_tok,
+        def tick(params, pools, tables, seq_lens, last_tok,
                  do_sample, temperature, top_k, top_p, seeds, tok_pos):
-            self._bind_params(param_vals)
+            forward = self._forward(params)
 
             def body(carry, j):
                 pools, lens, last = carry
-                views = self._views(pools, tables, lens)
-                with no_grad():
-                    logits_t, new_views = self.model.forward_with_cache(
-                        Tensor._wrap(last[:, None]), views,
-                        pos_offset=Tensor._wrap(lens[:, None]))
-                logits = logits_t._value[:, -1, :]
-                nxt = _next_tokens(logits, do_sample, temperature,
-                                   top_k, top_p, seeds, tok_pos, j)
+                logits, new_pools = forward(
+                    last[:, None], pools, tables, lens, lens[:, None])
+                nxt = _next_tokens(logits[:, -1, :], do_sample,
+                                   temperature, top_k, top_p, seeds,
+                                   tok_pos, j)
                 active = lens > 0
                 nxt = jnp.where(active, nxt, 0)
                 lens = jnp.where(active, lens + 1, 0)
-                new_pools = [c.pools for c in new_views]
                 return (new_pools, lens, nxt), nxt
 
             (pools, _, _), toks = jax.lax.scan(
@@ -1088,101 +1198,77 @@ class ServingEngine:
             return jnp.transpose(toks), pools, \
                 self._state_rows(pools)              # [B, k]
 
-        fn = self._tick_fns[k] = self._program(
-            "serving.tick", tick, (1,), ("steps_per_tick", k))
-        return fn
+        return self._build(_Decl(
+            "serving.tick", tick,
+            (_PARAMS, _POOLS) + self._sched_in + self._samp_in
+            + (_In((self.B,), jnp.int32),),
+            (_REP, _POOLS, _REP), self._tick_fns, k,
+            (("steps_per_tick", k),)))
 
-    # ------------------------------------------------------ TP programs
-    def _build_tp_tick(self, k: int):
-        """The k-step tick as a shard_map program: same scan/sampling
-        shape as the degree-1 tick, with the forward running on each
-        rank's weight/pool shards (`tp.forward_tp`).  Token choice sees
-        the FULL logits (replicated after the vocab all-gather), so the
-        streams are bit-identical to degree 1."""
-        from jax.sharding import PartitionSpec as _P
-        from . import tp as _tp
-        meta, bs = self._tp_meta, self.bs
-
-        def tick(params, pools, tables, seq_lens, last_tok,
-                 do_sample, temperature, top_k, top_p, seeds, tok_pos):
-            def body(carry, j):
-                pools, lens, last = carry
-                logits, pools = _tp.forward_tp(
-                    meta, params, last[:, None], pools, tables, lens,
-                    lens[:, None], bs)
-                nxt = _next_tokens(logits[:, -1, :], do_sample,
-                                   temperature, top_k, top_p, seeds,
-                                   tok_pos, j)
-                active = lens > 0
-                nxt = jnp.where(active, nxt, 0)
-                lens = jnp.where(active, lens + 1, 0)
-                return (pools, lens, nxt), nxt
-
-            (pools, _, _), toks = jax.lax.scan(
-                body, (pools, seq_lens, last_tok), jnp.arange(k))
-            return jnp.transpose(toks), pools, ()
-
-        body = self._shard_tp(
-            tick, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 9,
-            (_P(), _tp.pool_spec(), ()))
-        return self._program(
-            "serving.tick", body, (1,), ("steps_per_tick", k))
-
-    def _build_tp_decode(self):
-        from jax.sharding import PartitionSpec as _P
-        from . import tp as _tp
-        meta, bs = self._tp_meta, self.bs
-
-        def step(params, pools, tables, seq_lens, last_tok):
-            logits, pools = _tp.forward_tp(
-                meta, params, last_tok[:, None], pools, tables, seq_lens,
-                seq_lens[:, None], bs)
-            logits = logits[:, -1, :]
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                logits, pools
-
-        body = self._shard_tp(
-            step, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 3,
-            (_P(), _P(), _tp.pool_spec()))
-        return self._program(
-            "serving.decode", body, (1,), ("variant", "host_sampling_k1"))
-
-    def _prefill_program(self, L_pad: int):
-        fn = self._prefill_fns.get(L_pad)
+    def _prompt_program(self, name, cache, L_pad: int, at_offset: bool):
+        """Both prompt programs, one a pad bucket: the prompt (or the
+        chunk of one) right-padded to ``L_pad`` is written through the
+        slot's table row and the last REAL token's logits come back.
+        ``serving.prefill`` writes a whole prompt from position 0;
+        ``serving.prefill_cont`` takes one more input, the traced scalar
+        ``start``, and writes at positions start..start+true_len-1
+        through the chunk view (`_prefill_cont_program`).  A spec-model
+        engine threads (draft_params, draft_pools) through both, so the
+        draft's prompt KV lands in its pools under the same table row."""
+        fn = cache.get(L_pad)
         if fn is not None:
             return fn
-        if self._tp_mesh is not None:
-            fn = self._prefill_fns[L_pad] = self._build_tp_prefill(L_pad)
-            return fn
-        from ..framework.dygraph import no_grad
+        view_cls = self._chunk_view_cls if at_offset else None
 
-        def prefill(param_vals, pools, table_row, prompt, true_len):
-            self._bind_params(param_vals)
-            zero = jnp.zeros((1,), jnp.int32)
-            views = self._views(pools, table_row, zero)
-            with no_grad():
-                logits_t, new_views = self.model.forward_with_cache(
-                    Tensor._wrap(prompt), views, pos_offset=0)
-            # last REAL token's logits (prompt is right-padded to L_pad)
+        def prefill(params, pools, table_row, prompt, true_len, *start):
+            forward = self._forward(params)
+            if at_offset:
+                (off,) = start
+                lens = jnp.reshape(off, (1,))
+            else:
+                lens, off = jnp.zeros((1,), jnp.int32), 0
+            logits, pools = forward(prompt, pools, table_row, lens, off,
+                                    view_cls)
             row = jax.lax.dynamic_index_in_dim(
-                logits_t._value[0], true_len - 1, axis=0, keepdims=False)
-            new_pools = [c.pools for c in new_views]
-            return row, new_pools
+                logits[0], true_len - 1, axis=0, keepdims=False)
+            return row, pools
 
+        def prefill_spec(params, draft_vals, pools, dpools, table_row,
+                         prompt, true_len, *start):
+            row, pools = prefill(params, pools, table_row, prompt,
+                                 true_len, *start)
+            self._bind_draft(draft_vals)
+            dnew = self._draft_prompt_write(dpools, table_row, prompt,
+                                            *start)
+            return row, pools, dnew
+
+        i32 = jnp.int32
+        ins = (_In((1, self.nb_per_seq), i32), _In((1, L_pad), i32),
+               _In((), i32, ones=True))
+        if at_offset:
+            ins += (_In((), i32),)
         if self.spec_model:
-            def prefill_spec(param_vals, draft_vals, pools, dpools,
-                             table_row, prompt, true_len):
-                row, new_pools = prefill(param_vals, pools, table_row,
-                                         prompt, true_len)
-                self._bind_draft(draft_vals)
-                dnew = self._draft_prompt_write(dpools, table_row, prompt)
-                return row, new_pools, dnew
-            body, donate = prefill_spec, (2, 3)
+            body, state, outs = prefill_spec, \
+                (_PARAMS, _DPARAMS, _POOLS, _DPOOLS), (_REP, _POOLS, _DPOOLS)
         else:
-            body, donate = prefill, (1,)
-        fn = self._prefill_fns[L_pad] = self._program(
-            "serving.prefill", body, donate, ("L_pad", L_pad))
-        return fn
+            body, state, outs = prefill, (_PARAMS, _POOLS), (_REP, _POOLS)
+        return self._build(_Decl(name, body, state + ins, outs, cache,
+                                 L_pad, (("L_pad", L_pad),)))
+
+    def _prefill_program(self, L_pad: int):
+        return self._prompt_program("serving.prefill", self._prefill_fns,
+                                    L_pad, False)
+
+    def _prefill_cont_program(self, L_pad: int):
+        """Suffix prefill for a prefix-cache hit or a chunk of a chunked
+        prefill: the first ``start`` tokens' KV is already resident
+        through the slot's table (shared blocks, or the chunks before);
+        this program writes ONLY the suffix chunk (padded to the same
+        ladder bucket the full prefill uses — the warmup grid stays
+        enumerable).  ``start`` is a traced scalar, so one program per
+        bucket serves every split point."""
+        return self._prompt_program(
+            "serving.prefill_cont", self._prefill_cont_fns, L_pad, True)
 
     def _draft_prompt_write(self, dpools, table_row, prompt, start=None):
         """Traced helper: run the draft forward over a (padded) prompt
@@ -1196,131 +1282,13 @@ class ServingEngine:
             lens, cls, off = jnp.zeros((1,), jnp.int32), \
                 self.draft_cache.view, 0
         else:
-            lens, off = jnp.reshape(start, (1,)), Tensor._wrap(start)
-            cls = (self.draft_cache.chunk_kernel_view
-                   if _flags.get_flag("serving_pallas_prefill")
-                   else self.draft_cache.chunk_view)
+            lens, cls, off = jnp.reshape(start, (1,)), \
+                self._draft_chunk_view_cls, Tensor._wrap(start)
         dviews = self._views(dpools, table_row, lens, cls)
         with no_grad():
             _, dnew = self.draft.forward_with_cache(
                 Tensor._wrap(prompt), dviews, pos_offset=off)
         return [c.pools for c in dnew]
-
-    def _build_tp_prefill(self, L_pad: int):
-        from jax.sharding import PartitionSpec as _P
-        from . import tp as _tp
-        meta, bs = self._tp_meta, self.bs
-
-        def prefill(params, pools, table_row, prompt, true_len):
-            zero = jnp.zeros((1,), jnp.int32)
-            logits, pools = _tp.forward_tp(
-                meta, params, prompt, pools, table_row, zero, 0, bs)
-            row = jax.lax.dynamic_index_in_dim(
-                logits[0], true_len - 1, axis=0, keepdims=False)
-            return row, pools
-
-        if self.spec_model:
-            def prefill_spec(params, draft_vals, pools, dpools,
-                             table_row, prompt, true_len):
-                row, pools = prefill(params, pools, table_row, prompt,
-                                     true_len)
-                self._bind_draft(draft_vals)
-                dnew = self._draft_prompt_write(dpools, table_row, prompt)
-                return row, pools, dnew
-            body = self._shard_tp(
-                prefill_spec,
-                (self._tp_specs, _P(), _tp.pool_spec(), _P(), _P(),
-                 _P(), _P()),
-                (_P(), _tp.pool_spec(), _P()))
-            donate = (2, 3)
-        else:
-            body = self._shard_tp(
-                prefill,
-                (self._tp_specs, _tp.pool_spec(), _P(), _P(), _P()),
-                (_P(), _tp.pool_spec()))
-            donate = (1,)
-        return self._program(
-            "serving.prefill", body, donate, ("L_pad", L_pad))
-
-    def _prefill_cont_program(self, L_pad: int):
-        """Suffix prefill for a prefix-cache hit: the first ``start``
-        tokens' KV is already resident through the slot's table (shared
-        blocks); this program writes ONLY the suffix chunk (padded to
-        the same ladder bucket the full prefill uses — the warmup grid
-        stays enumerable) at positions start..start+true_len-1 and
-        returns the last real token's logits.  ``start`` is a traced
-        scalar, so one program per bucket serves every split point."""
-        fn = self._prefill_cont_fns.get(L_pad)
-        if fn is not None:
-            return fn
-        chunk_view_cls = self._chunk_view_cls
-
-        if self._tp_mesh is not None:
-            from jax.sharding import PartitionSpec as _P
-            from . import tp as _tp
-            meta, bs = self._tp_meta, self.bs
-
-            def cont(params, pools, table_row, suffix, true_len, start):
-                lens = jnp.reshape(start, (1,))
-                logits, pools = _tp.forward_tp(
-                    meta, params, suffix, pools, table_row, lens, start,
-                    bs, view_cls=chunk_view_cls)
-                row = jax.lax.dynamic_index_in_dim(
-                    logits[0], true_len - 1, axis=0, keepdims=False)
-                return row, pools
-
-            if self.spec_model:
-                def cont_spec(params, draft_vals, pools, dpools,
-                              table_row, suffix, true_len, start):
-                    row, pools = cont(params, pools, table_row, suffix,
-                                      true_len, start)
-                    self._bind_draft(draft_vals)
-                    dnew = self._draft_prompt_write(dpools, table_row,
-                                                    suffix, start=start)
-                    return row, pools, dnew
-                body = self._shard_tp(
-                    cont_spec,
-                    (self._tp_specs, _P(), _tp.pool_spec()) + (_P(),) * 5,
-                    (_P(), _tp.pool_spec(), _P()))
-                donate = (2, 3)
-            else:
-                body = self._shard_tp(
-                    cont, (self._tp_specs, _tp.pool_spec()) + (_P(),) * 4,
-                    (_P(), _tp.pool_spec()))
-                donate = (1,)
-            fn = self._prefill_cont_fns[L_pad] = self._program(
-                "serving.prefill_cont", body, donate, ("L_pad", L_pad))
-            return fn
-        from ..framework.dygraph import no_grad
-
-        def cont(param_vals, pools, table_row, suffix, true_len, start):
-            self._bind_params(param_vals)
-            lens = jnp.reshape(start, (1,))
-            views = self._views(pools, table_row, lens, chunk_view_cls)
-            with no_grad():
-                logits_t, new_views = self.model.forward_with_cache(
-                    Tensor._wrap(suffix), views,
-                    pos_offset=Tensor._wrap(start))
-            row = jax.lax.dynamic_index_in_dim(
-                logits_t._value[0], true_len - 1, axis=0, keepdims=False)
-            new_pools = [c.pools for c in new_views]
-            return row, new_pools
-
-        if self.spec_model:
-            def cont_spec(param_vals, draft_vals, pools, dpools,
-                          table_row, suffix, true_len, start):
-                row, new_pools = cont(param_vals, pools, table_row,
-                                      suffix, true_len, start)
-                self._bind_draft(draft_vals)
-                dnew = self._draft_prompt_write(dpools, table_row,
-                                                suffix, start=start)
-                return row, new_pools, dnew
-            body, donate = cont_spec, (2, 3)
-        else:
-            body, donate = cont, (1,)
-        fn = self._prefill_cont_fns[L_pad] = self._program(
-            "serving.prefill_cont", body, donate, ("L_pad", L_pad))
-        return fn
 
     def _cow_program(self):
         """Copy-on-write block copy: duplicate physical block ``src``
@@ -1328,7 +1296,8 @@ class ServingEngine:
         src/dst are traced scalars).  Admission uses it when a shared
         block must receive the recomputed last prompt token.  With spec
         decode the draft pools share the block ids, so the same program
-        copies the draft layers too."""
+        copies the draft layers too.  (Warm-up copies block 0 onto
+        itself.)"""
         if self._cow_fn is not None:
             return self._cow_fn
 
@@ -1340,25 +1309,17 @@ class ServingEngine:
                           for row, p in zip(rows, layer))
                     for layer in pools]
 
+        def cow_spec(pools, dpools, src, dst):
+            return cow(pools, src, dst), \
+                cow(dpools, src, dst, self.draft_cache.rows)
+
+        blocks = (_In((), jnp.int32),) * 2
         if self.spec_model:
-            def body(pools, dpools, src, dst):
-                return cow(pools, src, dst), \
-                    cow(dpools, src, dst, self.draft_cache.rows)
-            donate = (0, 1)
+            body, state, outs = cow_spec, (_POOLS, _DPOOLS), (_POOLS, _DPOOLS)
         else:
-            body, donate = cow, (0,)
-        if self._tp_mesh is not None:
-            from jax.sharding import PartitionSpec as _P
-            from . import tp as _tp
-            if self.spec_model:
-                body = self._shard_tp(
-                    body, (_tp.pool_spec(), _P(), _P(), _P()),
-                    (_tp.pool_spec(), _P()))
-            else:
-                body = self._shard_tp(body, (_tp.pool_spec(), _P(), _P()),
-                                      _tp.pool_spec())
-        self._cow_fn = self._program("serving.cow", body, donate)
-        return self._cow_fn
+            body, state, outs = cow, (_POOLS,), _POOLS
+        return self._build(_Decl("serving.cow", body, state + blocks, outs,
+                                 vars(self), "_cow_fn"))
 
     def _spec_program(self, k: int):
         """The compiled MODEL-draft speculative tick for ladder rung
@@ -1376,20 +1337,12 @@ class ServingEngine:
         if fn is not None:
             return fn
         from . import speculative as _spec
-        if self._tp_mesh is not None:
-            from jax.sharding import PartitionSpec as _P
-            from . import tp as _tp
-            body = self._shard_tp(
-                _spec.build_tp_spec_tick(self, k),
-                (self._tp_specs, _P(), _tp.pool_spec(), _P())
-                + (_P(),) * 9,
-                (_P(),) * 5 + (_tp.pool_spec(), _P()))
-        else:
-            body = _spec.build_spec_tick(self, k)
-        fn = self._spec_fns[k] = self._program(
-            "serving.spec_tick", body, (2, 3),
-            ("spec_k", k), ("draft", "model"))
-        return fn
+        return self._build(_Decl(
+            "serving.spec_tick", _spec.build_spec_tick(self, k),
+            (_PARAMS, _DPARAMS, _POOLS, _DPOOLS) + self._sched_in
+            + self._samp_in + (_In((self.B,), jnp.int32),),
+            (_REP,) * 5 + (_POOLS, _DPOOLS), self._spec_fns, k,
+            (("spec_k", k), ("draft", "model"))))
 
     def _spec_hd_program(self, k: int):
         """The compiled HOST-draft (ngram) speculative tick for ladder
@@ -1403,18 +1356,13 @@ class ServingEngine:
         if fn is not None:
             return fn
         from . import speculative as _spec
-        if self._tp_mesh is not None:
-            from jax.sharding import PartitionSpec as _P
-            from . import tp as _tp
-            body = self._shard_tp(
-                _spec.build_tp_hostdraft_tick(self, k),
-                (self._tp_specs, _tp.pool_spec()) + (_P(),) * 10,
-                (_P(),) * 5 + (_tp.pool_spec(),))
-        else:
-            body = _spec.build_hostdraft_tick(self, k)
-        fn = self._spec_hd_fns[k] = self._program(
-            "serving.spec_tick", body, (1,), ("spec_k", k), ("draft", "ngram"))
-        return fn
+        return self._build(_Decl(
+            "serving.spec_tick", _spec.build_hostdraft_tick(self, k),
+            (_PARAMS, _POOLS) + self._sched_in
+            + (_In((self.B, k), jnp.int32),) + self._samp_in
+            + (_In((self.B,), jnp.int32),),
+            (_REP,) * 5 + (_POOLS,), self._spec_hd_fns, k,
+            (("spec_k", k), ("draft", "ngram"))))
 
     # -------------------------------------------------------------- warmup
     def _warm_call(self, fn, args, aot, install):
@@ -1460,25 +1408,72 @@ class ServingEngine:
                     return _c(*a)
                 shim.__wrapped__ = inner
                 shim._xray_entry = entry
+                shim.decl = fn.decl
                 install(shim)
                 return out, True
             except Exception:  # noqa: BLE001 - AOT is an optimization;
                 pass           # the jit path below always works
         return fn(*args), False
 
+    def _grid(self):
+        """Every program this engine can ever dispatch, in warm-up
+        order, each as the accessor call that builds it: one tick per
+        tick size in {steps_per_tick, 1} (greedy and sampled decode
+        share each — per-slot sampling params are device inputs and both
+        `lax.cond` branches compile), the host-sampling k=1 decode
+        program, one spec tick per LADDER rung (adaptive k steps between
+        warmed programs, never into a compile), and the prompt programs
+        per pad-ladder bucket: the whole-prompt prefill unless the
+        engine is CHUNKED (``prefill_chunk > 0``: every admission then
+        runs the suffix-prefill programs, so the grid swaps one family
+        for the other), the suffix prefill where a prefix hit or a chunk
+        can ask for it, and the CoW block copy beside the prefix cache.
+        BOTH sampling variants are here regardless of the current
+        ``FLAGS_serving_device_sampling``: the flag is read live at
+        every dispatch, so a mid-run flip must not route traffic to an
+        un-warmed program."""
+        grid = [partial(self._tick_program, k)
+                for k in sorted({self.steps_per_tick, 1}, reverse=True)]
+        grid.append(self._decode_program)
+        if self.spec:
+            spec_program = (self._spec_program if self.spec_model
+                            else self._spec_hd_program)
+            grid += [partial(spec_program, sk) for sk in self.spec_ladder]
+        if self.chunk <= 0:
+            grid += [partial(self._prefill_program, L)
+                     for L in self.pad_ladder]
+        if self.prefix is not None or self.chunk > 0:
+            grid += [partial(self._prefill_cont_program, L)
+                     for L in self.pad_ladder]
+        if self.prefix is not None:
+            grid.append(self._cow_program)
+        return grid
+
+    def _inert_args(self, decl: _Decl, param_vals, draft_vals=None):
+        """The arguments warm-up calls a program with: the engine's own
+        parameters and pools in the places the declaration names, an
+        inert dummy for every scheduler input."""
+        state = {_PARAMS: param_vals, _POOLS: self.pools,
+                 _DPARAMS: draft_vals, _DPOOLS: self.dpools}
+        return tuple(a.inert() if isinstance(a, _In) else state[a]
+                     for a in decl.args)
+
+    def _keep_pools(self, decl: _Decl, out) -> None:
+        """The pools a program was given are donated to it: keep the
+        ones it returns in their place."""
+        outs, out = (decl.outs, out) if isinstance(decl.outs, tuple) \
+            else ((decl.outs,), (out,))
+        for kind, val in zip(outs, out):
+            if kind == _POOLS:
+                self.pools = val
+            elif kind == _DPOOLS:
+                self.dpools = val
+
     def warmup(self, aot: bool = True) -> dict:
         """Precompile the COMPLETE program grid this engine can ever
-        dispatch, before traffic arrives: one tick program per tick size
-        in {steps_per_tick, 1} (greedy and sampled decode share each —
-        per-slot sampling params are device inputs and both `lax.cond`
-        branches compile), the host-sampling k=1 decode program, and one
-        prefill program per pad-ladder bucket.  BOTH sampling variants
-        warm regardless of the current ``FLAGS_serving_device_sampling``
-        value: the flag is read live at every dispatch, so a mid-run
-        flip must not route traffic to an un-warmed program.  Dummy
-        inputs are inert: all-zero tables and seq_lens route every
-        write to the reserved scratch block 0 and hold every slot
-        inactive, so warmup is safe even mid-flight.
+        dispatch (`_grid`), before traffic arrives, calling each program
+        once with its inert arguments and threading the donated pools
+        through.
 
         Idempotent; returns (and stashes for ``stats()``) ``{warmup_s,
         programs, aot_programs, grid}``.  After warmup, traffic over the
@@ -1487,120 +1482,23 @@ class ServingEngine:
         if self._warmup_info is not None:
             return self._warmup_info
         t0 = time.perf_counter()
-        B, nb = self.B, self.nb_per_seq
-        z = lambda shape, dt: jnp.zeros(shape, dt)  # noqa: E731
         grid = []
         n_aot = 0
         with self._params_for_call() as param_vals:
-            samp = (z((B,), jnp.bool_), jnp.ones((B,), jnp.float32),
-                    z((B,), jnp.int32), jnp.ones((B,), jnp.float32),
-                    z((B,), jnp.uint32), z((B,), jnp.int32))
-            sched = (z((B, nb), jnp.int32), z((B,), jnp.int32),
-                     z((B,), jnp.int32))
-            # spec-decode engines thread (draft_params, draft_pools)
-            # through prefill/cont/cow and own the spec tick program
+            # read once, here: a program's trace leaves its tracers bound
+            # in the draft model until this bracket restores it
             dvals = self._draft_vals() if self.spec_model else None
-
-            def _set_dpools(out_tail):
-                if self.spec_model:
-                    self.dpools = out_tail
-            for k in sorted({self.steps_per_tick, 1}, reverse=True):
+            for program in self._grid():
+                fn = program()
+                decl = fn.decl
                 out, was_aot = self._warm_call(
-                    self._tick_program(k),
-                    (param_vals, self.pools) + sched + samp, aot,
-                    lambda f, _k=k: self._tick_fns.__setitem__(_k, f))
-                self.pools = out[1]
-                _last_column(out[0])    # the overlap chain's slice
+                    fn, self._inert_args(decl, param_vals, dvals), aot,
+                    partial(decl.cache.__setitem__, decl.key))
+                self._keep_pools(decl, out)
+                if decl.name == "serving.tick":
+                    _last_column(out[0])    # the overlap chain's slice
                 n_aot += was_aot
-                grid.append({"program": "tick", "steps_per_tick": k})
-            out, was_aot = self._warm_call(
-                self._decode_program(),
-                (param_vals, self.pools) + sched, aot,
-                lambda f: setattr(self, "_decode_fn", f))
-            self.pools = out[2]
-            n_aot += was_aot
-            grid.append({"program": "decode", "steps_per_tick": 1})
-            if self.spec:
-                # one spec program per LADDER rung (adaptive k steps
-                # between warmed programs, never into a compile); the
-                # host-draft variant threads no draft state at all
-                for sk in self.spec_ladder:
-                    if self.spec_model:
-                        out, was_aot = self._warm_call(
-                            self._spec_program(sk),
-                            (param_vals, dvals, self.pools, self.dpools)
-                            + sched + samp[:5]
-                            + (z((B,), jnp.int32),), aot,
-                            lambda f, _k=sk:
-                                self._spec_fns.__setitem__(_k, f))
-                        self.pools, self.dpools = out[5], out[6]
-                    else:
-                        out, was_aot = self._warm_call(
-                            self._spec_hd_program(sk),
-                            (param_vals, self.pools) + sched
-                            + (z((B, sk), jnp.int32),) + samp[:5]
-                            + (z((B,), jnp.int32),), aot,
-                            lambda f, _k=sk:
-                                self._spec_hd_fns.__setitem__(_k, f))
-                        self.pools = out[5]
-                    n_aot += was_aot
-                    grid.append({"program": "spec_tick", "spec_k": sk,
-                                 "draft": self.spec_kind})
-            if self.chunk <= 0:
-                # monolithic prefill: one program per ladder bucket.  A
-                # CHUNKED engine (FLAGS_serving_prefill_chunk > 0) never
-                # dispatches these — every admission runs the
-                # suffix-prefill chunk programs below instead, so the
-                # grid swaps one program family for the other.
-                for L_pad in self.pad_ladder:
-                    dpref = ((dvals, self.pools, self.dpools)
-                             if self.spec_model else (self.pools,))
-                    out, was_aot = self._warm_call(
-                        self._prefill_program(L_pad),
-                        (param_vals,) + dpref + (z((1, nb), jnp.int32),
-                         z((1, L_pad), jnp.int32), jnp.int32(1)), aot,
-                        lambda f, _L=L_pad:
-                            self._prefill_fns.__setitem__(_L, f))
-                    self.pools = out[1]
-                    _set_dpools(out[2] if self.spec_model else None)
-                    n_aot += was_aot
-                    grid.append({"program": "prefill", "L_pad": L_pad})
-            if self.prefix is not None or self.chunk > 0:
-                # suffix-prefill-at-offset programs: the prefix-cache
-                # hit path AND the chunked-prefill path (one program per
-                # ladder bucket; `start` is traced, so every split point
-                # and chunk offset shares it).  Dummies are inert: an
-                # all-zero table routes every write to scratch block 0.
-                for L_pad in self.pad_ladder:
-                    dpref = ((dvals, self.pools, self.dpools)
-                             if self.spec_model else (self.pools,))
-                    out, was_aot = self._warm_call(
-                        self._prefill_cont_program(L_pad),
-                        (param_vals,) + dpref + (z((1, nb), jnp.int32),
-                         z((1, L_pad), jnp.int32), jnp.int32(1),
-                         jnp.int32(0)), aot,
-                        lambda f, _L=L_pad:
-                            self._prefill_cont_fns.__setitem__(_L, f))
-                    self.pools = out[1]
-                    _set_dpools(out[2] if self.spec_model else None)
-                    n_aot += was_aot
-                    grid.append({"program": "prefill_cont",
-                                 "L_pad": L_pad})
-            if self.prefix is not None:
-                # the CoW block copy (the cache copies block 0 onto
-                # itself during warmup — inert)
-                cow_args = ((self.pools, self.dpools) if self.spec_model
-                            else (self.pools,))
-                out, was_aot = self._warm_call(
-                    self._cow_program(),
-                    cow_args + (jnp.int32(0), jnp.int32(0)), aot,
-                    lambda f: setattr(self, "_cow_fn", f))
-                if self.spec_model:
-                    self.pools, self.dpools = out
-                else:
-                    self.pools = out
-                n_aot += was_aot
-                grid.append({"program": "cow"})
+                grid.append(decl.grid)
         self._warmup_info = {
             "warmup_s": round(time.perf_counter() - t0, 4),
             "programs": len(grid), "aot_programs": n_aot, "grid": grid}
@@ -2698,7 +2596,10 @@ class ServingEngine:
         Returns True while work remains.  UNGUARDED — exceptions
         propagate to the caller; the serve loops wrap it (or their own
         cycles) in the crash-only guard."""
-        pend = self._dispatch_tick(boundary=True)
+        return self._harvest_step(self._dispatch_tick(boundary=True))
+
+    def _harvest_step(self, pend) -> bool:
+        """The second half of `step()`: harvest the tick it launched."""
         if pend is None:
             return bool(self.waiting or self.prefilling)
         self._harvest_tick(pend)
@@ -2712,10 +2613,7 @@ class ServingEngine:
         pend = None
         try:
             pend = self._dispatch_tick(boundary=True)
-            if pend is None:
-                return bool(self.waiting or self.prefilling)
-            self._harvest_tick(pend)
-            return True
+            return self._harvest_step(pend)
         except Exception as e:  # noqa: BLE001 - the guard's whole job
             if not self._absorb_failure(e, (pend,)):
                 raise

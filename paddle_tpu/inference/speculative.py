@@ -80,8 +80,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["accept_and_choose", "build_spec_tick", "build_tp_spec_tick",
-           "build_hostdraft_tick", "build_tp_hostdraft_tick"]
+__all__ = ["accept_and_choose", "build_spec_tick", "build_hostdraft_tick"]
 
 # disjoint PRNG stream tags: fold_in(fold_in(key(seed), TAG), position)
 DRAFT_FOLD = 0x51
@@ -219,61 +218,34 @@ def _finish(eng, tlogits, dtoks, dprobs, do_sample, temperature, top_k,
     return chosen, counts, accepts, new_lens, new_last
 
 
+def _verify(eng, params, pools, tables, seq_lens, last_tok, dtoks, k):
+    """The target's one chunk forward over ``[last, d_1..d_{k-1}]``,
+    through the engine's forward seam and its verify view (the paged
+    spec-verify Pallas kernel by default; `PagedChunkView` dense when
+    FLAGS_serving_pallas_verify is off).  k positions: position j's
+    logits judge d_{j+1}, and the max emit m = k needs KV only through
+    position n+k-1 (d_k, when emitted, becomes the NEXT tick's
+    last_tok).  Including d_k would score a k+1-th position whose logits
+    and KV write are provably never consumed — ~1/(k+1) of the verify
+    forward for nothing; causal masking makes the other positions'
+    logits bit-identical either way.  Returns ``(logits [B, k, V],
+    pools)``; token choice sees the full logits in every mode (under TP
+    they are replicated), which is the bit-parity contract."""
+    forward = eng._forward(params)
+    chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]], axis=1)
+    return forward(chunk, pools, tables, seq_lens, seq_lens[:, None],
+                   eng._verify_view_cls)
+
+
 def build_spec_tick(eng, k):
-    """Degree-1 spec tick body: draft scan -> one k-token chunk verify
-    forward through the engine's verify view (the paged spec-verify
-    Pallas kernel by default; `PagedChunkView` dense when
-    FLAGS_serving_pallas_verify is off) -> accept/choose.  Returns
-    ``(toks [B,k], counts, accepts, new_lens, new_last, pools,
-    dpools)`` — the lens/last outputs are the device carry an
-    overlapped next tick chains on."""
-    from ..framework.dygraph import no_grad
-    from ..framework.tensor import Tensor
-    verify_view_cls = eng._verify_view_cls
-
-    def tick(param_vals, draft_vals, pools, dpools, tables, seq_lens,
-             last_tok, do_sample, temperature, top_k, top_p, seeds,
-             kcap):
-        eng._bind_draft(draft_vals)
-        dtoks, dprobs, dpools = _draft_phase(
-            eng, dpools, tables, seq_lens, last_tok, do_sample,
-            temperature, top_k, top_p, seeds, k)
-        eng._bind_params(param_vals)
-        # chunk [last, d_1..d_{k-1}] — k positions: position j's logits
-        # judge d_{j+1}, and the max emit m = k needs KV only through
-        # position n+k-1 (d_k, when emitted, becomes the NEXT tick's
-        # last_tok).  Including d_k would score a k+1-th position whose
-        # logits and KV write are provably never consumed — ~1/(k+1) of
-        # the verify forward for nothing; causal masking makes the
-        # other positions' logits bit-identical either way.
-        chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
-                                axis=1)
-        views = [verify_view_cls.from_parts(*layer, tables, seq_lens,
-                                            eng.bs)
-                 for layer in pools]
-        with no_grad():
-            logits_t, new_views = eng.model.forward_with_cache(
-                Tensor._wrap(chunk), views,
-                pos_offset=Tensor._wrap(seq_lens[:, None]))
-        pools = [c.pools for c in new_views]
-        out = _finish(eng, logits_t._value, dtoks, dprobs, do_sample,
-                      temperature, top_k, top_p, seeds, seq_lens, kcap)
-        return out + (pools, dpools)
-
-    return tick
-
-
-def build_tp_spec_tick(eng, k):
-    """Tensor-parallel spec tick body (runs inside ``shard_map``): the
-    draft phase is REPLICATED — every rank computes the full draft
-    forward on its full copy of the (small) draft weights and pools —
-    while the verify forward is the sharded `tp.forward_tp` program
-    over the engine's verify view, so the expensive model scores the
-    chunk at 1/tp weights per rank.  Token choice sees the full replicated
-    logits, keeping the TP bit-parity contract."""
-    from . import tp as _tp
-    meta, bs = eng._tp_meta, eng.bs
-    verify_view_cls = eng._verify_view_cls
+    """Model-draft spec tick body: draft scan -> one k-token chunk
+    verify forward -> accept/choose.  The draft phase runs on the
+    draft's own (small) weights and pools, REPLICATED under TP, while
+    the verify is the engine's forward — sharded there, so the expensive
+    model scores the chunk at 1/tp weights per rank.  Returns ``(toks
+    [B,k], counts, accepts, new_lens, new_last, pools, dpools)`` — the
+    lens/last outputs are the device carry an overlapped next tick
+    chains on."""
 
     def tick(params, draft_vals, pools, dpools, tables, seq_lens,
              last_tok, do_sample, temperature, top_k, top_p, seeds,
@@ -282,12 +254,8 @@ def build_tp_spec_tick(eng, k):
         dtoks, dprobs, dpools = _draft_phase(
             eng, dpools, tables, seq_lens, last_tok, do_sample,
             temperature, top_k, top_p, seeds, k)
-        # k-position chunk, same reasoning as build_spec_tick
-        chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
-                                axis=1)
-        logits, pools = _tp.forward_tp(
-            meta, params, chunk, pools, tables, seq_lens,
-            seq_lens[:, None], bs, view_cls=verify_view_cls)
+        logits, pools = _verify(eng, params, pools, tables, seq_lens,
+                                last_tok, dtoks, k)
         out = _finish(eng, logits, dtoks, dprobs, do_sample,
                       temperature, top_k, top_p, seeds, seq_lens, kcap)
         return out + (pools, dpools)
@@ -305,51 +273,11 @@ def build_hostdraft_tick(eng, k):
     residual to ``p`` minus ``d``'s mass (see the module docstring).
     Returns ``(toks [B,k], counts, accepts, new_lens, new_last,
     pools)`` — no draft pools to thread."""
-    from ..framework.dygraph import no_grad
-    from ..framework.tensor import Tensor
-    verify_view_cls = eng._verify_view_cls
-
-    def tick(param_vals, pools, tables, seq_lens, last_tok, dtoks,
-             do_sample, temperature, top_k, top_p, seeds, kcap):
-        eng._bind_params(param_vals)
-        chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
-                                axis=1)
-        views = [verify_view_cls.from_parts(*layer, tables, seq_lens,
-                                            eng.bs)
-                 for layer in pools]
-        with no_grad():
-            logits_t, new_views = eng.model.forward_with_cache(
-                Tensor._wrap(chunk), views,
-                pos_offset=Tensor._wrap(seq_lens[:, None]))
-        pools = [c.pools for c in new_views]
-        logits = logits_t._value
-        dprobs = jax.nn.one_hot(dtoks, logits.shape[-1],
-                                dtype=jnp.float32)
-        out = _finish(eng, logits, dtoks, dprobs, do_sample,
-                      temperature, top_k, top_p, seeds, seq_lens, kcap)
-        return out + (pools,)
-
-    return tick
-
-
-def build_tp_hostdraft_tick(eng, k):
-    """Tensor-parallel host-drafted tick (runs inside ``shard_map``):
-    the proposed tokens and every scheduler input are replicated
-    (rank-0 broadcast), the verify forward is the sharded
-    `tp.forward_tp` chunk program, and token choice sees the full
-    replicated logits — the TP bit-parity contract, minus the draft
-    model entirely."""
-    from . import tp as _tp
-    meta, bs = eng._tp_meta, eng.bs
-    verify_view_cls = eng._verify_view_cls
 
     def tick(params, pools, tables, seq_lens, last_tok, dtoks,
              do_sample, temperature, top_k, top_p, seeds, kcap):
-        chunk = jnp.concatenate([last_tok[:, None], dtoks[:, :k - 1]],
-                                axis=1)
-        logits, pools = _tp.forward_tp(
-            meta, params, chunk, pools, tables, seq_lens,
-            seq_lens[:, None], bs, view_cls=verify_view_cls)
+        logits, pools = _verify(eng, params, pools, tables, seq_lens,
+                                last_tok, dtoks, k)
         dprobs = jax.nn.one_hot(dtoks, logits.shape[-1],
                                 dtype=jnp.float32)
         out = _finish(eng, logits, dtoks, dprobs, do_sample,

@@ -939,7 +939,7 @@ class UnderKeyedProgramCache(Rule):
         a memoized program builder, else None.  A builder both PROBES a
         cache slot and STORES a compiled program into it; ``factories``
         are local functions the store expression routes through
-        (``self._build_tp_tick(k)``-style) whose bodies trace."""
+        (``self._build_x(k)``-style) whose bodies trace."""
         store_sub = None      # cache[key] = <program>
         store_attr = None     # self._x = <program>
         factories: List[ast.AST] = []

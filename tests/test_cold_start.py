@@ -398,3 +398,61 @@ def test_warmup_covers_both_sampling_variants(model):
         reqs += _drive_mixed_traffic(eng, vocab, (12,), budget=4)
         assert compile_tracker.total_compiles() == before
         assert all(len(r.output_ids) == 4 for r in reqs)
+
+
+def _tiny_draft():
+    paddle.seed(1)
+    d = GPTForCausalLM(gpt3_tiny())
+    d.eval()
+    return d
+
+
+# mode -> (engine arguments, the warm-up grid it must list, in order)
+_TICKS = [{"program": "tick", "steps_per_tick": 2},
+          {"program": "tick", "steps_per_tick": 1},
+          {"program": "decode", "steps_per_tick": 1}]
+_PREFILL = [{"program": "prefill", "L_pad": 16}]
+_CONT = [{"program": "prefill_cont", "L_pad": 16}]
+_COW = [{"program": "cow"}]
+GRID_MODES = {
+    "plain": (dict(prefix_cache=False), _TICKS + _PREFILL),
+    "prefix_chunk": (dict(prefix_cache=True, prefill_chunk=8),
+                     _TICKS + _CONT + _COW),
+    "spec_model": (
+        dict(prefix_cache=True, spec_decode=True, spec_k=3),
+        _TICKS + [{"program": "spec_tick", "spec_k": 3, "draft": "model"}]
+        + _PREFILL + _CONT + _COW),
+    "spec_ngram": (
+        dict(prefix_cache=True, spec_decode=True, spec_draft="ngram",
+             spec_adaptive=True, spec_k_ladder="2,4"),
+        _TICKS + [{"program": "spec_tick", "spec_k": k, "draft": "ngram"}
+                  for k in (2, 4)] + _PREFILL + _CONT + _COW),
+    "tp2": (dict(prefix_cache=True, tp_degree=2),
+            _TICKS + _PREFILL + _CONT + _COW),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(GRID_MODES))
+def test_warmup_grid_is_the_engines_program_list(model, mode):
+    """`warmup()` is a loop over the engine's own program list for its
+    mode: the grid names every program the mode can dispatch, in the
+    order they were always warmed, each an AOT executable, and traffic
+    afterwards (greedy and sampled, a prefix hit where there is a prefix
+    cache) compiles nothing."""
+    kw, grid = GRID_MODES[mode]
+    if mode == "spec_model":
+        kw = dict(kw, draft_model=_tiny_draft())
+    vocab = model.cfg.vocab_size
+    eng = ServingEngine(model, max_batch=2, max_context=64, block_size=8,
+                        steps_per_tick=2, pad_buckets="16", **kw)
+    info = eng.warmup()
+    assert info["grid"] == grid
+    assert info["programs"] == info["aot_programs"] == len(grid)
+    assert eng.stats()["warmup"]["programs"] == len(grid)
+    before = compile_tracker.total_compiles()
+    reqs = _drive_mixed_traffic(eng, vocab, (12, 9), budget=5)
+    # the same 12-token prompt again: its first block is a prefix hit
+    reqs += _drive_mixed_traffic(eng, vocab, (12,), budget=5)
+    assert compile_tracker.total_compiles() == before
+    assert all(len(r.output_ids) == 5 for r in reqs)
+    assert eng.prefix is None or eng.prefix.hits >= 1

@@ -392,3 +392,28 @@ def test_greedy_streams_bit_identical_kernels_on_vs_off(engine_pair):
     _, off_streams = engine_pair["off"]
     assert on_streams == off_streams
     assert all(len(s) == 10 for s in on_streams)
+
+
+def test_draft_chunk_view_is_the_engines_snapshot_not_a_traced_flag_read(
+        model):
+    """FLAGS_serving_pallas_prefill is snapshotted at construction for
+    the draft model's share of a chunk as for the target's: a spec-model
+    engine built with the flag on still lowers BOTH through the chunk
+    kernel when `serving.prefill_cont` is first traced after the flag
+    went off (the read used to sit in a traced helper, one call below
+    the program body, where graft-lint R004 does not look)."""
+    paddle.seed(1)
+    draft = GPTForCausalLM(gpt3_tiny())
+    draft.eval()
+    with flag_guard(serving_pallas_prefill=True):
+        eng = ServingEngine(model, max_batch=2, max_context=64,
+                            block_size=16, prefill_chunk=8,
+                            pad_buckets="8", draft_model=draft,
+                            spec_decode=True, spec_k=2)
+    eng.add_request(Request(np.arange(1, 7), max_new_tokens=2))
+    with flag_guard(serving_pallas_prefill=False), \
+            xray.capture_kernel_claims() as claims:
+        eng.step()          # the first chunk: traces serving.prefill_cont
+    assert eng.prefill_chunks_total == 1
+    layers = model.cfg.num_layers + draft.cfg.num_layers
+    assert claims.count(("paged_chunk_prefill", "interpret")) == layers

@@ -172,3 +172,75 @@ def test_tp_flag_routes_engine_construction(model):
     r = eng.add_request(Request(p1, max_new_tokens=4))
     eng.run()
     assert r.done and len(r.output_ids) == 4
+
+
+# ------------------------------------------------- derived specs (ISSUE 30)
+# A program declares what each argument IS; the engine derives the
+# shard_map specs and donate_argnums from that.  This table is the
+# reference: the tuples as they were once counted by hand, a program.
+# S = the plan's parameter spec tree, K = the pool spec (heads over
+# 'tp'), R = replicated.  (in_specs, out_specs, donate_argnums)
+def _spec_table(S):
+    from jax.sharding import PartitionSpec as P
+    K, R = P("tp"), P()
+    plain = {
+        "tick": ((S, K) + (R,) * 9, (R, K, R), (1,)),
+        "decode": ((S, K) + (R,) * 3, (R, R, K), (1,)),
+        "prefill": ((S, K, R, R, R), (R, K), (1,)),
+        "prefill_cont": ((S, K) + (R,) * 4, (R, K), (1,)),
+        "cow": ((K, R, R), K, (0,)),
+    }
+    ngram = dict(plain, spec_tick=(
+        (S, K) + (R,) * 10, (R,) * 5 + (K,), (1,)))
+    model = {
+        "tick": plain["tick"], "decode": plain["decode"],
+        "spec_tick": ((S, R, K, R) + (R,) * 9, (R,) * 5 + (K, R), (2, 3)),
+        "prefill": ((S, R, K, R, R, R, R), (R, K, R), (2, 3)),
+        "prefill_cont": ((S, R, K) + (R,) * 5, (R, K, R), (2, 3)),
+        "cow": ((K, R, R, R), (K, R), (0, 1)),
+    }
+    return {"plain": plain, "spec_ngram": ngram, "spec_model": model}
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec_ngram", "spec_model"])
+def test_declared_programs_derive_the_hand_counted_specs(
+        model, mode, monkeypatch):
+    """Every program of a TP-2 engine's grid: the in/out specs its
+    shard_map gets and the donate_argnums its jit gets on a chip equal
+    the table above, and its degree-1 twin donates the same positions."""
+    import jax
+    kw = {"plain": {},
+          "spec_ngram": dict(spec_decode=True, spec_draft="ngram", spec_k=2),
+          "spec_model": dict(spec_decode=True, spec_k=2, draft_model=model),
+          }[mode]
+    seen = {}
+    real_shard_map, real_program = jax.shard_map, ServingEngine._program
+
+    def shard_map(fn, **kw):
+        seen["specs"] = (kw["in_specs"], kw["out_specs"])
+        return real_shard_map(fn, **kw)
+
+    def program(self, name, fn, donate, *blame):
+        seen["donate"] = donate
+        return real_program(self, name, fn, donate, *blame)
+
+    monkeypatch.setattr(jax, "shard_map", shard_map)
+    monkeypatch.setattr(ServingEngine, "_program", program)
+    for tp in (2, 1):
+        eng = ServingEngine(model, max_batch=2, max_context=64,
+                            block_size=16, steps_per_tick=2, tp_degree=tp,
+                            pad_buckets="16", prefix_cache=True, **kw)
+        table = _spec_table(eng._tp_specs)[mode]
+        grid = eng._grid()
+        assert len(grid) == {"plain": 6, "spec_ngram": 7,
+                             "spec_model": 7}[mode]
+        for build in grid:
+            seen.clear()
+            fn = build()
+            in_specs, out_specs, donate = table[fn.decl.grid["program"]]
+            assert seen["donate"] == fn.decl.donated == donate
+            assert len(fn.decl.args) == len(in_specs)
+            if tp > 1:
+                assert seen["specs"] == (in_specs, out_specs)
+            else:
+                assert "specs" not in seen

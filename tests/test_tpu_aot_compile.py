@@ -259,7 +259,6 @@ def _lower_engine_programs(v5e, chunk, max_batch=8, **cfg):
     spec = lambda tree: jax.tree_util.tree_map(        # noqa: E731
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sh),
         tree)
-    i32 = lambda *s: np.zeros(s, np.int32)             # noqa: E731
     saved = pallas_common.interpret_default
     pallas_common.interpret_default = lambda: False
     try:
@@ -271,29 +270,21 @@ def _lower_engine_programs(v5e, chunk, max_batch=8, **cfg):
         eng = ServingEngine(model, max_batch=max_batch,
                             max_context=cfg["max_seq_len"], block_size=64,
                             steps_per_tick=4, prefill_chunk=chunk)
-        B, nb = eng.B, eng.nb_per_seq
-        sched = (i32(B, nb), i32(B), i32(B))
-        samp = (np.zeros((B,), np.bool_), np.ones((B,), np.float32), i32(B),
-                np.ones((B,), np.float32), np.zeros((B,), np.uint32), i32(B))
-        row = (i32(1, nb), i32(1, chunk), np.int32(1))
         out = {}
         with eng._params_for_call() as params:
-            for label, fn, args, donated in (
-                    ("tick k1", eng._tick_program(1),
-                     (params, eng.pools) + sched + samp, 1),
-                    ("tick k4", eng._tick_program(4),
-                     (params, eng.pools) + sched + samp, 1),
-                    ("decode", eng._decode_program(),
-                     (params, eng.pools) + sched, 1),
-                    ("prefill_cont", eng._prefill_cont_program(chunk),
-                     (params, eng.pools) + row + (np.int32(0),), 1),
-                    ("prefill", eng._prefill_program(chunk),
-                     (params, eng.pools) + row, 1),
-                    ("cow", eng._cow_program(),
-                     (eng.pools, np.int32(0), np.int32(0)), 0)):
+            # each program's arguments and donated positions are its own
+            # declaration's: nothing here restates a signature
+            for label, fn in (
+                    ("tick k1", eng._tick_program(1)),
+                    ("tick k4", eng._tick_program(4)),
+                    ("decode", eng._decode_program()),
+                    ("prefill_cont", eng._prefill_cont_program(chunk)),
+                    ("prefill", eng._prefill_program(chunk)),
+                    ("cow", eng._cow_program())):
                 body = fn.__wrapped__.__wrapped__
                 out[label] = (fn._compile_name, jax.jit(
-                    body, donate_argnums=(donated,)).lower(*spec(args)))
+                    body, donate_argnums=fn.decl.donated).lower(
+                        *spec(eng._inert_args(fn.decl, params))))
         return eng.pools[0][0].shape, out
     finally:
         pallas_common.interpret_default = saved
