@@ -14,9 +14,11 @@ attention_kernel.cu`) driven by a request scheduler behind
 * Admission runs a compiled prefill program (cached per padded prompt
   bucket) that writes the prompt's K/V into the new slot's blocks
   through the SAME pools and returns the last real token's logits.
-* Free slots ride through the decode program as seq_len-0 rows: their
-  writes land in the reserved pad block 0 and their attention output is
-  ignored, so occupancy changes cost nothing.
+* Free slots ride through the decode program as seq_len-0 rows over an
+  all-zero table row (block 0 is the reserved pad block): the paged
+  kernels store and copy nothing for them (a latent cache's row write
+  lands in the pad block) and their attention output is ignored, so
+  occupancy changes cost nothing.
 * Sampling happens ON DEVICE inside the compiled k-step tick (the seat
   of the reference's fused top-p path in
   `fused_multi_transformer_op.cu.h`): per-slot temperature/top-k/top-p/
@@ -368,7 +370,7 @@ class Request:
         # chunked-prefill admission state (engine-owned; the table row
         # lives HERE — shadowing self.tables — until the last chunk
         # lands, so in-flight decode ticks see an all-zero row and
-        # route their seq_len-0 writes to the pad block)
+        # treat the slot as idle: no store, or one to the pad block)
         self._prefilling = False
         self._prefill_chunks = 0
         self._chunk_row = None        # np [nb_per_seq] shadow table row
@@ -2410,11 +2412,11 @@ class ServingEngine:
                        cached_len, t_admit) -> bool:
         """Chunked-prefill admission: stash the allocated table row on
         the REQUEST (a shadow row — ``self.tables[slot]`` stays
-        all-zero, so decode ticks dispatched mid-prefill route the
-        slot's inert seq_len-0 writes to the pad block instead of
-        corrupting freshly written chunks), dispatch the CoW copy if a
-        shared block must receive suffix writes, and queue the request
-        for the per-tick chunk budget."""
+        all-zero, so decode ticks dispatched mid-prefill see an idle
+        slot and store nothing for it, or its row into the pad block,
+        instead of corrupting freshly written chunks), dispatch the CoW
+        copy if a shared block must receive suffix writes, and queue the
+        request for the per-tick chunk budget."""
         if cow_src is not None:
             try:
                 cow_args = ((self.pools, self.dpools) if self.spec_model
@@ -2647,10 +2649,11 @@ class ServingEngine:
         t0 = time.perf_counter()
         # host dispatch phase: enqueue cost by design (the compute lands
         # in the harvest wait; a sampled program blocks inside the call)
+        lens = self.seq_lens[active]
         with _span("serve:tick_dispatch", active=len(active),
-                   kv_tokens=int(self.seq_lens[active].sum()),
-                   selected_tokens=self._selected(
-                       self.seq_lens[active])) as sp:
+                   kv_tokens=int(lens.sum()),
+                   kv_blocks=int((-(-lens // self.bs)).sum()),
+                   selected_tokens=self._selected(lens)) as sp:
             pend = self._launch_tick(active, t0, chain)
             sp.set(steps=pend.k)
         pend.dispatch_s = sp.seconds

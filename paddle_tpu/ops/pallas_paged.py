@@ -8,14 +8,20 @@ block table mapping its logical positions to physical blocks, so cache
 memory is allocated in pages instead of max-length rectangles.
 
 TPU design: one decode step attends a single query token per sequence over
-that sequence's block list.  The kernel runs on a (B*nh, max_blocks) grid
-whose LAST dimension is sequential on TPU, carrying the online-softmax
-state (m, l, acc) in VMEM scratch across block steps.  The physical block
-to stream is chosen by the BlockSpec index_map reading the SCALAR-PREFETCHED
-block table — the gather happens in the DMA engine's addressing, not as a
-data-plane gather op.  Blocks past ceil(seq_len/bs) are skipped entirely
-(`pl.when`), so compute is proportional to the true context length, not
-the padded table width.
+that sequence's block list.  `paged_decode` is one kernel invocation that
+walks only the blocks a running sequence holds: the pools stay in HBM, the
+SCALAR-PREFETCHED block table and lengths sit in SMEM, and for each
+sequence a loop of `ceil(blocks / group)` trips copies `group` blocks of
+all heads at a time through the table into one of two VMEM buffers (the
+next copy in flight while this one is multiplied), carrying the
+online-softmax state (m, l, acc) in VMEM scratch.  The gather happens in
+the DMA engine's addressing, not as a data-plane gather op; an idle slot
+and a table column past a sequence's length cost no grid step, no copy
+and no write-back.  The step's own k/v row is stored by the same kernel
+(`paged_decode_step`).  Pools whose heads are narrower than 128 lanes
+reach the compiled kernel through BlockSpecs on a (B, max_blocks) grid
+instead (`_copies_by_hand` says why); the chunk kernels below run
+BlockSpec grids of their own.
 
 Non-TPU backends run the same kernels under the Pallas interpreter
 (`pallas_common.interpret_default`); `paged_attention_reference` and
@@ -44,95 +50,180 @@ __all__ = ["paged_attention", "paged_attention_reference", "BlockKVCache",
 _NEG_INF = -1e30
 
 
+def _fold(q, k, v, first_pos, seq_len, scale, m_scr, l_scr, acc_scr):
+    """One online-softmax step of a decode query (q [nh, hd], a value)
+    over keys/values [nh, n, hd] at positions first_pos.., masked from
+    seq_len on, into the state m, l ([nh, 128], lane-broadcast) and acc
+    ([nh, hd]) in VMEM scratch."""
+    nh, n, _ = k.shape
+    # batched matvec as [nh, 1, hd] x [nh, n, hd]: Mosaic's dot lowering
+    # requires a non-empty lhs non-contracting dim set.  The unit dim is
+    # inserted while the value is float32 and the cast to the pool dtype
+    # follows: Mosaic has no [nh, hd] -> [nh, 1, hd] shape cast for
+    # packed (bf16) vectors.
+    q = q.astype(jnp.float32)[:, None, :].astype(k.dtype)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)[:, 0, :] * scale  # [nh, n]
+    pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, (nh, n), 1)
+    s = jnp.where(pos < seq_len, s, _NEG_INF)
+    m_prev = m_scr[:, 0]                                  # [nh]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    p = jnp.exp(s - m_new[:, None])                       # [nh, n]
+    alpha = jnp.exp(m_prev - m_new)
+    pv = jax.lax.dot_general(
+        p[:, None, :].astype(v.dtype), v,
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)[:, 0, :]      # [nh, hd]
+    acc_scr[:] = acc_scr[:] * alpha[:, None] + pv
+    l_scr[:] = l_scr[:] * alpha[:, None] + jnp.broadcast_to(
+        jnp.sum(p, axis=1)[:, None], l_scr.shape)
+    m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+
+
+def _reset(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _result(l_scr, acc_scr, dtype):
+    l = l_scr[:, 0]                                       # [nh]
+    return (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)[:, None]).astype(dtype)
+
+
+def _with_row(new, old, row):
+    """`old` ([nh, n, hd]) with its row `row` replaced by `new` ([nh, hd]);
+    through float32 for the packed-shape-cast reason of `_fold`."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1) == row
+    return jnp.where(hit, new.astype(jnp.float32)[:, None, :],
+                     old.astype(jnp.float32)).astype(old.dtype)
+
+
 def _decode_kernel(tables_ref, lens_ref, q_ref, *refs, scale, bs, max_blocks,
-                   nh, write):
-    """One grid instance = ALL heads of one sequence against one physical
-    block: grid (B, max_blocks), k/v blocks [nh, bs, hd].  Processing the
-    whole head dim per instance cuts the sequential grid by nh× and makes
-    each DMA nh× larger — the per-iteration launch overhead dominated the
-    per-head variant (round 3's kernel) at decode sizes.
+                   group, tile, write):
+    """ONE invocation walks every sequence's own blocks and nothing else.
+
+    The pools stay in HBM (`pl.ANY`); the table and lengths are in SMEM.
+    A first scalar pass notes how many table columns each slot walks (0
+    for an idle one, see `_walked`) and which slot is the next that walks
+    any.  Then, sequence by sequence, a loop of `ceil(blocks / group)`
+    trips (dynamic) copies `group` blocks of all heads at a time through
+    the table into one of two VMEM buffers - the copy of the next group,
+    or of the next busy sequence's first, in flight while this one is
+    multiplied - and folds them into the online-softmax state (m, l, acc
+    in VMEM scratch).  A table column past a sequence's blocks is neither
+    copied nor waited for: its rows of the buffer keep older (finite)
+    data and are masked by position; `vbuf` is zeroed once so that the
+    very first groups cannot meet uninitialised memory there.  An idle
+    slot costs its zero output row.
 
     With `write` (`paged_decode_step`) the step's own k/v row rides in:
-    it is merged into the sequence's last live block as that block passes
-    through VMEM, attended from there, and the merged block leaves as an
-    output aliased onto the pool — the decode store costs one block of
-    write-back a sequence and no op of its own."""
+    it is merged into the sequence's last block as that block sits in
+    VMEM, attended from there, and the aligned `tile` rows that hold it
+    go back to the pool (an output aliased onto the input) while the
+    group is multiplied - one tile of write-back a busy sequence."""
     if write:
-        (knew_ref, vnew_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
-         m_scr, l_scr, acc_scr) = refs
+        (knew_ref, vnew_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm,
+         kbuf, vbuf, sem, wsem, walk, nxt, m_scr, l_scr, acc_scr) = refs
     else:
-        k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    b = pl.program_id(0)
-    blk = pl.program_id(1)
+        (k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sem, walk, nxt, m_scr, l_scr, acc_scr) = refs
+    B, nh, hd = q_ref.shape
+    keys = group * bs
+    vbuf[...] = jnp.zeros_like(vbuf)
 
-    @pl.when(blk == 0)
+    def note(i, later):
+        b = B - 1 - i
+        n = _walked(tables_ref[b, 0], lens_ref[b], bs, max_blocks, write)
+        walk[b], nxt[b] = n, later
+        return jnp.where(n > 0, b, later)
+
+    first = jax.lax.fori_loop(0, B, note, B)
+
+    def copies(b, g, slot, do):
+        """`do` (start or wait) on the copies of group g of sequence b:
+        one a block the sequence holds there, K and V."""
+        def block(i, _):
+            blk = tables_ref[b, g * group + i]
+            rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                do(pltpu.make_async_copy(
+                    hbm.at[:, blk], buf.at[slot, :, rows], sem.at[slot]))
+
+        jax.lax.fori_loop(0, jnp.minimum(group, walk[b] - g * group), block,
+                          None)
+
+    def start(b, g, slot):
+        copies(b, g, slot, lambda c: c.start())
+
+    @pl.when(first < B)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        start(first, 0, 0)
 
-    seq_len = lens_ref[b]
-    n_blocks = (seq_len + bs - 1) // bs
+    def stored(b, g, slot):
+        """Where the new row goes (position lens[b] - 1: lens counts it):
+        its row in its `tile` rows of the buffer, those rows, and the two
+        copies that take them home to the pool."""
+        col = walk[b] - 1
+        row = (lens_ref[b] - 1) % bs
+        top = row // tile * tile
+        rows = pl.ds(pl.multiple_of((col - g * group) * bs + top, tile), tile)
+        home = [pltpu.make_async_copy(
+            buf.at[slot, :, rows],
+            pool.at[:, tables_ref[b, col], pl.ds(top, tile)], wsem)
+            for buf, pool in ((kbuf, ko_hbm), (vbuf, vo_hbm))]
+        return row - top, rows, home
 
-    def attend(k, v):                                     # [nh, bs, hd]
-        # batched matvec as [nh, 1, hd] x [nh, bs, hd]: Mosaic's dot
-        # lowering requires a non-empty lhs non-contracting dim set.  The
-        # unit dim is inserted while the value is float32 and the cast to
-        # the pool dtype follows: Mosaic has no [nh, hd] -> [nh, 1, hd]
-        # shape cast for packed (bf16) vectors.
-        q = q_ref[:, :].astype(jnp.float32)[:, None, :].astype(k.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[:, 0, :] * scale  # [nh, bs]
-        pos = blk * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (nh, bs), 1)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
-        m_prev = m_scr[:, 0]                              # [nh]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])                   # [nh, bs]
-        alpha = jnp.exp(m_prev - m_new)
-        pv = jax.lax.dot_general(
-            p[:, None, :].astype(v.dtype), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[:, 0, :]  # [nh, hd]
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + pv
-        l_scr[:] = l_scr[:] * alpha[:, None] + jnp.broadcast_to(
-            jnp.sum(p, axis=1)[:, None], l_scr.shape)
-        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+    def sequence(b, done):
+        """`done`: the groups walked before b (its parity is the buffer)."""
+        n_groups = (walk[b] + group - 1) // group
 
-    if not write:
-        @pl.when(blk < n_blocks)
+        @pl.when(walk[b] == 0)
         def _():
-            attend(k_ref[:, :, :], v_ref[:, :, :])
-    else:
-        # the new token is position seq_len - 1 (seq_len counts it)
-        last = _write_col(seq_len, bs, max_blocks)
+            o_ref[b] = jnp.zeros((nh, hd), o_ref.dtype)
 
-        @pl.when(blk < last)
+        @pl.when(walk[b] > 0)
         def _():
-            attend(k_ref[:, :, :], v_ref[:, :, :])
+            _reset(m_scr, l_scr, acc_scr)
 
-        @pl.when(blk == last)
-        def _():
-            hit = jax.lax.broadcasted_iota(
-                jnp.int32, k_ref.shape, 1) == (seq_len - 1) % bs
+            def walk_group(g, _):
+                slot = (done + g) % 2
+                last = g == n_groups - 1
 
-            def merged(new_ref, old_ref):
-                # through float32 for the same packed-shape-cast reason
-                new = new_ref[:, :].astype(jnp.float32)[:, None, :]
-                return jnp.where(hit, new, old_ref[:, :, :].astype(
-                    jnp.float32)).astype(old_ref.dtype)
+                # the copy to follow this one: the sequence's next group,
+                # or the first group of the next sequence that has any
+                @pl.when(jnp.logical_or(jnp.logical_not(last), nxt[b] < B))
+                def _():
+                    start(jnp.where(last, jnp.minimum(nxt[b], B - 1), b),
+                          jnp.where(last, 0, g + 1), 1 - slot)
 
-            k, v = merged(knew_ref, k_ref), merged(vnew_ref, v_ref)
-            ko_ref[:, :, :] = k
-            vo_ref[:, :, :] = v
-            attend(k, v)
+                copies(b, g, slot, lambda c: c.wait())
+                if write:
+                    @pl.when(last)
+                    def _():
+                        row, rows, home = stored(b, g, slot)
+                        for new_ref, buf in ((knew_ref, kbuf),
+                                             (vnew_ref, vbuf)):
+                            buf[slot, :, rows, :] = _with_row(
+                                new_ref[b], buf[slot, :, rows, :], row)
+                        for c in home:
+                            c.start()
 
-    @pl.when(blk == max_blocks - 1)
-    def _():
-        l = l_scr[:, 0]                                   # [nh]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:, :] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+                _fold(q_ref[b], kbuf[slot], vbuf[slot], g * keys,
+                      lens_ref[b], scale, m_scr, l_scr, acc_scr)
+                if write:
+                    @pl.when(last)
+                    def _():
+                        for c in stored(b, g, slot)[2]:
+                            c.wait()
+
+            jax.lax.fori_loop(0, n_groups, walk_group, None)
+            o_ref[b] = _result(l_scr, acc_scr, o_ref.dtype)
+
+        return done + n_groups
+
+    jax.lax.fori_loop(0, B, sequence, 0)
 
 
 def _write_col(seq_len, bs, max_blocks):
@@ -140,6 +231,99 @@ def _write_col(seq_len, bs, max_blocks):
     position past the table clamps to the last column, as a gather of the
     table would)."""
     return jnp.minimum((seq_len - 1) // bs, max_blocks - 1)
+
+
+def _walked(first_blk, seq_len, bs, max_blocks, write):
+    """Table columns the kernel walks for one slot, from what it can read:
+    the columns that hold positions 0..seq_len-1, and none for an idle
+    slot.  Reading only, a slot of length 0 is idle.  Writing, `seq_len`
+    counts the row to store, and a slot is idle when that row would be a
+    sequence's first (seq_len 1) into the reserved pad block 0: the
+    serving engine's free slot, length 0 over a zero table row.  A live
+    sequence of length 0 has a real first block and is walked."""
+    if not write:
+        return jnp.minimum((seq_len + bs - 1) // bs, max_blocks)
+    idle = jnp.logical_and(seq_len <= 1, first_blk == 0)
+    return jnp.where(idle, 0, _write_col(seq_len, bs, max_blocks) + 1)
+
+
+def _decode_grid_kernel(tables_ref, lens_ref, q_ref, *refs, scale, bs,
+                        max_blocks, write):
+    """The same step where Mosaic will not let a kernel copy the blocks
+    itself (`_copies_by_hand`): grid (B, max_blocks), Mosaic's own
+    pipeline streams block `tables[b, col]` of all heads a step, the state
+    lives in scratch across a sequence's columns, and a column past the
+    sequence's blocks is a skipped step.  The writing variant's aliased
+    output block is the one that takes the new row; an idle slot's is the
+    pad block, handed back as it came."""
+    if write:
+        (knew_ref, vnew_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+         m_scr, l_scr, acc_scr) = refs
+    else:
+        k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    b, col = pl.program_id(0), pl.program_id(1)
+    n = _walked(tables_ref[b, 0], lens_ref[b], bs, max_blocks, write)
+
+    def fold(k, v):
+        _fold(q_ref[...], k, v, col * bs, lens_ref[b], scale,
+              m_scr, l_scr, acc_scr)
+
+    @pl.when(col == 0)
+    def _():
+        _reset(m_scr, l_scr, acc_scr)
+
+    @pl.when(col < n - int(write))
+    def _():
+        fold(k_ref[...], v_ref[...])
+
+    if write:
+        @pl.when(col == n - 1)
+        def _():
+            row = (lens_ref[b] - 1) % bs
+            k = _with_row(knew_ref[...], k_ref[...], row)
+            v = _with_row(vnew_ref[...], v_ref[...], row)
+            ko_ref[...] = k
+            vo_ref[...] = v
+            fold(k, v)
+
+        @pl.when(jnp.logical_and(n == 0, col == 0))
+        def _():
+            ko_ref[...] = k_ref[...]
+            vo_ref[...] = v_ref[...]
+
+    @pl.when(col == max_blocks - 1)
+    def _():
+        o_ref[...] = _result(l_scr, acc_scr, o_ref.dtype)
+
+
+def _copies_by_hand(hd, interpret) -> bool:
+    """Whether `paged_decode` may copy a pool's blocks itself.  Mosaic
+    (libtpu 0.0.34) pads an operand's minor dimension to 128 lanes and
+    then refuses every slice of it ("Slice shape along dimension 3 must
+    be aligned to tiling (128), but is 64"), a whole block of all heads
+    included: a pool of narrower heads reaches the compiled kernel
+    through BlockSpecs (`_decode_grid_kernel`) until its rows are 128
+    lanes wide (PERF.md section 7).  The interpreter has no such rule."""
+    return bool(interpret) or hd % 128 == 0
+
+
+_GROUP_VMEM = 4 << 20        # bytes of the four [nh, group * bs, hd] buffers
+_GROUP_KEYS = 512            # ... no more keys a multiply than this
+_GROUP_BLOCKS = 8            # ... and no more copies in flight a buffer
+
+
+def _copy_group(nh, bs, hd, dtype, max_blocks):
+    """Blocks a copy group holds: as many as keep the two K and two V
+    buffers (lanes padded to 128, rows to the dtype's sublane tile)
+    within `_GROUP_VMEM`, a group within `_GROUP_KEYS` keys and
+    `_GROUP_BLOCKS` copies, at least one and no more than the table is
+    wide.  16 heads of 128 in bf16 blocks of 64: 4 (2 MB of K and V a
+    copy; 2, 4 and 8 read the same on the v5e, PERF.md section 6)."""
+    item = jnp.dtype(dtype).itemsize
+    rows = -(-bs // (32 // item)) * (32 // item)
+    block = nh * rows * (-(-hd // 128) * 128) * item
+    return max(1, min(_GROUP_VMEM // (4 * block), _GROUP_KEYS // bs,
+                      _GROUP_BLOCKS, max_blocks))
 
 
 def _decode_call(q, k_cache, v_cache, block_tables, seq_lens, interpret,
@@ -162,38 +346,55 @@ def _decode_pallas(q, k_cache, v_cache, block_tables, seq_lens, new_rows, *,
     _, _, bs, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
     write = new_rows is not None
-    kern = functools.partial(_decode_kernel, scale=1.0 / math.sqrt(hd),
-                             bs=bs, max_blocks=max_blocks, nh=nh,
-                             write=write)
-
-    def qmap(b, blk, tables, lens):
-        return (b, 0, 0)
-
-    def kvmap(b, blk, tables, lens):
-        return (0, tables[b, blk], 0, 0)
-
-    def wmap(b, blk, tables, lens):
-        return (0, tables[b, _write_col(lens[b], bs, max_blocks)], 0, 0)
-
-    row = pl.BlockSpec((None, nh, hd), qmap)
-    out_specs, out_shape = row, jax.ShapeDtypeStruct((B, nh, hd), q.dtype)
+    dtype = k_cache.dtype
+    scale = 1.0 / math.sqrt(hd)
+    state = [pltpu.VMEM((nh, 128), jnp.float32),
+             pltpu.VMEM((nh, 128), jnp.float32),
+             pltpu.VMEM((nh, hd), jnp.float32)]
+    out_shape = jax.ShapeDtypeStruct((B, nh, hd), q.dtype)
     if write:
-        pool = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
-        out_specs = [row] + [pl.BlockSpec((nh, None, bs, hd), wmap)] * 2
-        out_shape = [out_shape, pool, pool]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(k_cache.shape, dtype)] * 2
+    if _copies_by_hand(hd, interpret):
+        group = _copy_group(nh, bs, hd, dtype, max_blocks)
+        # the rows a write-back moves: the dtype's sublane tile, or the
+        # whole block where the tile does not divide it
+        tile = 32 // jnp.dtype(dtype).itemsize
+        tile = tile if bs % tile == 0 else bs
+        kern = functools.partial(_decode_kernel, scale=scale, bs=bs,
+                                 max_blocks=max_blocks, group=group,
+                                 tile=tile, write=write)
+        rows = pl.BlockSpec((B, nh, hd), lambda i, tables, lens: (0, 0, 0))
+        pool = pl.BlockSpec(memory_space=pl.ANY)
+        buf = pltpu.VMEM((2, nh, group * bs, hd), dtype)
+        grid = (1,)
+        scratch = [buf, buf, pltpu.SemaphoreType.DMA((2,))] \
+            + ([pltpu.SemaphoreType.DMA(())] if write else []) \
+            + [pltpu.SMEM((B,), jnp.int32), pltpu.SMEM((B,), jnp.int32)]
+        out_pool = pool
+    else:
+        kern = functools.partial(_decode_grid_kernel, scale=scale, bs=bs,
+                                 max_blocks=max_blocks, write=write)
+
+        def written(b, col, tables, lens):
+            # an idle slot's row is zero, so this is the pad block for it
+            return (0, tables[b, _write_col(lens[b], bs, max_blocks)], 0, 0)
+
+        rows = pl.BlockSpec((None, nh, hd),
+                            lambda b, col, tables, lens: (b, 0, 0))
+        pool = pl.BlockSpec(
+            (nh, None, bs, hd),
+            lambda b, col, tables, lens: (0, tables[b, col], 0, 0))
+        out_pool = pl.BlockSpec((nh, None, bs, hd), written)
+        grid, scratch = (B, max_blocks), []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, max_blocks),
-        in_specs=[row] * (3 if write else 1)
-        + [pl.BlockSpec((nh, None, bs, hd), kvmap)] * 2,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32),
-        ],
+        grid=grid,
+        in_specs=[rows] * (3 if write else 1) + [pool, pool],
+        out_specs=[rows, out_pool, out_pool] if write else rows,
+        scratch_shapes=scratch + state,
     )
-    rows = tuple(x.astype(k_cache.dtype) for x in new_rows) if write else ()
+    new = tuple(x.astype(dtype) for x in new_rows) if write else ()
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -202,7 +403,7 @@ def _decode_pallas(q, k_cache, v_cache, block_tables, seq_lens, new_rows, *,
         input_output_aliases={5: 1, 6: 2} if write else {},
         interpret=interpret,
         name="paged_decode",
-    )(block_tables, seq_lens, q, *rows, k_cache, v_cache)
+    )(block_tables, seq_lens, q, *new, k_cache, v_cache)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, seq_lens,
@@ -232,11 +433,16 @@ def paged_decode_step(q, k_step, v_step, k_pool, v_pool, block_tables,
 
     q/k_step/v_step: [B, nh, hd]; k_pool/v_pool: [nh, num_blocks, bs, hd];
     block_tables: [B, max_blocks] int32; seq_lens: [B] lengths BEFORE the
-    step.  Inactive slots (length 0 over a zero table row) write the pad
-    block 0 and attend it; their output is discarded upstream.  Returns
-    (out [B, nh, hd], k_pool, v_pool): the pools are aliased outputs, so a
-    donated pool — or a `lax.scan` carry — is updated where it lies, one
-    block of write-back a sequence.
+    step.  A slot is IDLE when its length is 0 and its table's first
+    entry is 0, the reserved pad block - the serving engine's free slot
+    (length 0 over a zero table row; `PagedKVCache` and the engine number
+    real blocks from 1): nothing is copied, merged or written for it, the
+    pad block included, and its output row is zeros (discarded upstream).
+    A live sequence of length 0 over a real block is not idle: its row is
+    written at position 0 and attended.  Returns (out [B, nh, hd], k_pool,
+    v_pool): the pools are aliased outputs, so a donated pool - or a
+    `lax.scan` carry - is updated where it lies, one sublane tile of
+    write-back a busy sequence (16 rows of bf16).
 
     Why the kernel and not `pool.at[:, blk, off].set(...)` beside it:
     XLA:TPU gives a scatter's operand the layout that makes the scattered

@@ -129,7 +129,8 @@ def test_span_attrs(run):
             ("serve:schedule", {"waiting", "running"}),
             ("serve:prefill_dispatch", {"rid", "prompt_tokens"}),
             ("serve:chunk_dispatch", {"rid", "q_tokens", "kv_tokens"}),
-            ("serve:tick_dispatch", {"steps", "active", "kv_tokens"}),
+            ("serve:tick_dispatch", {"steps", "active", "kv_tokens",
+                                     "kv_blocks"}),
             ("serve:emit", {"tokens"}),
             ("to_static:discover", {"fn"}),
             ("to_static:trace_lower", {"fn"}),
@@ -142,6 +143,10 @@ def test_span_attrs(run):
     ticks = _named(run, "serve:tick_dispatch")
     assert all(e[3]["steps"] in (1, 2) and 1 <= e[3]["active"] <= 2
                and e[3]["kv_tokens"] >= e[3]["active"] for e in ticks)
+    # live blocks at dispatch: at least one a running sequence, and no
+    # more than its tokens need (the kernel's walk, against B x columns)
+    assert all(e[3]["active"] <= e[3]["kv_blocks"]
+               <= e[3]["kv_tokens"] // 16 + e[3]["active"] for e in ticks)  # blocks of 16
     # every token but each request's first comes out of a tick's emit
     reqs = run["reqs"]
     assert sum(e[3]["tokens"] for e in _named(run, "serve:emit")) \

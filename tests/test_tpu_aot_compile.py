@@ -92,6 +92,9 @@ def _mosaic(fn):
     return traced
 
 
+_CELL_STEP = "paged_decode_step cell B16 nb24 nh16 hd128 bfloat16"
+
+
 def _cases():
     """name -> (function, argument shapes): every default-on kernel at
     Phase 3's shapes.  bf16 pools are what a deployment serves (the
@@ -127,6 +130,15 @@ def _cases():
                 functools.partial(pp.paged_verify_attention,
                                   interpret=False),
                 (((B, 4, nh, hd), dt), pool, pool) + tl)
+        if dt is BF16:
+            # the 1.3B serve cell's own decode step: batch 16 over a table
+            # 24 wide, 384 blocks of 64 + the pad block, the row stored
+            B, nb = 16, 24
+            pool = ((16, B * nb + 1, 64, 128), dt)
+            cases[_CELL_STEP] = (
+                functools.partial(pp.paged_decode_step, interpret=False),
+                (((B, 16, 128), dt),) * 3 + (pool, pool)
+                + (((B, nb), i32), ((B,), i32)))
         for T, M, E, k in ((1024, 768, 8, 2), (1024, 2048, 64, 8)):
             C = int(T * k / E * 1.25)
             cases[f"moe_dispatch T{T} M{M} E{E} {n}"] = (
@@ -234,7 +246,11 @@ def _custom_call_names(hlo_text: str) -> list:
 
 @pytest.mark.parametrize("kernel,case", sorted(
     list(KERNEL_CASES.items())
-    + [(k, _FLASH64) for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")]))
+    + [(k, _FLASH64) for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")]
+    # the writing variant at the cell's shape (the kernel copies the
+    # blocks itself) and at heads of 64 (it may not: BlockSpecs)
+    + [("paged_decode", _CELL_STEP),
+       ("paged_decode", "paged_decode_step nh12 hd64 bfloat16")]))
 def test_custom_call_is_named_for_its_kernel(compiled, kernel, case):
     names = _custom_call_names(compiled[case].result().as_text())
     assert names and any(kernel in n for n in names), names
@@ -389,6 +405,17 @@ def test_serving_program_leaves_the_pools_in_place(pool_programs, nh, hd,
         assert len(hits) <= 3 * 4, hits           # 2 layers x (k, v)
         if "tpu_custom_call" not in text:
             assert not hits, hits
+
+
+@pytest.mark.parametrize("program", ["tick k1", "tick k4"])
+@pytest.mark.parametrize("nh, hd", WIDTHS)
+def test_tick_holds_one_paged_decode_a_layer(pool_programs, nh, hd, program):
+    """The serve job holds every kernel claim of `serving.tick*` to
+    `paged_decode`: the two-layer tick has two Mosaic custom calls, both
+    of that name - the row's store is inside them, not a kernel beside."""
+    compiled, _ = pool_programs[nh, hd, program]
+    names = _custom_call_names(compiled.as_text())
+    assert len(names) == 2 and all("paged_decode" in n for n in names), names
 
 
 def test_copy_metric_reads_the_opcode_copy_and_no_other(pool_programs):
