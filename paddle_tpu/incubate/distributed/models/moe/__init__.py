@@ -2,7 +2,8 @@
 
 from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate, capacity
 from .moe_layer import ExpertMLP, MoELayer
-from .dropless import HeldExpertsLayer, SigmoidTopKGate
+from .dropless import HeldExpertsLayer, SigmoidTopKGate, SoftmaxTopKGate
 
 __all__ = ["MoELayer", "ExpertMLP", "BaseGate", "NaiveGate", "SwitchGate",
-           "GShardGate", "capacity", "SigmoidTopKGate", "HeldExpertsLayer"]
+           "GShardGate", "capacity", "SigmoidTopKGate", "SoftmaxTopKGate",
+           "HeldExpertsLayer"]

@@ -5,7 +5,9 @@ slot for.  The DeepSeek-V3 family (`topk_method: noaux_tc`) drops none:
 each token scores every expert with a sigmoid, a learned per-expert bias
 is added FOR THE CHOICE only, the `top_k` best are taken, and their
 un-biased scores, normalised over the picked and scaled, mix the expert
-outputs.  `SigmoidTopKGate` is that decision.
+outputs.  `SigmoidTopKGate` is that decision.  The Qwen3-MoE family
+routes by `SoftmaxTopKGate`: a softmax over the experts, the `top_k`
+largest, renormalised over the picked; no bias, no scaling.
 
 `HeldExpertsLayer` is what expert parallelism asks of a layer: it is told
 which experts live here (`expert_offset`, `n_experts_held` of the
@@ -34,7 +36,8 @@ from paddle_tpu.nn import Layer
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.ops import pallas_moe as _pm
 
-__all__ = ["SigmoidTopKGate", "HeldExpertsLayer", "publish_expert_rows"]
+__all__ = ["SigmoidTopKGate", "SoftmaxTopKGate", "HeldExpertsLayer",
+           "publish_expert_rows"]
 
 _M_ROWS = _metrics.counter(
     "moe.local_expert_tokens", "rows (token x routing choice) given to "
@@ -44,8 +47,8 @@ _M_ROWS = _metrics.counter(
 
 
 def publish_expert_rows(layer_rows, expert_offset: int = 0) -> None:
-    """Add device-side counts (`[2 kinds, 2, held]` a layer, as
-    `GlmMoeDsaBlock` accumulates them) to `moe.local_expert_tokens`.  The
+    """Add device-side counts (`[2 kinds, 2, held]` a layer, as the
+    models' blocks accumulate them) to `moe.local_expert_tokens`.  The
     caller passes the growth since its last call."""
     for li, rows in enumerate(layer_rows):
         for ki, kind in enumerate(("decode", "chunk")):
@@ -89,16 +92,50 @@ class SigmoidTopKGate(Layer):
         return Tensor._wrap(picked), Tensor._wrap(g)
 
 
+class SoftmaxTopKGate(Layer):
+    """`p = softmax(x W)` over the experts in float32; pick the `top_k`
+    largest; weights `p[picked] / sum p[picked]` (`norm_topk_prob`), or
+    `p[picked]` as they are.  No bias, no scaling, no capacity, no drop.
+    `weight_attr` places the router's weight (its initialiser)."""
+
+    def __init__(self, d_model: int, num_expert: int, top_k: int,
+                 norm_topk_prob: bool = True, weight_attr=None):
+        super().__init__()
+        self.num_expert, self.top_k = num_expert, top_k
+        self.norm_topk_prob = norm_topk_prob
+        self.weight = self.create_parameter([d_model, num_expert],
+                                            attr=weight_attr)
+
+    def route(self, x):
+        """x: array `[T, d_model]` -> (picked `[T, k]` int32, weights
+        `[T, k]` float32)."""
+        p = jax.nn.softmax(jnp.matmul(
+            x.astype(jnp.float32), self.weight._value.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        g, picked = jax.lax.top_k(p, self.top_k)
+        if self.norm_topk_prob:
+            g = g / g.sum(-1, keepdims=True)
+        return picked.astype(jnp.int32), g
+
+    def forward(self, x):
+        picked, g = self.route(x._value.reshape(-1, x.shape[-1]))
+        return Tensor._wrap(picked), Tensor._wrap(g)
+
+
 class HeldExpertsLayer(Layer):
     """`y = sum_{picked e held here} g_e E_e(x) + shared(x)`, `E` a
-    SwiGLU MLP of width `d_hidden`.  `shared` (a Layer, or None) is the
-    part every chip computes alike."""
+    SwiGLU MLP of width `d_hidden`.  `gate` is the routing decision over
+    the router's `gate.num_expert` experts (a Layer with `route(x) ->
+    (picked [T, k], weights [T, k])`: `SigmoidTopKGate`,
+    `SoftmaxTopKGate`).  `shared` (a Layer, or None) is the part every
+    chip computes alike.  `expert_init(lo, hi)` builds the initialiser of
+    the stacked expert weights (uniform(lo, hi) if None)."""
 
-    def __init__(self, d_model: int, d_hidden: int, num_expert: int,
-                 top_k: int, n_experts_held: int = None,
-                 expert_offset: int = 0, shared: Layer = None,
-                 routed_scaling_factor: float = 1.0):
+    def __init__(self, d_model: int, d_hidden: int, gate: Layer,
+                 n_experts_held: int = None, expert_offset: int = 0,
+                 shared: Layer = None, expert_init=None):
         super().__init__()
+        num_expert = gate.num_expert
         held = num_expert if n_experts_held is None else int(n_experts_held)
         if not 0 <= expert_offset <= expert_offset + held <= num_expert:
             raise ValueError(
@@ -106,9 +143,8 @@ class HeldExpertsLayer(Layer):
                 f"among the router's {num_expert}")
         self.num_expert, self.held, self.offset = num_expert, held, \
             int(expert_offset)
-        self.gate = SigmoidTopKGate(d_model, num_expert, top_k,
-                                    routed_scaling_factor)
-        self.experts = _HeldExperts(held, d_model, d_hidden)
+        self.gate = gate
+        self.experts = _HeldExperts(held, d_model, d_hidden, expert_init)
         self.shared_experts = shared
 
     def forward(self, x):
@@ -137,9 +173,9 @@ class HeldExpertsLayer(Layer):
 class _HeldExperts(Layer):
     """The stacked SwiGLU weights of the experts held here."""
 
-    def __init__(self, held: int, d_model: int, d_hidden: int):
+    def __init__(self, held: int, d_model: int, d_hidden: int, init=None):
         super().__init__()
-        init = paddle.nn.initializer.Uniform
+        init = init or paddle.nn.initializer.Uniform
         a, b = 1.0 / math.sqrt(d_model), 1.0 / math.sqrt(d_hidden)
         self.gate_proj = self.create_parameter(
             [held, d_model, d_hidden], default_initializer=init(-a, a))

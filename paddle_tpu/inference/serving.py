@@ -305,6 +305,15 @@ _M_OUTCOMES = _metrics.counter(
     "the SLO burn-rate monitor reads error|poisoned as budget burn")
 
 
+_M_BLOCK_FORWARDS = _metrics.counter(
+    "serving.block.forwards", "forwards of a block-diffusion tick "
+    "(denoising + commit), whole-batch forwards a launch")
+_M_BLOCK_REVEALED = _metrics.counter(
+    "serving.block.tokens_revealed", "tokens revealed by the denoising "
+    "forwards of block-diffusion ticks, by per_forward= how many of a "
+    "sequence's block one forward revealed (1..block_length)")
+
+
 class TickTimeout(RuntimeError):
     """The harvest of a compiled tick did not materialize within
     ``FLAGS_serving_tick_timeout_s`` — a hung device program.  Raised
@@ -348,6 +357,9 @@ class Request:
         self.seed = int(seed) if seed is not None else self.rid
         self._rng = np.random.RandomState(self.seed)
         self.output_ids: List[int] = []
+        # under block-diffusion generation: for each output token the
+        # denoising forward (0-based) of its block's tick that revealed it
+        self.reveal_steps: List[int] = []
         self.done = False
         self.slot: Optional[int] = None
         # scheduler knobs (ISSUE 11): higher priority admits first among
@@ -451,7 +463,7 @@ class _PendingTick:
                  "device_sampling", "overlapped", "step_no", "san",
                  "spec", "counts", "accepts", "new_lens", "new_last",
                  "chunks", "kcap", "sched_s", "chunk_s", "dispatch_s",
-                 "state")
+                 "state", "block")
 
     def __init__(self, active, k, toks, logits, reqs, t0,
                  device_sampling, step_no, san=None):
@@ -471,6 +483,11 @@ class _PendingTick:
         self.new_lens = None
         self.new_last = None
         self.state = ()     # the cache's per-layer state after this tick
+        # a block-diffusion tick: (given, new, step_of) — per slot, how
+        # many of the block's tokens were the prompt's and how many are to
+        # be handed over, and the device handle of each position's
+        # revealing forward
+        self.block = None
         self.chunks = 0     # prefill chunks run at this tick's boundary
         self.kcap = None    # per-slot emit caps of a spec dispatch
         # per-tick phase breakdown: seconds of the serve:schedule,
@@ -641,6 +658,15 @@ class ServingEngine:
         for mech, why in self.cache.unsupported.items():
             if asked.get(mech):
                 raise ValueError(f"ServingEngine({mech}=...): {why}")
+        # how the model generates, if other than one token a forward and
+        # sequence (`BlockDiffusion`): the tick then denoises and commits
+        # one block a running sequence, and every prompt is absorbed
+        # through the chunk programs up to its last whole block
+        self.gen = self.cache.generation
+        if self.gen is not None and block_size % self.gen.block_length:
+            raise ValueError(
+                f"block_size {block_size} must be a multiple of the "
+                f"model's block_length {self.gen.block_length}")
         self._sd = model.state_dict()
         self._keys = sorted(self._sd)
         dtype = self._sd[self._keys[0]]._value.dtype
@@ -851,6 +877,10 @@ class ServingEngine:
         self._samp_in = (_In(B, jnp.bool_), _In(B, jnp.float32, ones=True),
                          _In(B, i32), _In(B, jnp.float32, ones=True),
                          _In(B, jnp.uint32))
+        # the prompt's tail (fewer than block_length tokens) that opens
+        # each slot's next block; empty from the second block on
+        self.block_tail: List[List[int]] = [[] for _ in range(max_batch)]
+        self._block_tick_fn = None
         self._decode_fn = None
         self._tick_fns = {}
         self._prefill_fns = {}
@@ -899,6 +929,13 @@ class ServingEngine:
         if self.chunk < 0:
             raise ValueError(
                 f"serving_prefill_chunk must be >= 0: {self.chunk}")
+        if self.gen is not None:
+            # chunks begin and end on multiples of the block length (the
+            # mask is full inside a block); unchunked, a chunk is as long
+            # as the longest bucket
+            Lb = self.gen.block_length
+            self.chunk = max(Lb, (self.chunk or self.pad_ladder[-1])
+                             // Lb * Lb)
         # admissions mid-chunked-prefill, oldest first (the scheduler
         # finishes the oldest before starting the next: chunk budget
         # spent round-robin would inflate EVERY waiting TTFT)
@@ -1072,7 +1109,8 @@ class ServingEngine:
         parameter input, it returns ``forward(ids, pools, tables, lens,
         pos_offset, view_cls=None) -> (logits [B, s, V], new_pools)``,
         which the body may call any number of times (the tick calls it
-        inside its scan).
+        inside its scan; the block tick says ``in_tick=True``, which the
+        views carry to a layer that counts its rows by kind of program).
 
         Degree 1 binds the parameters into the live model (a quantized
         payload dequantizes here, once, outside any scan) and each call
@@ -1095,8 +1133,12 @@ class ServingEngine:
         from ..framework.dygraph import no_grad
         self._bind_params(params)
 
-        def forward(ids, pools, tables, lens, pos_offset, view_cls=None):
+        def forward(ids, pools, tables, lens, pos_offset, view_cls=None,
+                    in_tick=False):
             views = self._views(pools, tables, lens, view_cls)
+            if in_tick:
+                for view in views:
+                    view.in_tick = True
             if not isinstance(pos_offset, int):
                 pos_offset = Tensor._wrap(pos_offset)
             with no_grad():
@@ -1207,6 +1249,76 @@ class ServingEngine:
             (_REP, _POOLS, _REP), self._tick_fns, k,
             (("steps_per_tick", k),)))
 
+    def _block_tick_program(self):
+        """The tick of a block-diffusion model (`self.gen`): for every
+        running slot, one launch runs the `denoising_steps` denoising
+        forwards of its next block and the forward that commits it.
+
+        ``block_toks`` `[B, L]` holds each slot's block as it starts: the
+        prompt's tail, then `[MASK]`.  A denoising forward runs the `L`
+        positions at ``seq_lens`` against the committed K and V and the
+        block itself (its rows are written behind ``seq_lens``, where the
+        next forward overwrites them and nothing else looks), takes
+        `x0 = argmax`, `log c = max - logsumexp` of the float32 logits at
+        each masked position and reveals the `L / denoising_steps` masked
+        positions of highest `c` (ties: the lowest position) as their
+        `x0`.  The commit forward writes the finished block's K and V.
+        Returns (the block's tokens `[B, L]`, for each position the
+        denoising forward that revealed it or -1 for a given token
+        `[B, L]`, the pools, the state rows).  The host advances
+        ``seq_lens`` by `L`."""
+        if self._block_tick_fn is not None:
+            return self._block_tick_fn
+        gen = self.gen
+        L, n_steps = gen.block_length, gen.denoising_steps
+        per = L // n_steps
+
+        def block_tick(params, pools, tables, seq_lens, block_toks):
+            forward = self._forward(params)
+            view = self._chunk_view_cls
+
+            def run(pools, toks):
+                return forward(toks, pools, tables, seq_lens,
+                               seq_lens[:, None], view, in_tick=True)
+
+            def denoise(carry, j):
+                pools, toks, step_of = carry
+                with jax.named_scope("bd_denoise"):
+                    logits, pools = run(pools, toks)
+                with jax.named_scope("bd_reveal"):
+                    logits = logits.astype(jnp.float32)
+                    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    logc = jnp.max(logits, axis=-1) \
+                        - jax.nn.logsumexp(logits, axis=-1)
+                    masked = step_of == n_steps
+                    _, top = jax.lax.top_k(
+                        jnp.where(masked, logc, -jnp.inf), per)
+                    which = jnp.zeros_like(masked).at[
+                        jnp.arange(masked.shape[0])[:, None], top].set(True)
+                    which = which & masked
+                    toks = jnp.where(which, x0, toks)
+                    step_of = jnp.where(which, j, step_of)
+                return (pools, toks, step_of), None
+
+            step_of = jnp.where(block_toks == gen.mask_token_id,
+                                jnp.int32(n_steps), jnp.int32(-1))
+            (pools, toks, step_of), _ = jax.lax.scan(
+                denoise, (pools, block_toks, step_of),
+                jnp.arange(n_steps, dtype=jnp.int32))
+            with jax.named_scope("bd_commit"):
+                _, pools = run(pools, toks)
+            return toks, step_of, pools, self._state_rows(pools)
+
+        i32 = jnp.int32
+        return self._build(_Decl(
+            "serving.block_tick", block_tick,
+            (_PARAMS, _POOLS, _In((self.B, self.nb_per_seq), i32),
+             _In((self.B,), i32), _In((self.B, L), i32)),
+            (_REP, _REP, _POOLS, _REP), vars(self), "_block_tick_fn",
+            (("block_length", L), ("denoising_steps", n_steps)),
+            {"program": "block_tick", "block_length": L,
+             "denoising_steps": n_steps}))
+
     def _prompt_program(self, name, cache, L_pad: int, at_offset: bool):
         """Both prompt programs, one a pad bucket: the prompt (or the
         chunk of one) right-padded to ``L_pad`` is written through the
@@ -1231,6 +1343,10 @@ class ServingEngine:
                 lens, off = jnp.zeros((1,), jnp.int32), 0
             logits, pools = forward(prompt, pools, table_row, lens, off,
                                     view_cls)
+            if self.gen is not None:
+                # a block-diffusion prompt is prefilled for its K and V:
+                # its first tokens come from the block tick, not from here
+                return jnp.zeros((), jnp.float32), pools
             row = jax.lax.dynamic_index_in_dim(
                 logits[0], true_len - 1, axis=0, keepdims=False)
             return row, pools
@@ -1434,6 +1550,13 @@ class ServingEngine:
         ``FLAGS_serving_device_sampling``: the flag is read live at
         every dispatch, so a mid-run flip must not route traffic to an
         un-warmed program."""
+        if self.gen is not None:
+            # one tick whatever the mix, and every prompt through the
+            # chunk programs (no copy-on-write: a prefix hit ends on a
+            # whole block, behind which the prompt is prefilled afresh)
+            return [self._block_tick_program] + [
+                partial(self._prefill_cont_program, L)
+                for L in self.pad_ladder]
         grid = [partial(self._tick_program, k)
                 for k in sorted({self.steps_per_tick, 1}, reverse=True)]
         grid.append(self._decode_program)
@@ -1582,6 +1705,21 @@ class ServingEngine:
             raise ValueError(
                 "engine is draining: admission closed (retry against "
                 "another replica)")
+        if self.gen is not None:
+            why = None
+            if req.do_sample:
+                why = ("sampling", "block-diffusion generation is served "
+                       "greedy (tokens are revealed by confidence); "
+                       "do_sample is not available")
+            elif self.gen.mask_token_id in req.prompt_ids:
+                why = ("mask_token", "the prompt holds the model's [MASK] "
+                       f"token {self.gen.mask_token_id}")
+            if why is not None:
+                _M_REJECTIONS.inc(reason=why[0])
+                self._ev_note(f"rejected:{why[0]}")
+                if traced:
+                    self._reject_trace(req, why[0])
+                raise ValueError(why[1])
         if L + req.max_new_tokens > self.max_context:
             _M_REJECTIONS.inc(reason="over_context")
             self._ev_note("rejected:over_context")
@@ -1880,6 +2018,10 @@ class ServingEngine:
         req = self.waiting[0]
         L = len(req.prompt_ids)
         chunked = self.chunk > 0
+        # the prompt tokens a hit may stand for: all but the last, whose
+        # logits are the first token — or, under block diffusion, the
+        # whole blocks (no logits are needed of a prompt)
+        reusable = L - 1 if self.gen is None else self._prefill_len(req)
         # --- prefix lookup: the longest resident full-block prefix is a
         # pointer copy; reuse is capped at L-1 so at least one suffix
         # token runs forward (its logits are the request's first token).
@@ -1902,7 +2044,7 @@ class ServingEngine:
                 req._prefix_match = match
                 req._prefix_epoch = self.prefix.epoch
             chain = match.blocks
-            cached_len = min(len(chain) * self.bs, L - 1)
+            cached_len = min(len(chain) * self.bs, reusable)
             if cached_len <= 0:
                 chain, cached_len = [], 0
         split_col = cached_len // self.bs
@@ -2111,32 +2253,26 @@ class ServingEngine:
         activate the slot for decode ticks."""
         L = len(req.prompt_ids)
         _M_ADMISSIONS.inc()
+        req._t_admit = t_admit
+        if _metrics.enabled():
+            _M_QWAIT.observe(t_admit - req._t_enqueue)
+        req.slot = slot
+        self.slot_req[slot] = req
+        if self.gen is not None:
+            # block diffusion: the slot stands at the prompt's last whole
+            # block; its first tokens (and its TTFT) come with its first
+            # block tick
+            p0 = self._prefill_len(req)
+            self.seq_lens[slot] = p0
+            self.block_tail[slot] = list(req.prompt_ids[p0:])
+            self._update_occupancy()
+            return
         first = req._sample(np.asarray(row))
         # np.asarray(row) above was the host sync: the first token
         # really exists now, so this is TTFT, not enqueue time
-        t_first = time.perf_counter()
-        req._t_admit, req._t_first = t_admit, t_first
-        req._t_last = t_first
-        ttft = t_first - req._t_enqueue
-        slo = _flags.get_flag("serving_ttft_slo_ms")
-        late = slo > 0 and ttft * 1e3 > slo
-        if _metrics.enabled():
-            _M_QWAIT.observe(t_admit - req._t_enqueue)
-            _M_TTFT.observe(ttft)
-            if late:
-                _M_SLO.inc(metric="ttft")
-        # router evidence (always on, unlike the metrics-gated sketches
-        # above): the /healthz TTFT predictor needs admission rate and
-        # recent TTFTs even on engines running with metrics disabled,
-        # and the fleet burn-rate monitor its tally of SLO violations
-        self._admit_times.append(t_first)
-        self._ttft_recent.append(ttft)
-        if late:
-            self._ev_slo_viol += 1
+        self._note_first_token(req, time.perf_counter())
         req.output_ids.append(first)
         req._stream_push(first)
-        req.slot = slot
-        self.slot_req[slot] = req
         self.seq_lens[slot] = L
         self.last_tok[slot] = first
         self.samp_do[slot] = req.do_sample
@@ -2149,6 +2285,25 @@ class ServingEngine:
         _M_TOKENS.inc()
         self._update_occupancy()
         self._maybe_finish(req, first)
+
+    def _note_first_token(self, req, t_first: float) -> None:
+        """Stamp a request's first token and feed the TTFT evidence."""
+        req._t_first = req._t_last = t_first
+        ttft = t_first - req._t_enqueue
+        slo = _flags.get_flag("serving_ttft_slo_ms")
+        late = slo > 0 and ttft * 1e3 > slo
+        if _metrics.enabled():
+            _M_TTFT.observe(ttft)
+            if late:
+                _M_SLO.inc(metric="ttft")
+        # router evidence (always on, unlike the metrics-gated sketches
+        # above): the /healthz TTFT predictor needs admission rate and
+        # recent TTFTs even on engines running with metrics disabled,
+        # and the fleet burn-rate monitor its tally of SLO violations
+        self._admit_times.append(t_first)
+        self._ttft_recent.append(ttft)
+        if late:
+            self._ev_slo_viol += 1
 
     def _free_capacity(self) -> int:
         """Free blocks INCLUDING those held only by the prefix index —
@@ -2230,6 +2385,7 @@ class ServingEngine:
                 self.tables[slot, col] = 0
         self.seq_lens[slot] = 0
         self.last_tok[slot] = 0
+        self.block_tail[slot] = []
         self.samp_do[slot] = False
         self.samp_temp[slot] = 1.0
         self.samp_topk[slot] = 0
@@ -2446,7 +2602,6 @@ class ServingEngine:
         req._prefilling = True
         req._prefill_chunks = 0
         self.slot_req[slot] = req
-        self.prefilling.append(req)
         if self.prefix is not None:
             req._prefix_blocks = split_col + (1 if cow_src is not None
                                               else 0)
@@ -2459,8 +2614,21 @@ class ServingEngine:
             else:
                 self.prefix.misses += 1
                 _M_PREFIX_MISSES.inc()
+        if cached_len >= self._prefill_len(req):
+            # block diffusion: the prompt's whole blocks were all hits
+            # (or it has none), and its tail opens the first block
+            self._complete_chunked(req, None)
+        else:
+            self.prefilling.append(req)
         self._update_occupancy()
         return True
+
+    def _prefill_len(self, req) -> int:
+        """The prompt tokens the chunk programs absorb: all of them, or
+        under block diffusion its whole blocks (the tail opens the first
+        block of the generation)."""
+        L = len(req.prompt_ids)
+        return L if self.gen is None else L - L % self.gen.block_length
 
     def _prefill_chunk_step(self, req) -> None:
         """Dispatch ONE bounded prefill chunk for an in-flight chunked
@@ -2470,7 +2638,7 @@ class ServingEngine:
         offset causal mask).  The LAST chunk's logits row is the
         request's first token."""
         slot = req.slot
-        L = len(req.prompt_ids)
+        L = self._prefill_len(req)
         off = req._chunk_off
         n = min(self.chunk, L - off)
         L_pad = self._pad_bucket(n)
@@ -2656,6 +2824,8 @@ class ServingEngine:
                    selected_tokens=self._selected(lens)) as sp:
             pend = self._launch_tick(active, t0, chain)
             sp.set(steps=pend.k)
+            if pend.block is not None:
+                sp.set(block_len=self.gen.block_length)
         pend.dispatch_s = sp.seconds
         pend.chunks = self._chunks_this_boundary
         self._chunks_this_boundary = 0
@@ -2687,6 +2857,8 @@ class ServingEngine:
     def _launch_tick(self, active, t0, chain):
         """Enqueue the tick program over ``active`` (the speculative
         one where eligible) and advance the host's view of the slots."""
+        if self.gen is not None:
+            return self._launch_block_tick(active, t0)
         device_sampling = _flags.get_flag("serving_device_sampling")
         # a chained dispatch continues its predecessor's kind (the
         # overlap gate matched them); at a boundary, spec eligibility is
@@ -2752,6 +2924,55 @@ class ServingEngine:
                             device_sampling=device_sampling,
                             step_no=self.steps, san=san)
         pend.state = state
+        return pend
+
+    def _launch_block_tick(self, active, t0):
+        """Enqueue the block tick over ``active``: each slot's next block
+        (the prompt's tail, then `[MASK]`) is denoised and committed, and
+        the host's view of the slot moves a block on.  Of the block's new
+        tokens a request takes what its budget has left; the rest are
+        dropped at harvest."""
+        gen = self.gen
+        Lb = gen.block_length
+        toks_in = np.full((self.B, Lb), gen.mask_token_id, np.int32)
+        given = np.zeros((self.B,), np.int32)
+        new = np.zeros((self.B,), np.int32)
+        for slot in active:
+            req = self.slot_req[slot]
+            tail = self.block_tail[slot]
+            toks_in[slot, :len(tail)] = tail
+            given[slot] = len(tail)
+            new[slot] = min(Lb - len(tail),
+                            req.max_new_tokens - int(self.tok_pos[slot]))
+            # the block lies inside one pool block (block_size is a
+            # multiple of its length): draw it if the block opens one
+            pos = int(self.seq_lens[slot])
+            col = pos // self.bs
+            if self.tables[slot, col] == 0:
+                self.tables[slot, col] = self._alloc_block()
+                self.reserved -= 1
+                req._growth_left -= 1
+        san = _jaxsan.token("serving.tick")
+        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
+        with self._params_for_call() as param_vals, \
+                _flight.guard("serving.tick"):
+            toks, step_of, self.pools, state = self._dispatch_call(
+                "serving.tick.dispatch",
+                lambda: self._block_tick_program()(
+                    param_vals, self.pools, dev(self.tables),
+                    dev(self.seq_lens), dev(toks_in)))
+        forwards = gen.denoising_steps + 1
+        self.steps += forwards
+        for slot in active:
+            self.seq_lens[slot] += Lb
+            self.tok_pos[slot] += int(new[slot])
+            self.block_tail[slot] = []
+        pend = _PendingTick(active=active, k=forwards, toks=toks,
+                            logits=None, reqs=list(self.slot_req), t0=t0,
+                            device_sampling=True, step_no=self.steps,
+                            san=san)
+        pend.state = state
+        pend.block = (given, new, step_of)
         return pend
 
     def _spec_eligible(self, active, device_sampling) -> bool:
@@ -3010,6 +3231,41 @@ class ServingEngine:
                 self._spec_ticks_since_adapt += 1
             if spec_accepted:
                 _M_SPEC_ACCEPTED.inc(spec_accepted)
+        elif pend.block is not None:
+            # block-diffusion tick: each slot's block arrives whole; its
+            # new tokens (behind the prompt's tail, within the budget) are
+            # handed over together, each with the forward that revealed it
+            given, new, step_of = pend.block
+            step_of = np.asarray(step_of)
+            per = self.gen.block_length // self.gen.denoising_steps
+            _M_BLOCK_FORWARDS.inc(k)
+            for slot in pend.active:
+                req = pend.reqs[slot]
+                masks = self.gen.block_length - int(given[slot])
+                if masks >= per:
+                    _M_BLOCK_REVEALED.inc((masks // per) * per,
+                                          per_forward=per)
+                if masks % per:
+                    _M_BLOCK_REVEALED.inc(masks % per,
+                                          per_forward=masks % per)
+                if req.done:
+                    continue
+                req._ticks += 1
+                if req._t_first is None:
+                    # its first tokens: a TTFT, and no inter-token gap
+                    self._note_first_token(req, time.perf_counter())
+                else:
+                    harvested_by.append((req, len(req.output_ids)))
+                lo = int(given[slot])
+                for j in range(lo, lo + int(new[slot])):
+                    if req.done:
+                        break    # tokens behind an eos are dropped
+                    tok = int(toks[slot, j])
+                    req.output_ids.append(tok)
+                    req.reveal_steps.append(int(step_of[slot, j]))
+                    req._stream_push(tok)
+                    self.tokens_out += 1
+                    self._maybe_finish(req, tok)
         else:
             for slot in pend.active:
                 req = pend.reqs[slot]
@@ -3058,7 +3314,9 @@ class ServingEngine:
             # (one harvest gap imputed to the k tokens it yielded) —
             # deliberately NOT per-request timing, so the "metrics off
             # = zero per-request tracing work" pin stays intact
-            self._ev_tpot.add(dt / max(k, 1), weight=harvested)
+            self._ev_tpot.add(
+                dt / max(k if pend.block is None
+                         else self.gen.block_length, 1), weight=harvested)
         # per-token inter-token latency (TPOT): tokens arrive k at a
         # time, so each of this harvest's tokens is imputed an equal
         # share of the gap since the request's previous token
@@ -3167,8 +3425,8 @@ class ServingEngine:
         spec tick (on the device seq_lens/last handles, needing spec_k
         budget beyond the in-flight upper bound), a plain tick a plain
         one — a kind switch is a real boundary (harvest first)."""
-        if not _flags.get_flag("serving_overlap"):
-            return False
+        if not _flags.get_flag("serving_overlap") or self.gen is not None:
+            return False     # (a block tick is harvested before the next)
         if self.waiting:
             return False     # admissions join at a real boundary
         if self.prefilling and not self._chunk_overlap_ok():

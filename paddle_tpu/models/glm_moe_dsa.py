@@ -40,7 +40,8 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..framework.tensor import Tensor
-from ..incubate.distributed.models.moe import HeldExpertsLayer
+from ..incubate.distributed.models.moe import (HeldExpertsLayer,
+                                              SigmoidTopKGate)
 from ..incubate.nn.functional import fused_rotary_position_embedding
 from .generation import GenerationMixin
 from .kv_cache import CacheSpec, LatentPagedCache, PoolRow
@@ -224,12 +225,13 @@ class GlmMoeDsaBlock(nn.Layer):
         if self.is_moe:
             self.mlp = HeldExpertsLayer(
                 cfg.hidden_size, cfg.moe_intermediate_size,
-                cfg.n_routed_experts, cfg.num_experts_per_tok,
+                SigmoidTopKGate(cfg.hidden_size, cfg.n_routed_experts,
+                                cfg.num_experts_per_tok,
+                                cfg.routed_scaling_factor),
                 n_experts_held=cfg.n_experts_held,
                 expert_offset=cfg.expert_offset,
                 shared=mlp(cfg.moe_intermediate_size
-                           * cfg.n_shared_experts),
-                routed_scaling_factor=cfg.routed_scaling_factor)
+                           * cfg.n_shared_experts))
         else:
             self.mlp = mlp(cfg.intermediate_size)
 

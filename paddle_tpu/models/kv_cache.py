@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 __all__ = ["StaticKVCache", "PagedKVCache", "PagedChunkView",
            "PagedChunkKernelView", "PagedVerifyKernelView", "PoolRow",
-           "CacheSpec", "kv_cache_spec", "LatentPagedCache"]
+           "CacheSpec", "BlockDiffusion", "kv_cache_spec", "LatentPagedCache"]
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -137,19 +137,45 @@ class PagedKVCache:
                        ).reshape(batch, nb)
         self.seq_lens = jnp.zeros((batch,), jnp.int32)
 
+    # a layer's arrays that are not pools (`PoolRow.paged` false: an
+    # expert layer's row counts), threaded with them: `()` for GPT / Llama
+    state = ()
+    # whether the view was made for a forward of a block-diffusion tick
+    # (the engine says so: `ServingEngine._forward`) and not for a prompt
+    # chunk of as many rows; read on the view a layer is handed
+    in_tick = False
+
     @classmethod
-    def from_parts(cls, k, v, tables, seq_lens, block_size):
+    def from_parts(cls, k, v, *rest, block_size=None):
         """The one constructor for views over existing pools (used by the
-        pytree unflattener and the serving engine's per-call views)."""
+        pytree unflattener and the serving engine's per-call views):
+        `(k, v, *state, tables, seq_lens, block_size)`, `state` the
+        layer's arrays that are not paged, in `CacheSpec.rows` order."""
+        if block_size is None:
+            *rest, block_size = rest
+        *state, tables, seq_lens = rest
         c = cls.__new__(cls)
         c.k, c.v, c.tables, c.seq_lens, c.bs = k, v, tables, seq_lens, \
             block_size
+        c.state = tuple(state)
         return c
 
     @property
     def pools(self):
         """The layer's device arrays, in its `CacheSpec.rows` order."""
-        return (self.k, self.v)
+        return (self.k, self.v) + self.state
+
+    @property
+    def active(self):
+        """`[B]`: which sequences of the batch are real.  An idle slot
+        has a zero table row (its writes go to the pad block 0)."""
+        return self.tables[:, 0] != 0
+
+    def with_state(self, *state):
+        """This view over the same pools with `state` in `self.state`'s
+        place."""
+        return type(self).from_parts(self.k, self.v, *state, self.tables,
+                                     self.seq_lens, self.bs)
 
     def update_and_attend(self, q, k, v):
         """q/k/v: jnp [B, s, nh, hd] (post-RoPE).  s == 1 -> paged decode
@@ -159,7 +185,7 @@ class PagedKVCache:
         from ..ops import pallas_paged
         B, s, nh, hd = q.shape
         new = PagedKVCache.__new__(PagedKVCache)
-        new.bs, new.tables = self.bs, self.tables
+        new.bs, new.tables, new.state = self.bs, self.tables, self.state
         if s == 1:
             out, new.k, new.v = pallas_paged.paged_decode_step(
                 q[:, 0], k[:, 0], v[:, 0], self.k, self.v, self.tables,
@@ -205,76 +231,67 @@ class PagedChunkView(PagedKVCache):
     in one chunk): from-empty prefill never needs the gather, and the
     serving engine keeps using the cheaper base program when neither a
     cached prefix nor chunking is in play.  Decode steps (``s == 1``)
-    fall through to the base paged kernel unchanged.  GQA models whose
-    attention layer hands over un-repeated kv heads get them repeated
-    here to the pool's per-query-head layout (the same resolution the
-    Llama paged path applies before the cache)."""
+    fall through to the base paged kernel unchanged.
 
-    def update_and_attend(self, q, k, v):
-        if q.shape[1] == 1:
+    The pools hold the model's KV heads (`[nkv, ...]`): a grouped-query
+    model hands over its `nkv` heads as they are, they are written as
+    they are, and query head `j` reads pool head `j // (nh / nkv)`
+    (nothing repeats K or V).  `mask_block = L` makes the mask causal
+    over blocks of `L` positions and full inside one (`key < (query // L
+    + 1) * L`; a block-diffusion model's, whose chunks start at multiples
+    of `L`); 1 is the offset causal mask."""
+
+    def update_and_attend(self, q, k, v, mask_block: int = 1):
+        if q.shape[1] == 1 and mask_block == 1 \
+                and k.shape[2] == q.shape[2]:
             return super().update_and_attend(q, k, v)
-        new, pos = self._write_chunk(q, k, v)
-        return new, self._attend_chunk(q, new, pos)
+        new = self._write_chunk(q, k, v, mask_block)
+        return new, self._attend_chunk(q, new, mask_block)
 
-    def _write_chunk(self, q, k, v):
+    def _write_chunk(self, q, k, v, mask_block: int = 1):
         """Write the chunk through the block table at absolute positions
         ``seq_lens + j`` (`pallas_paged.paged_write_chunk`: in place, a
-        block at a time); returns (advanced view, pos[B, s])."""
+        block at a time); returns the advanced view."""
         from ..ops import pallas_paged
-        nh = q.shape[2]
-        s = q.shape[1]
-        if k.shape[2] != nh:
-            if nh % k.shape[2]:
-                raise ValueError(
-                    f"kv heads {k.shape[2]} do not divide query heads "
-                    f"{nh}")
-            rep = nh // k.shape[2]
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        start = self.seq_lens                          # [B] cached tokens
-        pos = start[:, None] + jnp.arange(s, dtype=start.dtype)  # [B, s]
+        if k.shape[2] != self.k.shape[0]:
+            raise ValueError(
+                f"the chunk carries {k.shape[2]} kv heads, the pool holds "
+                f"{self.k.shape[0]}")
         cls = type(self)
         new = cls.__new__(cls)
-        new.bs, new.tables = self.bs, self.tables
+        new.bs, new.tables, new.state = self.bs, self.tables, self.state
         new.k, new.v = pallas_paged.paged_write_chunk(
-            self.k, self.v, self.tables, start, k, v)
-        new.seq_lens = self.seq_lens + s
-        return new, pos
+            self.k, self.v, self.tables, self.seq_lens, k, v,
+            align=mask_block if self.bs % mask_block == 0 else 1)
+        new.seq_lens = self.seq_lens + q.shape[1]
+        return new
 
-    def _attend_chunk(self, q, new, pos):
+    def _attend_chunk(self, q, new, mask_block: int = 1):
         """Linearize the table (cached prefix + just-written chunk) and
-        attend with the offset causal mask: query at absolute position
-        p sees keys 0..p — all real written positions for real queries
+        attend under the mask (`paged_chunk_attention_reference`): a
+        query at absolute position p sees keys 0..p, or to the end of
+        its mask block — all real written positions for real queries
         (padded chunk rows attend garbage and are discarded upstream)."""
-        B, s, nh, hd = q.shape
-        nb = self.tables.shape[1]
-        k_lin = jnp.take(new.k, self.tables, axis=1)   # [nh, B, nb, bs, hd]
-        v_lin = jnp.take(new.v, self.tables, axis=1)
-        k_lin = k_lin.reshape(nh, B, nb * self.bs, hd)
-        v_lin = v_lin.reshape(nh, B, nb * self.bs, hd)
-        logits = jnp.einsum("bqhd,hbkd->bhqk", q.astype(jnp.float32),
-                            k_lin.astype(jnp.float32)) / math.sqrt(hd)
-        kpos = jnp.arange(nb * self.bs, dtype=pos.dtype)
-        mask = kpos[None, :] <= pos[:, :, None]        # [B, s, K]
-        logits = jnp.where(mask[:, None], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.einsum("bhqk,hbkd->bqhd", probs,
-                          v_lin.astype(jnp.float32)).astype(q.dtype)
+        from ..ops import pallas_paged
+        return pallas_paged.paged_chunk_attention_reference(
+            q, new.k, new.v, self.tables, self.seq_lens, mask_block)
 
 
 class PagedChunkKernelView(PagedChunkView):
     """`PagedChunkView` with the dense linearized-table attend replaced
     by the chunked paged-prefill Pallas kernel
-    (`ops/pallas_paged.paged_chunk_attention`).  The write path — GQA
-    head repeat, table-routed block writes, pad-block overflow — is inherited
-    unchanged, so the two views differ only in how the attend lowers.
+    (`ops/pallas_paged.paged_chunk_attention`).  The write path —
+    table-routed block writes of the kv heads, pad-block overflow — is
+    inherited unchanged, so the two views differ only in how the attend
+    lowers.
     Selected by the serving engine when `FLAGS_serving_pallas_prefill`
     is on (snapshotted at engine init, never read under trace)."""
 
-    def _attend_chunk(self, q, new, pos):
+    def _attend_chunk(self, q, new, mask_block: int = 1):
         from ..ops import pallas_paged
         return pallas_paged.paged_chunk_attention(
-            q, new.k, new.v, self.tables, self.seq_lens)
+            q, new.k, new.v, self.tables, self.seq_lens,
+            mask_block=mask_block)
 
 
 class PagedVerifyKernelView(PagedChunkKernelView):
@@ -283,8 +300,11 @@ class PagedVerifyKernelView(PagedChunkKernelView):
     distinct entry point so the verify program carries its own audit
     claim and its own flag (`FLAGS_serving_pallas_verify`)."""
 
-    def _attend_chunk(self, q, new, pos):
+    def _attend_chunk(self, q, new, mask_block: int = 1):
         from ..ops import pallas_paged
+        if mask_block != 1:
+            raise ValueError("the verify kernel runs under the causal mask "
+                             f"only (mask_block={mask_block})")
         return pallas_paged.paged_verify_attention(
             q, new.k, new.v, self.tables, self.seq_lens)
 
@@ -317,6 +337,19 @@ class PoolRow:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """How a block-diffusion model generates (`CacheSpec.generation`): a
+    block of `block_length` positions is denoised in `denoising_steps`
+    forwards, each revealing the `block_length / denoising_steps` masked
+    positions of highest confidence, from `mask_token_id`, and committed
+    by one more forward; the mask is causal over such blocks and full
+    inside one."""
+    block_length: int
+    denoising_steps: int
+    mask_token_id: int
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What `ServingEngine` needs to know of a model's cache, from
     `model.cache_spec()`: the arrays a layer keeps (`rows`, the same for
@@ -327,7 +360,9 @@ class CacheSpec:
     `unsupported` names the engine mechanisms this cache cannot run
     under, with the reason: the engine raises at construction.
     `attend_limit` is the size of a sparse selection, for the spans'
-    `selected_tokens`."""
+    `selected_tokens`.  `generation` is None for a model that yields one
+    token a forward and sequence, or says what else it does
+    (`BlockDiffusion`): the engine builds its tick from it."""
     num_layers: int
     rows: tuple
     view: type                    # decode step, prefill from empty
@@ -336,6 +371,7 @@ class CacheSpec:
     verify_kernel_view: type = None  # spec-decode verify
     unsupported: dict = dataclasses.field(default_factory=dict)
     attend_limit: int = 0         # tokens a query attends at most; 0: all
+    generation: object = None
 
     def __post_init__(self):
         for name in ("chunk_view", "chunk_kernel_view",
@@ -354,13 +390,19 @@ class CacheSpec:
                 for _ in range(self.num_layers)]
 
 
-def kv_cache_spec(num_layers: int, num_heads: int, head_dim: int):
+def kv_cache_spec(num_layers: int, num_kv_heads: int, head_dim: int,
+                  state_rows: tuple = (), **spec):
     """The (K, V) cache of the GPT / Llama families: two pools a layer,
-    heads leading, one row of `head_dim` a head and token."""
-    row = dict(lead=(num_heads,), trail=(head_dim,))
-    return CacheSpec(num_layers, (PoolRow("k", **row), PoolRow("v", **row)),
+    the KV heads leading (`[num_kv_heads, blocks + 1, block_size,
+    head_dim]`: a grouped-query model's are fewer than its query heads),
+    one row of `head_dim` a KV head and token.  `state_rows`: the layer's
+    `PoolRow`s that are not paged, behind the pools; `spec`: further
+    `CacheSpec` fields."""
+    row = dict(lead=(num_kv_heads,), trail=(head_dim,))
+    return CacheSpec(num_layers, (PoolRow("k", **row), PoolRow("v", **row))
+                     + tuple(state_rows),
                      PagedKVCache, PagedChunkView, PagedChunkKernelView,
-                     PagedVerifyKernelView)
+                     PagedVerifyKernelView, **spec)
 
 
 class LatentPagedCache:

@@ -530,14 +530,18 @@ def paged_write_prefill(k_pool, v_pool, tables, k, v):
     return jax.lax.fori_loop(0, B * nb, put_block, (k_pool, v_pool))
 
 
-@jax.jit
-def paged_write_chunk(k_pool, v_pool, tables, start_lens, k, v):
+@functools.partial(jax.jit, static_argnames=("align",))
+def paged_write_chunk(k_pool, v_pool, tables, start_lens, k, v, align=1):
     """Traced chunk write at an offset: token j of stream b's chunk
-    (k/v [B, s, nh, hd], heads already repeated to the pool's) lands at
-    absolute position ``start_lens[b] + j`` through the block table.
+    (k/v [B, s, nkv, hd], the pool's own kv heads) lands at absolute
+    position ``start_lens[b] + j`` through the block table.
 
-    A chunk of s tokens from a traced, unaligned start touches at most
-    n = ceil((s + bs - 1) / bs) consecutive table columns.  Each of those
+    A chunk of s tokens from a traced start that is a multiple of
+    `align` (1: any start; a divisor of the block size) touches at most
+    n = ceil((s + bs - align) / bs) consecutive table columns: one where a
+    block-diffusion model's block of `align` tokens lies inside a pool
+    block, where an unaligned write of as many has to allow for two.
+    Each of those
     blocks is read, merged with the chunk's rows under a position mask
     and put back in place (`_put`): a block the chunk does not reach is
     rewritten as it was.  Columns past the table go to the pad block 0
@@ -550,7 +554,7 @@ def paged_write_chunk(k_pool, v_pool, tables, start_lens, k, v):
     nh, _, bs, hd = k_pool.shape
     B, s = k.shape[0], k.shape[1]
     nb = tables.shape[1]
-    n = (s + 2 * bs - 2) // bs
+    n = (s + 2 * bs - align - 1) // bs
     r = start_lens % bs                                     # [B]
     cols = (start_lens // bs)[:, None] + jnp.arange(
         n, dtype=start_lens.dtype)                          # [B, n]
@@ -588,16 +592,32 @@ def paged_copy_block(pool, src, dst, block_axis: int = 1):
     return jax.lax.dynamic_update_slice_in_dim(pool, block, dst, block_axis)
 
 
+def _visible(kpos, qpos, mask_block):
+    """Which keys (positions `kpos`) a query at `qpos` sees: causal over
+    blocks of `mask_block` positions and full inside one, `kpos <
+    (qpos // L + 1) * L`; `L = 1` is the offset-causal `kpos <= qpos`."""
+    if mask_block == 1:
+        return kpos <= qpos
+    return kpos < (qpos // mask_block + 1) * mask_block
+
+
 def _chunk_grid_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
                        m_scr, l_scr, acc_scr, *, scale, bs, max_blocks,
-                       q_blk):
+                       q_blk, group, mask_block):
     """Flash-style chunk prefill, grid (B, s/q_blk, max_blocks): one
     instance = one q tile of one sequence against one physical block,
     streamed through the scalar-prefetched table (the DMA does the
     gather, like `_decode_kernel`).  Online-softmax state lives in VMEM
     scratch across the sequential block dimension.  Queries sit at
     absolute positions `start + j` (start = cached prefix length), so
-    the causal mask is offset: key position <= query position."""
+    the causal mask is offset: key position <= query position, or
+    `_visible`'s block form under `mask_block > 1`.
+
+    The query tile arrives folded, `[nkv, q_blk, hd]` with row `r` the
+    query head `r % group` of its kv head at chunk position `r // group`:
+    the `group` query heads of a kv head (grouped-query attention; 1 where
+    every query head has a pool head of its own) are rows of ONE matmul
+    against that head's block, and nothing repeats K or V."""
     b = pl.program_id(0)
     qt = pl.program_id(1)
     blk = pl.program_id(2)
@@ -609,20 +629,21 @@ def _chunk_grid_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     start = starts_ref[b]
-    qpos = start + qt * q_blk + jax.lax.broadcasted_iota(
-        jnp.int32, (q_blk, 1), 0)[:, 0]                   # [q_blk]
-    qpos_max = start + (qt + 1) * q_blk - 1
+    qpos = start + (qt * q_blk + jax.lax.broadcasted_iota(
+        jnp.int32, (q_blk, 1), 0)[:, 0]) // group         # [q_blk]
+    qpos_max = start + ((qt + 1) * q_blk - 1) // group
 
-    @pl.when(blk * bs <= qpos_max)
+    @pl.when(_visible(blk * bs, qpos_max, mask_block))
     def _():
-        q = jnp.transpose(q_ref[...], (1, 0, 2))          # [nh, q_blk, hd]
-        k = k_ref[...]                                    # [nh, bs, hd]
+        q = q_ref[...]                                    # [nkv, q_blk, hd]
+        k = k_ref[...]                                    # [nkv, bs, hd]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale   # [nh, q_blk, bs]
         kpos = blk * bs + jax.lax.broadcasted_iota(
             jnp.int32, (q_blk, bs), 1)
-        s = jnp.where((kpos <= qpos[:, None])[None], s, _NEG_INF)
+        s = jnp.where(_visible(kpos, qpos[:, None], mask_block)[None], s,
+                      _NEG_INF)
         m_prev = m_scr[:, :]                              # [nh, q_blk]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
         p = jnp.exp(s - m_new[:, :, None])
@@ -639,12 +660,11 @@ def _chunk_grid_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
     def _():
         l = l_scr[:, :]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_scr[:] / l_safe[:, :, None]             # [nh, q_blk, hd]
-        o_ref[...] = jnp.transpose(out, (1, 0, 2)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[:] / l_safe[:, :, None]).astype(o_ref.dtype)
 
 
 def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
-                        *, scale, bs, max_blocks, s):
+                        *, scale, bs, max_blocks, s, group, mask_block):
     """Single-pass variant, grid (B,): the whole chunk of one sequence in
     one instance, a `fori_loop` over only the LIVE blocks (trip count
     `ceil((start + s) / bs)` — data-dependent, unlike a grid dimension).
@@ -655,14 +675,17 @@ def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
     — linear in POOL size, which loses to the dense gather at any real
     pool.  One grid step per sequence pays the pool copy once and skips
     dead table columns entirely, which is also where the win over dense
-    comes from: dense attends the full padded table width."""
+    comes from: dense attends the full padded table width.  `s` counts
+    the query rows, folded as in `_chunk_grid_kernel`: `[nkv, s, hd]`."""
     b = pl.program_id(0)
     start = starts_ref[b]
-    q = q_ref[...]                                        # [s, nh, hd]
-    nh, hd = q.shape[1], q.shape[2]
-    q = jnp.transpose(q, (1, 0, 2)).astype(jnp.float32)   # [nh, s, hd]
-    qpos = start + jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)[:, 0]
-    n_iter = jnp.minimum((start + s + bs - 1) // bs, max_blocks)
+    q = q_ref[...].astype(jnp.float32)                    # [nkv, s, hd]
+    nh, hd = q.shape[0], q.shape[2]
+    qpos = start + jax.lax.broadcasted_iota(
+        jnp.int32, (s, 1), 0)[:, 0] // group
+    # the keys the last query sees: to the end of its mask block
+    end = ((start + (s - 1) // group) // mask_block + 1) * mask_block
+    n_iter = jnp.minimum((end + bs - 1) // bs, max_blocks)
 
     def body(i, carry):
         m, l, acc = carry
@@ -673,7 +696,8 @@ def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
             q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale   # [nh, s, bs]
         kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-        sc = jnp.where((kpos <= qpos[:, None])[None], sc, _NEG_INF)
+        sc = jnp.where(_visible(kpos, qpos[:, None], mask_block)[None], sc,
+                       _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=2))
         p = jnp.exp(sc - m_new[:, :, None])
         alpha = jnp.exp(m - m_new)
@@ -688,8 +712,7 @@ def _chunk_fused_kernel(tables_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
     a0 = jnp.zeros((nh, s, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_iter, body, (m0, l0, a0))
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = jnp.transpose(acc / l[:, :, None],
-                               (1, 0, 2)).astype(o_ref.dtype)
+    o_ref[...] = (acc / l[:, :, None]).astype(o_ref.dtype)
 
 
 def _chunk_q_tile(s, nh, hd):
@@ -713,20 +736,29 @@ def _chunk_q_tile(s, nh, hd):
 
 def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
                           interpret=None, strategy=None, q_blk=None,
-                          _claim_name="paged_chunk_prefill"):
+                          mask_block=1, _claim_name="paged_chunk_prefill"):
     """Chunked/suffix prefill attention over a paged KV cache.
 
     q:            [B, s, nh, hd]  chunk queries (s > 1 typical; post-RoPE)
-    k_cache/v_cache: [nh, num_blocks, bs, hd] physical block pool with
+    k_cache/v_cache: [nkv, num_blocks, bs, hd] physical block pool with
         the chunk ALREADY WRITTEN at positions start..start+s-1 (the
         write stays the caller's — `PagedChunkView` through
-        `paged_write_chunk`, in place and in this layout)
+        `paged_write_chunk`, in place and in this layout).  `nkv` divides
+        `nh`: query head `j` attends pool head `j // (nh / nkv)`
+        (grouped-query attention; `nkv = nh` is one pool head a query
+        head).  The `nh / nkv` query heads of a pool head are folded into
+        the query rows, so a chunk of 4 positions under 8 heads a group
+        is one 32-row matmul a pool head against each block; K and V are
+        never repeated.
     block_tables: [B, max_blocks] int32 physical block ids (pad with 0)
     start_lens:   [B] int32 cached-prefix length per sequence; query j
         sits at absolute position start + j and attends keys 0..start+j
         (offset causal mask, `PagedChunkView`'s contract — including the
         overflow rows past the table, which attend the whole table and
         are discarded upstream)
+    mask_block: `L`; a query at position `t` sees key `s` iff
+        `s < (t // L + 1) * L`: causal over blocks of `L` positions, full
+        inside one (block-diffusion models).  1 is the offset causal mask.
     strategy: "grid" (flash tiles over (B, s-tiles, blocks) — the TPU
         layout) or "fused" (one pass per sequence — the interpret-mode
         layout; see `_chunk_fused_kernel`).  Default: by `interpret`.
@@ -738,86 +770,102 @@ def paged_chunk_attention(q, k_cache, v_cache, block_tables, start_lens,
         strategy = "fused" if interpret else "grid"
     pallas_common.claim(_claim_name, interpret)
     B, s, nh, hd = q.shape
-    bs = k_cache.shape[2]
+    nkv, bs = k_cache.shape[0], k_cache.shape[2]
+    if nh % nkv:
+        raise ValueError(f"kv heads {nkv} do not divide query heads {nh}")
+    group = nh // nkv
     max_blocks = block_tables.shape[1]
     scale = 1.0 / math.sqrt(hd)
+    rows = s * group               # query rows a pool head
+    # [B, s, nkv, group, hd] -> [B, nkv, s * group, hd]
+    q = jnp.transpose(q.reshape(B, s, nkv, group, hd),
+                      (0, 2, 1, 3, 4)).reshape(B, nkv, rows, hd)
+
+    def q_spec(tile, index):
+        return pl.BlockSpec((None, nkv, tile, hd), index)
 
     if strategy == "fused":
         kern = functools.partial(_chunk_fused_kernel, scale=scale, bs=bs,
-                                 max_blocks=max_blocks, s=s)
+                                 max_blocks=max_blocks, s=rows, group=group,
+                                 mask_block=mask_block)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((None, s, nh, hd),
-                             lambda b, tables, starts: (b, 0, 0, 0)),
+                q_spec(rows, lambda b, tables, starts: (b, 0, 0, 0)),
                 pl.BlockSpec(k_cache.shape,
                              lambda b, tables, starts: (0, 0, 0, 0)),
                 pl.BlockSpec(v_cache.shape,
                              lambda b, tables, starts: (0, 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, s, nh, hd),
-                                   lambda b, tables, starts: (b, 0, 0, 0)),
+            out_specs=q_spec(rows, lambda b, tables, starts: (b, 0, 0, 0)),
         )
     else:
         if q_blk is None:
-            q_blk = _chunk_q_tile(s, nh, hd)
-        if s % q_blk:
-            raise ValueError(f"chunk length {s} not divisible by q tile "
-                             f"{q_blk}")
+            q_blk = _chunk_q_tile(rows, nkv, hd)
+        if rows % q_blk or q_blk % group:
+            raise ValueError(f"chunk of {rows} query rows ({group} a "
+                             f"position) not divisible by q tile {q_blk}")
         kern = functools.partial(_chunk_grid_kernel, scale=scale, bs=bs,
-                                 max_blocks=max_blocks, q_blk=q_blk)
+                                 max_blocks=max_blocks, q_blk=q_blk,
+                                 group=group, mask_block=mask_block)
 
         def qmap(b, qt, blk, tables, starts):
-            return (b, qt, 0, 0)
+            return (b, 0, qt, 0)
 
         def kvmap(b, qt, blk, tables, starts):
             return (0, tables[b, blk], 0, 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, s // q_blk, max_blocks),
+            grid=(B, rows // q_blk, max_blocks),
             in_specs=[
-                pl.BlockSpec((None, q_blk, nh, hd), qmap),
-                pl.BlockSpec((nh, None, bs, hd), kvmap),
-                pl.BlockSpec((nh, None, bs, hd), kvmap),
+                q_spec(q_blk, qmap),
+                pl.BlockSpec((nkv, None, bs, hd), kvmap),
+                pl.BlockSpec((nkv, None, bs, hd), kvmap),
             ],
-            out_specs=pl.BlockSpec((None, q_blk, nh, hd), qmap),
+            out_specs=q_spec(q_blk, qmap),
             scratch_shapes=[
-                pltpu.VMEM((nh, q_blk), jnp.float32),
-                pltpu.VMEM((nh, q_blk), jnp.float32),
-                pltpu.VMEM((nh, q_blk, hd), jnp.float32),
+                pltpu.VMEM((nkv, q_blk), jnp.float32),
+                pltpu.VMEM((nkv, q_blk), jnp.float32),
+                pltpu.VMEM((nkv, q_blk, hd), jnp.float32),
             ],
         )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, s, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=_claim_name,
     )(block_tables, start_lens, q, k_cache, v_cache)
+    return jnp.transpose(out.reshape(B, nkv, s, group, hd),
+                         (0, 2, 1, 3, 4)).reshape(B, s, nh, hd)
 
 
 def paged_chunk_attention_reference(q, k_cache, v_cache, block_tables,
-                                    start_lens):
-    """Pure-XLA oracle: `PagedChunkView`'s dense linearized-table gather
-    with the offset causal mask, bit-for-bit the view's math."""
+                                    start_lens, mask_block=1):
+    """Pure-XLA oracle, and what `PagedChunkView` attends through: the
+    dense linearized-table gather under the mask (`mask_block` as
+    `paged_chunk_attention` reads it); a pool head is read by the
+    `nh / nkv` query heads of its group."""
     B, s, nh, hd = q.shape
-    bs = k_cache.shape[2]
+    nkv, bs = k_cache.shape[0], k_cache.shape[2]
     nb = block_tables.shape[1]
     pos = start_lens[:, None] + jnp.arange(s, dtype=start_lens.dtype)
     k_lin = jnp.take(k_cache, block_tables, axis=1).reshape(
-        nh, B, nb * bs, hd)
+        nkv, B, nb * bs, hd)
     v_lin = jnp.take(v_cache, block_tables, axis=1).reshape(
-        nh, B, nb * bs, hd)
-    logits = jnp.einsum("bqhd,hbkd->bhqk", q.astype(jnp.float32),
-                        k_lin.astype(jnp.float32)) / math.sqrt(hd)
+        nkv, B, nb * bs, hd)
     kpos = jnp.arange(nb * bs, dtype=pos.dtype)
-    mask = kpos[None, :] <= pos[:, :, None]
-    logits = jnp.where(mask[:, None], logits, -jnp.inf)
+    mask = _visible(kpos[None, :], pos[:, :, None], mask_block)
+    qg = q.astype(jnp.float32).reshape(B, s, nkv, nh // nkv, hd)
+    logits = jnp.einsum("bqhgd,hbkd->bhgqk", qg,
+                        k_lin.astype(jnp.float32)) / math.sqrt(hd)
+    logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,hbkd->bqhd", probs,
-                      v_lin.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bhgqk,hbkd->bqhgd", probs,
+                      v_lin.astype(jnp.float32)).reshape(
+                          B, s, nh, hd).astype(q.dtype)
 
 
 def paged_verify_attention(q, k_cache, v_cache, block_tables, start_lens,

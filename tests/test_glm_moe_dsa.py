@@ -267,11 +267,12 @@ def test_idle_slots_of_a_decode_step_reach_no_expert():
 
 
 def test_inactive_tokens_get_the_shared_part_alone():
-    from paddle_tpu.incubate.distributed.models.moe import HeldExpertsLayer
+    from paddle_tpu.incubate.distributed.models.moe import (
+        HeldExpertsLayer, SigmoidTopKGate)
     from paddle_tpu.nn import Linear
     paddle.seed(1)
-    layer = HeldExpertsLayer(16, 8, num_expert=8, top_k=2, n_experts_held=4,
-                             expert_offset=2,
+    layer = HeldExpertsLayer(16, 8, SigmoidTopKGate(16, 8, 2),
+                             n_experts_held=4, expert_offset=2,
                              shared=Linear(16, 16, bias_attr=False))
     x = Tensor._wrap(jnp.asarray(
         np.random.RandomState(0).randn(6, 16), jnp.float32))

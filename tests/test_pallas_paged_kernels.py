@@ -5,8 +5,8 @@ Three layers of evidence:
 * **Oracle parity** — `paged_chunk_attention` (both the interpret-mode
   "fused" strategy and the TPU "grid" strategy, run here in interpret
   mode) and `paged_verify_attention` against
-  `paged_chunk_attention_reference` (bit-for-bit `PagedChunkView`
-  math), over the routing grid that breaks naive implementations:
+  `paged_chunk_attention_reference` (what `PagedChunkView` attends
+  through), over the routing grid that breaks naive implementations:
   chunk start != 0, seq_len landing exactly on a block boundary, GQA
   repeat > 1, and overflow rows past the table.
 * **The audit flip** — a warmed serving engine's
@@ -62,7 +62,7 @@ def _case(B, s, start, nh_q, nh_kv, bs=8, hd=16, max_blocks=None,
     q = jnp.asarray(rng.standard_normal((B, s, nh_q, hd)),
                     jnp.float32) * 0.5
     starts = jnp.full((B,), start, jnp.int32)
-    del nh_kv   # GQA repeat happens before the pool in PagedChunkView
+    del nh_kv   # callers cut the pools to their kv heads themselves
     return q, k, v, jnp.asarray(tables), starts
 
 
@@ -103,24 +103,76 @@ def test_grid_strategy_matches_dense_oracle(case):
                                atol=2e-6, rtol=2e-6)
 
 
-def test_gqa_pools_repeat_to_query_heads():
-    """GQA repeat > 1: `PagedChunkView` repeats kv heads to query
-    multiplicity BEFORE the pool write, so the kernel sees per-query-
-    head pools.  Emulate: build with nh_q pools whose kv heads repeat
-    pairwise, assert parity still holds (the kernel needs no group
-    mapping)."""
+def test_kv_head_pools_equal_pools_repeated_to_the_query_heads():
+    """Grouped-query attention: the pools hold the kv heads and the
+    kernel maps each group's query heads onto their pool head.  The
+    result is what the same kernel gives over pools REPEATED to the query
+    heads, one pool head a query head — the layout before ISSUE 32, 2 x
+    the cache here and 8 x at 32 heads over 4."""
     q, k, v, tables, starts = _case(B=2, s=4, start=9, nh_q=4, nh_kv=2)
-    # force the repeated-head structure the view produces
-    k = k.at[1].set(k[0]).at[3].set(k[2])
-    v = v.at[1].set(v[0]).at[3].set(v[2])
-    ref = pp.paged_chunk_attention_reference(q, k, v, tables, starts)
-    out = pp.paged_chunk_attention(q, k, v, tables, starts,
-                                   interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-6, rtol=2e-6)
-    # the repeated kv heads produce DIFFERENT outputs per query head
-    # (queries differ), i.e. the case is not degenerate
+    kv_k, kv_v = k[:2], v[:2]
+    rep_k, rep_v = jnp.repeat(kv_k, 2, axis=0), jnp.repeat(kv_v, 2, axis=0)
+    ref = pp.paged_chunk_attention_reference(q, rep_k, rep_v, tables, starts)
+    for strategy in ("fused", "grid"):
+        out = pp.paged_chunk_attention(q, kv_k, kv_v, tables, starts,
+                                       interpret=True, strategy=strategy)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-6, rtol=2e-6)
+    # the two query heads of a group produce DIFFERENT outputs (queries
+    # differ), i.e. the case is not degenerate
     assert not np.allclose(np.asarray(out)[:, :, 0], np.asarray(out)[:, :, 1])
+
+
+def _dense_block_masked(q, k, v, tables, starts, mask_block):
+    """Plain numpy: each sequence's table linearized, K and V repeated to
+    the query heads, key `s` visible to the query at `t` iff
+    `s < (t // L + 1) * L`."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    tables, starts = np.asarray(tables), np.asarray(starts)
+    B, s, nh, hd = q.shape
+    nkv, _, bs, _ = k.shape
+    out = np.zeros_like(q)
+    for b in range(B):
+        kl = np.repeat(k[:, tables[b]].reshape(nkv, -1, hd), nh // nkv, 0)
+        vl = np.repeat(v[:, tables[b]].reshape(nkv, -1, hd), nh // nkv, 0)
+        t = starts[b] + np.arange(s)
+        seen = np.arange(kl.shape[1])[None, :] \
+            < ((t // mask_block + 1) * mask_block)[:, None]
+        sc = np.einsum("qhd,hkd->hqk", q[b], kl) / np.sqrt(hd)
+        sc = np.where(seen[None], sc, -np.inf)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[b] = np.einsum("hqk,hkd->qhd", p, vl)
+    return out
+
+
+@pytest.mark.parametrize("strategy", ["fused", "grid"])
+@pytest.mark.parametrize("mask_block", [1, 4])
+@pytest.mark.parametrize("group", [1, 2, 8])
+def test_kv_head_chunk_kernel_under_the_block_mask(group, mask_block,
+                                                   strategy):
+    """`nh / nkv` query heads a pool head, folded into the query rows, and
+    a mask that is causal over blocks of `mask_block` and full inside one:
+    both strategies against a dense float64 reference that repeats K and
+    V.  Starts are multiples of the mask's block, as the engine's are."""
+    nkv = 2
+    q, k, v, tables, starts = _case(B=3, s=8, start=12, nh_q=nkv * group,
+                                    nh_kv=nkv, seed=group)
+    k, v = k[:nkv], v[:nkv]
+    starts = jnp.asarray([12, 4, 16], jnp.int32)
+    want = _dense_block_masked(q, k, v, tables, starts, mask_block)
+    out = pp.paged_chunk_attention(
+        q, k, v, tables, starts, interpret=True, strategy=strategy,
+        mask_block=mask_block,
+        q_blk=4 * group if strategy == "grid" else None)
+    np.testing.assert_allclose(np.asarray(out), want, atol=3e-6, rtol=3e-6)
+    ref = pp.paged_chunk_attention_reference(q, k, v, tables, starts,
+                                             mask_block)
+    np.testing.assert_allclose(np.asarray(ref), want, atol=3e-6, rtol=3e-6)
+    if mask_block == 4:
+        # the block's later positions are seen: not the causal answer
+        causal = _dense_block_masked(q, k, v, tables, starts, 1)
+        assert np.abs(causal - want).max() > 1e-3
 
 
 def test_verify_kernel_matches_chunk_semantics():
@@ -196,7 +248,7 @@ WRITE_CASES = {
         "chunk", [6, 13], [[1, 2], [3, 4]], 4),
     "verify_k4_with_inactive_stream": (
         "chunk", [7, 0, 21], [[1, 2], [], [3, 4, 5]], 4),
-    "chunk_gqa_repeat": ("gqa", [3, 10], [[1, 2], [3, 4, 5]], 6),
+    "chunk_gqa_kv_heads": ("gqa", [3, 10], [[1, 2], [3, 4, 5]], 6),
     "prefill_ragged_tail": ("prefill", [0, 0], [[1, 2, 3], [4, 5, 6]], 19),
     "prefill_whole_blocks": ("prefill", [0], [[7, 3]], 16),
     "cow_block": ("cow", [], [], 0),
@@ -248,27 +300,25 @@ def test_pool_write_matches_the_scatter_it_replaced(case, dtype):
         want = (_scatter_prefill(k_pool, tables, k),
                 _scatter_prefill(v_pool, tables, v))
     else:
+        # a grouped-query model's pools hold its kv heads, and the
+        # chunk is written as it comes: nothing repeats K or V
         nkv = nh // 2 if kind == "gqa" else nh
+        k_pool, v_pool = k_pool[:nkv], v_pool[:nkv]
         k, v = (jnp.asarray(rng.standard_normal((B, s, nkv, hd)),
                             jnp.float32) for _ in range(2))
         q = jnp.zeros((B, s, nh, hd), jnp.float32)
 
         @jax.jit
         def write(kp, vp, tables, lens, q, k, v):       # lens traced
-            new, pos = PagedChunkView.from_parts(
+            new = PagedChunkView.from_parts(
                 kp, vp, tables, lens, bs)._write_chunk(q, k, v)
-            return (new.k, new.v), pos, new.seq_lens
+            return (new.k, new.v), new.seq_lens
 
-        got, pos, new_lens = write(k_pool, v_pool, tables, lens, q, k, v)
-        np.testing.assert_array_equal(
-            np.asarray(pos), np.asarray(lens)[:, None] + np.arange(s))
+        got, new_lens = write(k_pool, v_pool, tables, lens, q, k, v)
         np.testing.assert_array_equal(np.asarray(new_lens),
                                       np.asarray(lens) + s)
-        rep = nh // nkv
-        want = (_scatter_chunk(k_pool, tables, lens,
-                               jnp.repeat(k, rep, axis=2)),
-                _scatter_chunk(v_pool, tables, lens,
-                               jnp.repeat(v, rep, axis=2)))
+        want = (_scatter_chunk(k_pool, tables, lens, k),
+                _scatter_chunk(v_pool, tables, lens, v))
     for g, w, old in zip(got, want, (k_pool, v_pool)):
         g, w, old = (np.asarray(a, np.float32) for a in (g, w, old))
         assert g.dtype == w.dtype and g.shape == w.shape

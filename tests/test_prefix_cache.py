@@ -211,12 +211,13 @@ def test_chunk_view_attention_matches_from_scratch_oracle():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_chunk_view_gqa_head_repeat_matches_dense_oracle():
-    """ISSUE 12 satellite: the PR 11 GQA path — `PagedChunkView` hands
-    over UN-repeated kv heads (kv_heads < query heads) and the view
-    repeats them to the pool's per-query-head layout.  Until now this
-    rode only through Llama composition tests; pin it directly against
-    the dense oracle (repeat kv, causal attention at the offset)."""
+def test_chunk_view_gqa_kv_head_pools_match_dense_oracle():
+    """The grouped-query path of `PagedChunkView`: it is handed the
+    UN-repeated kv heads (kv_heads < query heads) and, since ISSUE 32,
+    writes them as they are into pools of the kv heads — each group's
+    query heads read their pool head, nothing repeats K or V.  Pinned
+    directly against the dense oracle (repeat kv, causal attention at the
+    offset)."""
     import jax.numpy as jnp
     from paddle_tpu.models.kv_cache import PagedChunkView, _dense_causal
     rng = np.random.RandomState(1)
@@ -226,8 +227,8 @@ def test_chunk_view_gqa_head_repeat_matches_dense_oracle():
     q = rng.randn(1, L, nh, hd).astype(np.float32)
     k = rng.randn(1, L, kvh, hd).astype(np.float32)
     v = rng.randn(1, L, kvh, hd).astype(np.float32)
-    pools = (jnp.zeros((nh, nb + 1, bs, hd), jnp.float32),
-             jnp.zeros((nh, nb + 1, bs, hd), jnp.float32))
+    pools = (jnp.zeros((kvh, nb + 1, bs, hd), jnp.float32),
+             jnp.zeros((kvh, nb + 1, bs, hd), jnp.float32))
     tables = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
     view = PagedChunkView.from_parts(pools[0], pools[1], tables,
                                      jnp.zeros((1,), jnp.int32), bs)
@@ -246,8 +247,9 @@ def test_chunk_view_gqa_head_repeat_matches_dense_oracle():
                          jnp.asarray(v_rep))[:, L1:]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    # the kv-head count must divide the query heads — anything else is
-    # a loud error, not a silent wrong repeat
+    assert view.k.shape[0] == kvh
+    # the chunk's kv heads must be the pool's — anything else is a loud
+    # error, not a silent wrong write
     bad = PagedChunkView.from_parts(pools[0], pools[1], tables,
                                     jnp.zeros((1,), jnp.int32), bs)
     with np.testing.assert_raises(ValueError):

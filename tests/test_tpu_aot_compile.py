@@ -180,6 +180,29 @@ def _cases():
         (((16, 1, 64, 576), BF16), ((16, 1, 32, 128), BF16),
          ((16, 1, 32), BF16), ((6145, 64, 640), BF16),
          ((6145, 64, 128), BF16), ((16, nb), i32), ((16, 1), i32)))
+    # SDAR-30B-A3B's block tick and chunk at its published head shapes:
+    # 32 query heads over kv-head pools of 4 x 128 (nothing repeats K or
+    # V), the mask full inside blocks of 4; a tick's block of 4 positions
+    # for 32 slots over a table 64 wide, and a 512-token chunk
+    pool = ((4, 2561, 64, 128), BF16)
+    for B, s in ((32, 4), (1, 512)):
+        cases[f"paged_chunk_attention gqa8 L4 B{B} s{s} bf16"] = (
+            functools.partial(pp.paged_chunk_attention, interpret=False,
+                              mask_block=4),
+            (((B, s, 32, 128), BF16), pool, pool,
+             ((B, 64), i32), ((B,), i32)))
+    cases["paged_write_chunk kv4 L4 B32 s4 bf16"] = (
+        functools.partial(pp.paged_write_chunk, align=4),
+        (pool, pool, ((32, 64), i32), ((32,), i32),
+         ((32, 4, 4, 128), BF16), ((32, 4, 4, 128), BF16)))
+    # ... and its expert layer: all 128 experts held, a tick's rows (32
+    # slots x 4 positions x 8 choices) and a 512-token chunk's
+    for rows in (1024, 4096):
+        for k, n in ((2048, 768), (768, 2048)):
+            cases[f"moe_grouped_matmul e128 m{rows} k{k} n{n} bf16"] = (
+                functools.partial(pallas_moe.grouped_matmul,
+                                  group_offset=0, interpret=False),
+                (((rows, k), BF16), ((128, k, n), BF16), ((129,), i32)))
     B, S, nh, hd = 2, 1024, 12, 64
     kv = ((B, S, nh // 4, hd), BF16)            # GQA: 3 kv heads for 12
     cases["flash fwd+bwd kv_mask dropout gqa bf16"] = (
