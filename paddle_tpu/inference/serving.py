@@ -27,14 +27,26 @@ attention_kernel.cu`) driven by a request scheduler behind
   the host round trip over the same k steps greedy ones do.  The
   host-side per-row sampler survives behind
   ``FLAGS_serving_device_sampling=0`` (it demotes ticks to k=1).
-* The tick loop double-buffers (``FLAGS_serving_overlap``): tick t+1's
-  compiled step is dispatched — feeding tick t's on-device last-token
-  handle straight back in — BEFORE tick t is harvested, so device
-  compute overlaps host detokenize/bookkeeping.  JAX async dispatch
-  makes this a reordering plus one in-flight handle, not a thread; an
-  EOS discovered at harvest simply wastes the already-dispatched step
-  (the block-budget clamp keeps the overrun inside the admission
-  reservation).
+* The serve loop keeps one tick in flight.  ``run()`` (until the engine
+  is empty) and ``serve_forever()`` (until its stop event; the loop
+  behind every front end, replica and benchmark cell) drive ONE cycle,
+  `_cycle`: tick t+1's compiled step is dispatched — feeding tick t's
+  on-device last-token handle straight back in — BEFORE tick t is
+  harvested, so device compute overlaps host detokenize/bookkeeping.
+  JAX async dispatch makes this a reordering plus one in-flight handle,
+  not a thread; an EOS discovered at harvest simply wastes the
+  already-dispatched step (the block-budget clamp keeps the overrun
+  inside the admission reservation).  A chained dispatch skips the
+  boundary schedule, the only place admissions, cancellations, sheds
+  and evictions happen, so `_can_overlap` refuses the chain — the next
+  harvest is then followed by a REAL boundary — whenever the engine's
+  own state says a boundary has work: a request waits, a running one
+  was cancelled or finished or is on its last budgeted tick, the tick
+  kind would switch, a drain was requested or the loop's stop event is
+  set.  Nothing waits more than the one tick in flight at its arrival.
+  ``FLAGS_serving_overlap=0`` is the synchronous cycle (dispatch,
+  harvest, in a row) the bit-equality tests compare against; `step()`
+  is always synchronous.
 
 Block accounting reserves the worst case (prompt + max_new_tokens) at
 admission, so a running sequence can never hit pool exhaustion
@@ -976,6 +988,9 @@ class ServingEngine:
         self._draining = False
         self._drain_requested = False
         self._drain_info: Optional[dict] = None
+        # the event `serve_forever` runs until, while it runs: a set
+        # event ends the chain of ticks (`_can_overlap`)
+        self._stop_event = None
         # --- router evidence (ISSUE 16): always-on (independent of the
         # metrics gate) recent admission timestamps + TTFTs.  /healthz
         # ships rate + median so the fleet router's queue-position
@@ -2821,7 +2836,8 @@ class ServingEngine:
         with _span("serve:tick_dispatch", active=len(active),
                    kv_tokens=int(lens.sum()),
                    kv_blocks=int((-(-lens // self.bs)).sum()),
-                   selected_tokens=self._selected(lens)) as sp:
+                   selected_tokens=self._selected(lens),
+                   chained=int(chain is not None)) as sp:
             pend = self._launch_tick(active, t0, chain)
             sp.set(steps=pend.k)
             if pend.block is not None:
@@ -3424,11 +3440,23 @@ class ServingEngine:
         chained dispatch continues `pend`'s KIND: a spec tick chains a
         spec tick (on the device seq_lens/last handles, needing spec_k
         budget beyond the in-flight upper bound), a plain tick a plain
-        one — a kind switch is a real boundary (harvest first)."""
+        one — a kind switch is a real boundary (harvest first).
+
+        A chained dispatch skips `_boundary_schedule`, and behind a
+        front end long answers can chain for seconds, so everything
+        only a boundary acts on says no here: a waiting request, a
+        cancelled one in any slot, a requested drain, the serve loop's
+        stop event.  The tick in flight is then harvested and the next
+        dispatch is a real boundary — within one tick, whatever runs."""
         if not _flags.get_flag("serving_overlap") or self.gen is not None:
             return False     # (a block tick is harvested before the next)
+        if self._drain_requested or (self._stop_event is not None
+                                     and self._stop_event.is_set()):
+            return False     # the loop is ending: harvest what flies
         if self.waiting:
             return False     # admissions join at a real boundary
+        if any(r is not None and r.cancelled for r in self.slot_req):
+            return False     # evictions and aborts happen at a boundary
         if self.prefilling and not self._chunk_overlap_ok():
             return False     # pending chunk work needs a real boundary
         if pend.spec:
@@ -3527,12 +3555,59 @@ class ServingEngine:
         self._chunks_this_boundary = 0
         self._chunk_s_this_boundary = 0.0
 
+    def _cycle(self, pend):
+        """One turn of the serve loop, the one both drivers run: takes
+        the tick in flight (None at a boundary) and returns the one in
+        flight afterwards.  With nothing in flight it dispatches a
+        boundary tick (schedule first).  Then, where `_can_overlap`
+        allows, tick t+1 is chained on `pend`'s device tokens — with
+        the non-final prefill chunks that may ride behind it — BEFORE
+        `pend` is harvested; otherwise `pend` is harvested alone and
+        the next turn starts at a real boundary.  Crash-only: a failure
+        is absorbed by `_absorb_failure` (request strike, or eviction
+        of the slots the ticks in flight covered) and the turn ends
+        with nothing in flight; only sanitizer findings propagate."""
+        if pend is None:
+            try:
+                pend = self._dispatch_tick(boundary=True)
+            except Exception as e:  # noqa: BLE001 - crash-only guard
+                if not self._absorb_failure(e, ()):
+                    raise
+                return None
+            if pend is None:
+                return None      # nothing decodable yet (chunks, queue)
+        nxt = None
+        try:
+            if self._can_overlap(pend):
+                nxt = self._dispatch_tick(boundary=False, chain=pend)
+                if nxt is not None:
+                    nxt.overlapped = True
+                    _M_OVERLAP.inc()
+                    try:
+                        self._overlap_chunk_work(nxt)
+                    except Exception as e:  # noqa: BLE001
+                        # a chunk's own failure strikes ITS request; the
+                        # two ticks in flight are sound and are harvested
+                        if getattr(e, "_serving_req", None) is None \
+                                or not self._absorb_failure(e, ()):
+                            raise
+            self._harvest_tick(pend)
+        except Exception as e:  # noqa: BLE001 - crash-only guard
+            if not self._absorb_failure(e, (pend, nxt)):
+                raise
+            return None
+        return nxt
+
+    def _has_work(self) -> bool:
+        return bool(self.waiting or self.prefilling
+                    or self._active_slots())
+
     def run(self) -> List[Request]:
-        """Drive until every queued request finishes; returns them in
-        completion order.  With ``FLAGS_serving_overlap`` the loop keeps
-        one tick in flight: dispatch t+1 (chaining t's device last-token
-        column), THEN harvest t — device compute and host harvest/
-        detokenize overlap instead of strictly alternating."""
+        """Drive `_cycle` until every queued request finishes; returns
+        them in completion order.  The loop keeps one tick in flight
+        (``FLAGS_serving_overlap``): dispatch t+1 (chaining t's device
+        last-token column), THEN harvest t — device compute and host
+        harvest/detokenize overlap instead of strictly alternating."""
         from ..observability import http as _http
         _http.start_from_flags()   # no-op unless FLAGS_metrics_port > 0
         _http.attach_engine(self)
@@ -3542,34 +3617,8 @@ class ServingEngine:
             self.warmup()          # compile the whole grid BEFORE
         self._mark_ready()         # traffic waits on a program build
         pend = None
-        while True:
-            if pend is None:
-                if not (self.waiting or self.prefilling
-                        or self._active_slots()):
-                    break
-                try:
-                    pend = self._dispatch_tick(boundary=True)
-                except Exception as e:  # noqa: BLE001 - crash-only guard
-                    if not self._absorb_failure(e, ()):
-                        raise
-                    continue
-                if pend is None:
-                    continue     # waiting on evictions, as before
-            nxt = None
-            try:
-                if self._can_overlap(pend):
-                    nxt = self._dispatch_tick(boundary=False, chain=pend)
-                    if nxt is not None:
-                        nxt.overlapped = True
-                        _M_OVERLAP.inc()
-                        self._overlap_chunk_work(nxt)
-                self._harvest_tick(pend)
-            except Exception as e:  # noqa: BLE001 - crash-only guard
-                if not self._absorb_failure(e, (pend, nxt)):
-                    raise
-                pend = None
-                continue
-            pend = nxt
+        while pend is not None or self._has_work():
+            pend = self._cycle(pend)
         # final eviction sweep
         for slot in list(range(self.B)):
             if self.slot_req[slot] is not None and self.slot_req[slot].done:
@@ -3580,16 +3629,27 @@ class ServingEngine:
         return self.finished
 
     def serve_forever(self, stop_event, idle_s: float = 0.002) -> None:
-        """Drive the engine until ``stop_event`` (a threading.Event) is
+        """Drive `_cycle` until ``stop_event`` (a threading.Event) is
         set, serving traffic submitted concurrently — the loop behind
-        the streaming endpoint (``FLAGS_serving_http_port``): handler
-        threads `add_request` and read each request's token stream;
-        this loop ticks while work exists and naps otherwise.  Runs the
-        SYNCHRONOUS step cycle: a latency-facing frontend wants
-        admissions (and cancellations) at every boundary, not deferred
-        behind an overlapped tick.
+        the streaming endpoint (``FLAGS_serving_http_port``), a fleet
+        replica and every serve cell of the benchmark: handler threads
+        `add_request` and read each request's token stream; this loop
+        ticks while work exists and naps (``idle_s``, under the span
+        ``serve:idle``) otherwise.
 
-        Crash-only (ISSUE 15): every step runs under the tick guard —
+        The same cycle as `run()`: while nothing needs a boundary, tick
+        t+1 is enqueued on tick t's device tokens before t is harvested,
+        so the device does not wait for the host between ticks.  An
+        arrival, a cancellation, a finished or nearly spent request, a
+        drain request or the stop event ends the chain (`_can_overlap`):
+        the tick in flight is harvested and the next dispatch is a real
+        boundary, so a latency-facing front end sees its admissions and
+        cancellations at most one tick later than a synchronous loop
+        would show them.  The tick in flight is always harvested before
+        `drain()` runs and before this returns: no token of a request
+        the engine took is dropped.
+
+        Crash-only (ISSUE 15): every cycle runs under the tick guard —
         one request's failure never kills the loop — and SIGTERM (main
         thread only) or ``POST /drain`` flips `request_drain()`, which
         this loop turns into a graceful `drain()` and a clean return."""
@@ -3605,23 +3665,30 @@ class ServingEngine:
                 lambda signum, frame: self.request_drain())
         except ValueError:
             pass    # not the main thread: POST /drain still works
+        self._stop_event = stop_event
         try:
             if self._warmup_info is None \
                     and _flags.get_flag("serving_warmup"):
                 self.warmup()
             self._mark_ready()
+            pend = None
             while not stop_event.is_set():
-                if self._drain_requested and not self._draining:
+                if pend is None and self._drain_requested \
+                        and not self._draining:
                     self.drain()
                     return
-                if self.waiting or self.prefilling \
-                        or self._active_slots():
-                    self._guarded_step()
+                if pend is not None or self._has_work():
+                    pend = self._cycle(pend)
                 else:
                     # an empty engine is not a slow one
                     with _span("serve:idle"):
                         time.sleep(idle_s)
+            if pend is not None:
+                # stopped with a tick in flight: the set event forbids a
+                # chain, so this turn harvests it and leaves none
+                self._cycle(pend)
         finally:
+            self._stop_event = None
             if old_handler is not None:
                 try:
                     _signal.signal(_signal.SIGTERM, old_handler)

@@ -527,6 +527,345 @@ def test_sse_terminal_error_frame_format(model):
         srv.close()
 
 
+# ------------------------ serve_forever keeps a tick in flight (ISSUE 33)
+
+_FOREVER: dict = {}
+
+
+def _forever_engine(model, chunk):
+    """One tiny engine a chunk size for the cases below (blocksan armed:
+    every harvest reconciles the block ledger), built at first use."""
+    if chunk not in _FOREVER:
+        with flag_guard(enable_jaxsan=True):
+            _FOREVER[chunk] = ServingEngine(
+                model, max_batch=4, max_context=96, block_size=16,
+                steps_per_tick=2, prefill_chunk=chunk, prefix_cache=False)
+    return _FOREVER[chunk]
+
+
+def _requests(seed, budgets, sampled=False, lengths=(7, 19, 11, 26)):
+    rng = np.random.RandomState(seed)
+    return [Request(rng.randint(1, 1000, (n,)), max_new_tokens=b,
+                    do_sample=sampled and i % 2 == 0, top_k=20,
+                    temperature=0.9, seed=100 + i)
+            for i, (n, b) in enumerate(zip(lengths, budgets))]
+
+
+def _reference(eng, seed, budgets, sampled=False):
+    """The same requests through the synchronous cycle
+    (``serving_overlap=False``): what every chained stream must equal."""
+    with flag_guard(serving_overlap=False):
+        reqs = [eng.add_request(r)
+                for r in _requests(seed, budgets, sampled)]
+        eng.run()
+    eng.finished.clear()
+    return [list(r.output_ids) for r in reqs]
+
+
+def _until(cond, what, timeout=60.0):
+    deadline = time.time() + timeout
+    while not cond():
+        assert time.time() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.001)
+
+
+def _served(eng, reqs):
+    """Wait for the streams to end AND for the boundary after the last
+    harvest, which gives the finished requests' slots and blocks back."""
+    _until(lambda: all(r.done for r in reqs)
+           and eng.stats()["active"] == 0, "the streams' end")
+
+
+def _chaining(n, running):
+    """The last `n` ticks were each enqueued behind an unharvested one,
+    with `running` requests holding a slot."""
+    return lambda log: len(log) >= n and all(
+        e["launched"] and e["chained"] and len(e["rids"]) == running
+        for e in log[-n:])
+
+
+class _Loop:
+    """`eng.serve_forever` on a thread, with a log of its dispatches —
+    one entry a `_dispatch_tick` call: did it launch a tick, was the tick
+    chained, which requests held a slot after it — and a way to HOLD the
+    loop right after a dispatch, so that another thread (the test's: the
+    generator of the traffic) acts at a known point: with one tick
+    enqueued behind another, both unharvested."""
+
+    def __init__(self, eng):
+        self.eng, self.log = eng, []
+        self.stop = threading.Event()
+        self.error = None
+        self.fail = None            # "dispatch" | "harvest": raise once
+        self._when = None
+        self._held, self._go = threading.Event(), threading.Event()
+        self._last = None
+
+    def hold_when(self, pred):
+        self._held.clear()
+        self._go.clear()
+        self._when = pred
+
+    def held(self, timeout=60.0):
+        assert self._held.wait(timeout), "the loop never got there"
+        return len(self.log)
+
+    def release(self):
+        self._go.set()
+
+    def ticks_until(self, n, pred):
+        """The ticks launched from log entry `n` to the first entry at
+        which `pred(entry)` holds (a boundary acted), that one left out."""
+        def first():
+            return next((i for i in range(n, len(self.log))
+                         if pred(self.log[i])), None)
+
+        _until(lambda: first() is not None, "the boundary")
+        return [e for e in self.log[n:first()] if e["launched"]]
+
+    def __enter__(self):
+        eng = self.eng
+        dispatch_tick, harvest_tick = eng._dispatch_tick, eng._harvest_tick
+
+        def dispatch(boundary=True, chain=None):
+            if chain is not None and self.fail == "dispatch":
+                self.fail = None
+                raise RuntimeError("injected: the chained dispatch raised")
+            pend = dispatch_tick(boundary=boundary, chain=chain)
+            self._last = pend if pend is not None else self._last
+            self.log.append({
+                "launched": pend is not None, "chained": chain is not None,
+                "draining": eng._draining,
+                "rids": {r.rid for r in eng.slot_req if r is not None}})
+            if self._when is not None and self._when(self.log):
+                self._when = None
+                self._held.set()
+                self._go.wait(60)
+                self._go.clear()
+            return pend
+
+        def harvest(pend):
+            if self.fail == "harvest" and self._last is not pend:
+                self.fail = None     # a tick is chained behind this one
+                raise RuntimeError("injected: the harvest raised")
+            return harvest_tick(pend)
+
+        eng._dispatch_tick, eng._harvest_tick = dispatch, harvest
+
+        def target():
+            try:
+                eng.serve_forever(self.stop)
+            except BaseException as e:  # noqa: BLE001 - re-raised at exit
+                self.error = e
+
+        self.thread = threading.Thread(target=target, daemon=True)
+        self.thread.start()
+        return self
+
+    def __exit__(self, *exc):
+        self.stop.set()
+        self._go.set()
+        self.thread.join(60)
+        del self.eng._dispatch_tick, self.eng._harvest_tick
+        assert not self.thread.is_alive()
+        if self.error is not None and exc[0] is None:
+            raise self.error
+        return False
+
+
+def _settled(eng):
+    """Every block and reservation came back, under an armed blocksan."""
+    st = eng.stats()
+    return (st["free_blocks"] == eng.num_blocks and st["reserved"] == 0
+            and st["active"] == 0 and eng._blocksan is not None
+            and eng._blocksan.verifies > 0)
+
+
+def _case_parity(model, chunk, sampled):
+    """(1) Streams under the chained `serve_forever`, arrivals from a
+    generator thread, equal the synchronous cycle's token for token; the
+    chain engaged, and the counter and the spans' totals say so."""
+    eng = _forever_engine(model, chunk)
+    budgets = (24, 17, 30, 9)
+    want = _reference(eng, 11 + chunk, budgets, sampled)
+    before = eng.stats()["ticks"]
+    obs_metrics.reset()
+    with _Loop(eng) as lp:
+        reqs = _requests(11 + chunk, budgets, sampled)
+        for r in reqs:
+            eng.add_request(r)
+            time.sleep(0.004)
+        _served(eng, reqs)
+    assert [list(r.output_ids) for r in reqs] == want
+    assert all(r.outcome == "finished" for r in reqs)
+    chained = sum(e["launched"] and e["chained"] for e in lp.log)
+    launched = sum(e["launched"] for e in lp.log)
+    assert launched == eng.stats()["ticks"] - before
+    assert 0 < chained < launched        # boundaries still came
+    snap = obs_metrics.snapshot()["serving.overlap_dispatches"]
+    assert sum(x["value"] for x in snap["series"]) == chained
+    assert _settled(eng)
+
+
+def _case_no_starvation(model, chunk):
+    """(2) Three long answers chain; one is cancelled from another thread
+    and is gone at the first boundary after the ticks in flight; a
+    request added meanwhile gets its slot within one tick of the tick in
+    flight at its arrival.  Neither waits for the answers to end."""
+    eng = _forever_engine(model, chunk)
+    budgets = (60, 60, 60, 12)
+    want = _reference(eng, 23 + chunk, budgets)
+    with _Loop(eng) as lp:
+        *reqs, late = _requests(23 + chunk, budgets)
+        lp.hold_when(_chaining(3, running=3))
+        for r in reqs:
+            eng.add_request(r)
+        n = lp.held()                    # two ticks in flight, none waited
+        reqs[1].cancel()
+        lp.hold_when(_chaining(2, running=2))
+        lp.release()
+        gone = lp.ticks_until(n, lambda e: reqs[1].rid not in e["rids"])
+        assert len(gone) <= 1 and all(e["chained"] for e in gone)
+        assert reqs[1].outcome == "cancelled"
+        m = lp.held()                    # the other two chain on
+        eng.add_request(late)
+        lp.release()
+        came = lp.ticks_until(m, lambda e: late.rid in e["rids"])
+        assert len(came) <= 1 and all(e["chained"] for e in came)
+        _served(eng, (reqs[0], reqs[2], late))
+    got = [list(r.output_ids) for r in reqs + [late]]
+    assert got[0] == want[0] and got[2] == want[2] and got[3] == want[3]
+    assert 0 < len(got[1]) < 60 and got[1] == want[1][:len(got[1])]
+    assert _settled(eng)
+
+
+def _case_in_flight(model, what):
+    """(3) The loop is stopped, asked to drain, or a tick raises, each
+    with one tick enqueued behind another: every token of a dispatched
+    tick reaches its request (or the tick's slots are evicted
+    ``outcome=error`` with what they had), the ledger reconciles, and
+    the loop returns."""
+    budgets = (40, 40, 40, 8)
+    if what == "drain":                  # a drained engine stays closed
+        with flag_guard(enable_jaxsan=True):
+            eng = ServingEngine(model, max_batch=3, max_context=64,
+                                block_size=16, steps_per_tick=2,
+                                prefix_cache=False)
+    else:
+        eng = _forever_engine(model, 0)
+    want = _reference(eng, 37, budgets)
+    errors = eng.tick_errors
+    with _Loop(eng) as lp:
+        *reqs, late = _requests(37, budgets)
+        lp.hold_when(_chaining(3, running=3))
+        for r in reqs:
+            eng.add_request(r)
+        n = lp.held()
+        slots = [r.slot for r in reqs]
+        if what == "stop":
+            lp.stop.set()
+        elif what == "drain":
+            eng.request_drain()
+        else:
+            lp.fail = what               # "dispatch" or "harvest"
+        lp.release()
+        if what in ("stop", "drain"):
+            lp.thread.join(60)
+        else:
+            _until(lambda: all(r.outcome for r in reqs),
+                   "the error evictions")
+        if what == "stop":
+            # returned with nothing in flight: what was dispatched was
+            # harvested, token for token, and the engine goes on from it
+            assert not lp.thread.is_alive() and lp.error is None
+            assert len(lp.log) == n      # and no further tick was chained
+            for r, slot, ref in zip(reqs, slots, want):
+                assert 1 < len(r.output_ids) < 40 and not r.done
+                assert len(r.output_ids) == int(eng.tok_pos[slot])
+                assert r.output_ids == ref[:len(r.output_ids)]
+            eng.run()
+            assert [list(r.output_ids) for r in reqs] == want[:3]
+        elif what == "drain":
+            # the tick in flight was harvested BEFORE drain()'s own
+            # steps; the running answers finished inside the deadline
+            assert not lp.thread.is_alive() and lp.error is None
+            assert not lp.ticks_until(n, lambda e: e["draining"])
+            assert eng._drain_info is not None
+            assert eng._drain_info["evicted_running"] == 0
+            assert [list(r.output_ids) for r in reqs] == want[:3]
+            with pytest.raises(ValueError, match="draining"):
+                eng.add_request(late)
+        else:
+            # the ticks in flight are abandoned and exactly their slots
+            # evicted with what they had; the loop lives and serves on
+            assert eng.tick_errors == errors + 1
+            for r, ref in zip(reqs, want):
+                assert r.outcome == "error"
+                assert 1 < len(r.output_ids) < 40
+                assert r.output_ids == ref[:len(r.output_ids)]
+            eng.add_request(late)
+            _served(eng, [late])
+            assert late.outcome == "finished" \
+                and list(late.output_ids) == want[3]
+    eng.finished.clear()
+    assert _settled(eng)
+
+
+def _case_chunk_behind_chain_raises(model):
+    """A prefill chunk riding behind a chained tick raises: its own
+    request is struck and tried again, and the two ticks in flight are
+    still harvested — the running stream loses no token."""
+    from paddle_tpu.testing import chaos
+    eng = _forever_engine(model, 8)
+    rng = np.random.RandomState(5)
+    prompts = (rng.randint(1, 1000, (6,)), rng.randint(1, 1000, (40,)))
+    with flag_guard(serving_overlap=False):
+        want = [eng.add_request(Request(p, max_new_tokens=b))
+                for p, b in zip(prompts, (24, 4))]
+        eng.run()
+    eng.finished.clear()
+    errors, before = eng.tick_errors, eng.overlap_chunks_total
+    reqs = [eng.add_request(Request(p, max_new_tokens=b))
+            for p, b in zip(prompts, (24, 4))]
+    # chunk dispatches: the short prompt's one, the long one's first (at
+    # its admission's boundary), then its second — behind a chained tick
+    with chaos.fail_at("serving.prefill.dispatch", on_calls=[3],
+                       exc=RuntimeError) as f:
+        eng.run()
+    assert f.fires == 1 and eng.tick_errors == errors + 1
+    assert eng.overlap_chunks_total > before      # the retry's rode too
+    assert [list(r.output_ids) for r in reqs] \
+        == [list(r.output_ids) for r in want]
+    assert reqs[1]._strikes == 1 and reqs[1].outcome == "finished"
+    eng.finished.clear()
+    assert _settled(eng)
+
+
+_TICK_IN_FLIGHT = {
+    "parity-greedy": (_case_parity, 0, False),
+    "parity-sampled": (_case_parity, 0, True),
+    "parity-greedy-chunked": (_case_parity, 8, False),
+    "parity-sampled-chunked": (_case_parity, 8, True),
+    "no-starvation": (_case_no_starvation, 0),
+    "no-starvation-chunked": (_case_no_starvation, 8),
+    "stop-with-a-tick-in-flight": (_case_in_flight, "stop"),
+    "drain-with-a-tick-in-flight": (_case_in_flight, "drain"),
+    "chained-dispatch-raises": (_case_in_flight, "dispatch"),
+    "harvest-raises-with-a-tick-in-flight": (_case_in_flight, "harvest"),
+    "chunk-behind-the-chain-raises": (_case_chunk_behind_chain_raises,),
+}
+
+
+@pytest.mark.parametrize("case", list(_TICK_IN_FLIGHT))
+def test_serve_forever_keeps_a_tick_in_flight(model, case):
+    """ISSUE 33: `serve_forever` drives the cycle `run()` drives — tick
+    t+1 is enqueued on tick t's device tokens before t is harvested —
+    and everything only a boundary acts on (an arrival, a cancellation,
+    a drain request, the stop event) ends the chain within one tick."""
+    fn, *args = _TICK_IN_FLIGHT[case]
+    fn(model, *args)
+
+
 # ----------------------------------------------- heavy composition pins
 
 @pytest.mark.slow   # compiles a TP program grid — full runs cover it

@@ -4,7 +4,9 @@ under `jax.profiler.trace` on the CPU and read back with `ProfileData` —
 every span of the table is on `/host:CPU` under its exact name with its
 attrs, the serve phases of a tick do not overlap, the dispatch spans of
 one request share its `rid`, and the flight record's phases and the
-compile tracker's seconds are the same spans' durations."""
+compile tracker's seconds are the same spans' durations.  Since ISSUE 33
+the loop keeps a tick in flight: a `serve:tick_dispatch` span says
+whether it was `chained` behind an unharvested tick."""
 
 import glob
 import threading
@@ -20,6 +22,7 @@ from paddle_tpu.inference.serving import Request, ServingEngine
 from paddle_tpu.jit import to_static
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt3_tiny
 from paddle_tpu.observability import compile_tracker, flight_recorder
+from paddle_tpu.observability import metrics as obs_metrics
 
 TRACE_ID = "0123456789abcdef"
 PHASES = ("serve:schedule", "serve:tick_dispatch", "serve:harvest_wait",
@@ -43,6 +46,9 @@ def run(tmp_path_factory):
                     trace_id=TRACE_ID),
             Request(rng.randint(1, 1000, (10,)), max_new_tokens=4),
             Request(rng.randint(1, 1000, (20,)), max_new_tokens=3)]
+    # a long answer, sent once the engine is empty: alone in it, nothing
+    # waits and nothing finishes, so its ticks chain (1 + 2 + 2 + 2 + 2)
+    lone = Request(rng.randint(1, 1000, (12,)), max_new_tokens=9)
 
     @to_static
     def tiny_step(a):
@@ -53,6 +59,7 @@ def run(tmp_path_factory):
     compile_tracker.reset()
     obs._SPAN_TOTALS.clear()
     stop = threading.Event()
+    obs_metrics.reset()
 
     def client():
         for r in reqs:
@@ -60,6 +67,9 @@ def run(tmp_path_factory):
         while not all(r.done for r in reqs):
             time.sleep(0.005)
         time.sleep(0.02)           # a few naps of the empty engine
+        eng.add_request(lone)
+        while not lone.done:
+            time.sleep(0.005)
         stop.set()
 
     d = str(tmp_path_factory.mktemp("trace"))
@@ -89,8 +99,12 @@ def run(tmp_path_factory):
                     (e.start_ns, e.start_ns + e.duration_ns, e.name,
                      dict(e.stats), ln.name) for e in ln.events
                     if e.name.startswith(("serve:", "to_static:")))
+    reqs.append(lone)
+    overlap = obs_metrics.snapshot().get("serving.overlap_dispatches")
     return {"events": sorted(events, key=lambda e: e[:2]), "reqs": reqs,
             "first_call_s": first_call_s, "totals": obs.span_totals(),
+            "overlap_dispatches": sum(x["value"] for x in overlap["series"])
+            if overlap else 0,
             "ticks": [r for r in flight_recorder.default_recorder().steps()
                       if r.get("timeline") == "serving"]}
 
@@ -118,10 +132,36 @@ def test_serve_phases_of_a_tick_do_not_overlap(run):
     sched = _named(run, "serve:schedule")
     for e in [x for x in run["events"] if x[2] in NESTED]:
         assert any(s[0] <= e[0] and e[1] <= s[1] for s in sched), e[2]
-    # and within a tick the order is schedule, dispatch, wait, emit
-    order = [e[2] for e in loop if e[2] != "serve:idle"]
+    # and within a tick the order is schedule, dispatch, wait, emit; the
+    # dispatch of the NEXT tick, chained behind it, may lie before its wait
+    order = [e[2] for e in loop if e[2] != "serve:idle"
+             and not e[3].get("chained")]
     i = order.index("serve:tick_dispatch")
     assert order[i - 1:i + 3] == list(PHASES[:4])
+
+
+def test_tick_dispatch_says_whether_it_was_chained(run):
+    """A tick enqueued behind an unharvested one carries `chained` 1, a
+    boundary's tick 0 and a `serve:schedule` before it; the counter
+    `serving.overlap_dispatches` counts the same dispatches."""
+    loop = [e for e in run["events"] if e[2] in PHASES]
+    ticks = _named(run, "serve:tick_dispatch")
+    assert {e[3]["chained"] for e in ticks} == {0, 1}
+    chained = [e for e in ticks if e[3]["chained"]]
+    assert len(chained) == run["overlap_dispatches"] >= 3   # the lone one
+    for i, e in enumerate(loop):
+        if e[2] != "serve:tick_dispatch":
+            continue
+        before = loop[i - 1][2]
+        if e[3]["chained"]:
+            # straight after a dispatch, or after the emit of the tick
+            # before the one it chains on: never after a schedule
+            assert before in ("serve:tick_dispatch", "serve:emit")
+        else:
+            assert before == "serve:schedule"
+    # every tick is still waited for and emitted once
+    assert len(_named(run, "serve:harvest_wait")) == len(ticks) \
+        == len(_named(run, "serve:emit"))
 
 
 def test_span_attrs(run):
@@ -130,7 +170,7 @@ def test_span_attrs(run):
             ("serve:prefill_dispatch", {"rid", "prompt_tokens"}),
             ("serve:chunk_dispatch", {"rid", "q_tokens", "kv_tokens"}),
             ("serve:tick_dispatch", {"steps", "active", "kv_tokens",
-                                     "kv_blocks"}),
+                                     "kv_blocks", "chained"}),
             ("serve:emit", {"tokens"}),
             ("to_static:discover", {"fn"}),
             ("to_static:trace_lower", {"fn"}),
