@@ -317,6 +317,15 @@ _M_OUTCOMES = _metrics.counter(
     "the SLO burn-rate monitor reads error|poisoned as budget burn")
 
 
+_M_MTP_DRAFTED = _metrics.counter(
+    "serving.mtp.drafted", "drafts of the model's own multi-token-"
+    "prediction module judged by a verify forward (one a running slot a "
+    "self-drafted tick), as counted on the device")
+_M_MTP_ACCEPTED = _metrics.counter(
+    "serving.mtp.accepted", "... of which the verify forward found to be "
+    "the model's own choice (each lets the forward emit a second token)")
+
+
 _M_BLOCK_FORWARDS = _metrics.counter(
     "serving.block.forwards", "forwards of a block-diffusion tick "
     "(denoising + commit), whole-batch forwards a launch")
@@ -372,6 +381,10 @@ class Request:
         # under block-diffusion generation: for each output token the
         # denoising forward (0-based) of its block's tick that revealed it
         self.reveal_steps: List[int] = []
+        # under self-drafting: for each verify forward of this request,
+        # in order, (the draft it judged, whether the draft was the
+        # model's own choice, the tokens the forward emitted: 1 or 2)
+        self.draft_log: List[tuple] = []
         self.done = False
         self.slot: Optional[int] = None
         # scheduler knobs (ISSUE 11): higher priority admits first among
@@ -400,6 +413,8 @@ class Request:
         self._chunk_row = None        # np [nb_per_seq] shadow table row
         self._chunk_off = 0           # prompt tokens written so far
         self._chunk_t_admit = None
+        self._first_draft = None      # a self-drafter's draft, from the
+                                      # prompt's last chunk
         # token stream listener (the SSE endpoint): harvest puts each
         # emitted token id, terminal states put None
         self._stream_q = None
@@ -475,7 +490,7 @@ class _PendingTick:
                  "device_sampling", "overlapped", "step_no", "san",
                  "spec", "counts", "accepts", "new_lens", "new_last",
                  "chunks", "kcap", "sched_s", "chunk_s", "dispatch_s",
-                 "state", "block")
+                 "state", "block", "new_draft", "judged")
 
     def __init__(self, active, k, toks, logits, reqs, t0,
                  device_sampling, step_no, san=None):
@@ -494,6 +509,9 @@ class _PendingTick:
         self.accepts = None
         self.new_lens = None
         self.new_last = None
+        # a self-drafted tick: the next drafts, and the drafts it judged
+        self.new_draft = None
+        self.judged = None
         self.state = ()     # the cache's per-layer state after this tick
         # a block-diffusion tick: (given, new, step_of) — per slot, how
         # many of the block's tokens were the prompt's and how many are to
@@ -674,7 +692,16 @@ class ServingEngine:
         # sequence (`BlockDiffusion`): the tick then denoises and commits
         # one block a running sequence, and every prompt is absorbed
         # through the chunk programs up to its last whole block
-        self.gen = self.cache.generation
+        from ..models.kv_cache import BlockDiffusion, SelfDraft
+        generation = self.cache.generation
+        self.gen = generation if isinstance(generation, BlockDiffusion) \
+            else None
+        # ... or a model that drafts for itself through its multi-token-
+        # prediction module (`SelfDraft`): the tick is then one forward
+        # over (last token, draft) that emits one or two tokens a slot and
+        # drafts again, every prompt goes through the chunk programs (they
+        # also write the module's rows), and requests are served greedy
+        self.mtp = generation if isinstance(generation, SelfDraft) else None
         if self.gen is not None and block_size % self.gen.block_length:
             raise ValueError(
                 f"block_size {block_size} must be a multiple of the "
@@ -857,6 +884,12 @@ class ServingEngine:
         self.tables = np.zeros((max_batch, self.nb_per_seq), np.int32)
         self.seq_lens = np.zeros((max_batch,), np.int32)
         self.last_tok = np.zeros((max_batch,), np.int32)
+        # a self-drafting model's draft of the token after `last_tok`
+        self.draft_tok = np.zeros((max_batch,), np.int32)
+        self._mtp_fn = None
+        self._probe_fns = {}
+        self.mtp_forwards = 0      # verify forwards x running slots
+        self.mtp_accepted = 0
         # per-slot sampling params — device INPUTS of the decode tick
         # (free slots carry the identity: greedy, t=1, no filters)
         self.samp_do = np.zeros((max_batch,), bool)
@@ -941,6 +974,8 @@ class ServingEngine:
         if self.chunk < 0:
             raise ValueError(
                 f"serving_prefill_chunk must be >= 0: {self.chunk}")
+        if self.mtp is not None and self.chunk <= 0:
+            self.chunk = self.pad_ladder[-1]
         if self.gen is not None:
             # chunks begin and end on multiples of the block length (the
             # mask is full inside a block); unchunked, a chunk is as long
@@ -1149,18 +1184,43 @@ class ServingEngine:
         self._bind_params(params)
 
         def forward(ids, pools, tables, lens, pos_offset, view_cls=None,
-                    in_tick=False):
+                    in_tick=False, hidden=False):
             views = self._views(pools, tables, lens, view_cls)
             if in_tick:
                 for view in views:
                     view.in_tick = True
             if not isinstance(pos_offset, int):
                 pos_offset = Tensor._wrap(pos_offset)
+            # `hidden`: the last hidden states in the logits' place (a
+            # self-drafting model's programs apply the head themselves)
+            run = self.model.forward_hidden if hidden \
+                else self.model.forward_with_cache
             with no_grad():
-                logits_t, new_views = self.model.forward_with_cache(
+                out_t, new_views = run(
                     Tensor._wrap(ids), views, pos_offset=pos_offset)
-            return logits_t._value, [c.pools for c in new_views]
+            return out_t._value, [c.pools for c in new_views]
         return forward
+
+    def _draft(self, pools, tables, lens, h, next_ids, view_cls=None,
+               in_tick=False):
+        """The self-drafter's side of the forward seam (the parameters
+        are bound by `_forward`): the module over the positions whose
+        hidden states are `h`, `lens` the lengths BEFORE them.  Returns
+        (the module's last hidden states `[B, s, H]`, of which `_head`
+        gives the logits; new pools)."""
+        from ..framework.dygraph import no_grad
+        views = self._views(pools, tables, lens, view_cls)
+        for view in views:
+            view.in_tick = in_tick
+        with no_grad():
+            z, new_views = self.model.draft_hidden(h, next_ids, views)
+        return z._value, [c.pools for c in new_views]
+
+    def _head(self, h):
+        """Logits of hidden states that passed their last norm."""
+        from ..framework.dygraph import no_grad
+        with no_grad():
+            return self.model.head(Tensor._wrap(h))._value
 
     def _program(self, name, fn, donate, *blame):
         """Jit ``fn`` as the serving program ``name``.  The jitted
@@ -1334,6 +1394,74 @@ class ServingEngine:
             {"program": "block_tick", "block_length": L,
              "denoising_steps": n_steps}))
 
+    def _mtp_tick_program(self):
+        """The tick of a self-drafting model (`self.mtp`): each running
+        slot's stream ends in ``last_tok`` (not yet cached) with the
+        module's ``draft`` of the token after it.  ONE forward of the
+        model over ``[last_tok, draft]`` at positions ``n, n + 1`` (scope
+        ``mtp_verify``) gives both positions' logits and hidden states;
+        the accept tail of every spec tick (`speculative._finish`, greedy)
+        emits ``t_{n+1}`` and, where the draft was it, ``t_{n+2}``, within
+        the slot's cap ``kcap``; then the module runs over both positions
+        with the emitted tokens (scope ``mtp_draft``), writing its rows,
+        and its logits at the last emitted position are the next draft.
+        The rejected position's rows (the model's at ``n + 1``, the
+        module's in slot ``n + 2``) lie behind the new length and are
+        overwritten by the next forward.  Returns (toks `[B, 2]`, counts,
+        accepts, new_lens, new_last, new_draft, the draft judged, pools,
+        state rows): lens, last and draft are what a chained tick takes
+        from the device."""
+        if self._mtp_fn is not None:
+            return self._mtp_fn
+        from . import speculative as _spec
+        mtp_row = [r.name for r in self.cache.rows].index("mtp")
+
+        def mtp_tick(params, pools, tables, seq_lens, last_tok, draft,
+                     kcap):
+            forward = self._forward(params)
+            B = self.B
+            with jax.named_scope("mtp_verify"):
+                h, pools = forward(
+                    jnp.stack([last_tok, draft], 1), pools, tables,
+                    seq_lens, seq_lens[:, None], None, in_tick=True,
+                    hidden=True)
+                logits = self._head(h)
+            # position 1 has no draft to judge: it is never "accepted"
+            dtoks = jnp.stack([draft, jnp.full_like(draft, -1)], 1)
+            greedy = (jnp.zeros((B,), bool), jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.uint32))
+            toks, counts, accepts, new_lens, new_last = _spec._finish(
+                self, logits, dtoks,
+                jnp.zeros(logits.shape[:2] + (1,), jnp.float32), *greedy,
+                seq_lens, kcap)
+            z, pools = self._draft(pools, tables, seq_lens, h, toks,
+                                   in_tick=True)
+            with jax.named_scope("mtp_draft"):     # its head is its price
+                drafts = jnp.argmax(self._head(z), axis=-1).astype(jnp.int32)
+            new_draft = jnp.take_along_axis(
+                drafts, jnp.maximum(counts - 1, 0)[:, None], axis=1)[:, 0]
+            new_draft = jnp.where(seq_lens > 0, new_draft, 0)
+            # the drafter's device-side counts, on the module's layer
+            tally = jnp.stack([jnp.sum(seq_lens > 0), jnp.sum(accepts)]
+                              ).astype(jnp.int32)
+            last = list(pools[-1])
+            last[mtp_row] = last[mtp_row] + tally
+            pools = list(pools[:-1]) + [tuple(last)]
+            return (toks, counts, accepts, new_lens, new_last, new_draft,
+                    draft, pools, self._state_rows(pools))
+
+        i32 = jnp.int32
+        return self._build(_Decl(
+            "serving.mtp_tick", mtp_tick,
+            (_PARAMS, _POOLS) + self._sched_in
+            + (_In((self.B,), i32), _In((self.B,), i32)),
+            (_REP,) * 7 + (_POOLS, _REP), vars(self), "_mtp_fn",
+            (("draft", "mtp"), ("depth", self.mtp.depth)),
+            {"program": "mtp_tick", "draft": "mtp",
+             "depth": self.mtp.depth}))
+
     def _prompt_program(self, name, cache, L_pad: int, at_offset: bool):
         """Both prompt programs, one a pad bucket: the prompt (or the
         chunk of one) right-padded to ``L_pad`` is written through the
@@ -1366,6 +1494,29 @@ class ServingEngine:
                 logits[0], true_len - 1, axis=0, keepdims=False)
             return row, pools
 
+        def prefill_mtp(params, pools, table_row, prompt, true_len, off,
+                        next_ids):
+            """A chunk of a self-drafting model's prompt: the model's
+            rows, then the module's from the chunk's own hidden states
+            and ``next_ids``, the prompt shifted by one; -1 there stands
+            for the token this chunk's last logits choose (the prompt's
+            last chunk).  Returns (the last real token's logits, the
+            module's draft of the token after the chosen one, pools)."""
+            forward = self._forward(params)
+            lens = jnp.reshape(off, (1,))
+            h, pools = forward(prompt, pools, table_row, lens, off, view_cls,
+                               hidden=True)
+            def last_row(x):     # the head over the last real token only
+                return self._head(jax.lax.dynamic_slice_in_dim(
+                    x, true_len - 1, 1, axis=1))[0, 0]
+
+            row = last_row(h)
+            first = jnp.argmax(row).astype(jnp.int32)
+            z, pools = self._draft(pools, table_row, lens, h,
+                                   jnp.where(next_ids < 0, first, next_ids),
+                                   view_cls)
+            return row, jnp.argmax(last_row(z)).astype(jnp.int32), pools
+
         def prefill_spec(params, draft_vals, pools, dpools, table_row,
                          prompt, true_len, *start):
             row, pools = prefill(params, pools, table_row, prompt,
@@ -1383,6 +1534,10 @@ class ServingEngine:
         if self.spec_model:
             body, state, outs = prefill_spec, \
                 (_PARAMS, _DPARAMS, _POOLS, _DPOOLS), (_REP, _POOLS, _DPOOLS)
+        elif self.mtp is not None and at_offset:
+            body, state, outs = prefill_mtp, (_PARAMS, _POOLS), \
+                (_REP, _REP, _POOLS)
+            ins += (_In((1, L_pad), i32),)
         else:
             body, state, outs = prefill, (_PARAMS, _POOLS), (_REP, _POOLS)
         return self._build(_Decl(name, body, state + ins, outs, cache,
@@ -1572,6 +1727,14 @@ class ServingEngine:
             return [self._block_tick_program] + [
                 partial(self._prefill_cont_program, L)
                 for L in self.pad_ladder]
+        if self.mtp is not None:
+            # one tick whatever the mix, every prompt through the chunk
+            # programs, and the copy-on-write a prefix hit always takes
+            grid = [self._mtp_tick_program] + [
+                partial(self._prefill_cont_program, L)
+                for L in self.pad_ladder]
+            return grid + ([self._cow_program]
+                           if self.prefix is not None else [])
         grid = [partial(self._tick_program, k)
                 for k in sorted({self.steps_per_tick, 1}, reverse=True)]
         grid.append(self._decode_program)
@@ -1735,6 +1898,17 @@ class ServingEngine:
                 if traced:
                     self._reject_trace(req, why[0])
                 raise ValueError(why[1])
+        if self.mtp is not None and req.do_sample:
+            # the self-drafted tick verifies greedily: a sampled request
+            # is refused here, with the reason, rather than served greedy
+            _M_REJECTIONS.inc(reason="sampling")
+            self._ev_note("rejected:sampling")
+            if traced:
+                self._reject_trace(req, "sampling")
+            raise ValueError(
+                "self-drafting through the multi-token-prediction module "
+                "is served greedy; do_sample is not available (build the "
+                "model with mtp_draft=False to sample)")
         if L + req.max_new_tokens > self.max_context:
             _M_REJECTIONS.inc(reason="over_context")
             self._ev_note("rejected:over_context")
@@ -2060,21 +2234,31 @@ class ServingEngine:
                 req._prefix_epoch = self.prefix.epoch
             chain = match.blocks
             cached_len = min(len(chain) * self.bs, reusable)
+            if self.mtp is not None:
+                # the module's row in a block's first slot is made of the
+                # hidden state of the token before the block: the last
+                # shared token is recomputed (into a copy of its block,
+                # the copy-on-write below) so that the request's first
+                # private slot can be written
+                cached_len = min(cached_len, len(chain) * self.bs - 1)
             if cached_len <= 0:
                 chain, cached_len = [], 0
         split_col = cached_len // self.bs
         cow = bool(chain) and (cached_len % self.bs != 0)
+        mtp_slot = 1 if self.mtp is not None else 0
         if chain or chunked:
             # exact blocks for the real prompt span: suffix/chunk writes
             # go through PagedChunkView, whose padded positions route to
             # the pad block — no bucket over-allocation to release
-            need_now = self._blocks_for(L) - split_col
+            # (a self-drafter's module keeps its row of position L - 1
+            # in slot L)
+            need_now = self._blocks_for(L + mtp_slot) - split_col
         else:
             L_pad = self._pad_bucket(L)
             need_now = self._blocks_for(L_pad)  # <= nb_per_seq by clamp
         # full reservation: prompt blocks now + growth to the worst case
         total_need = self._blocks_for(L + req.max_new_tokens)
-        growth = max(0, total_need - self._blocks_for(L))
+        growth = max(0, total_need - self._blocks_for(L + mtp_slot))
         # pin the reused blocks BEFORE any index eviction can run: a
         # chain entry freed and reallocated under us would alias garbage
         for b in chain[:split_col]:
@@ -2290,6 +2474,9 @@ class ServingEngine:
         req._stream_push(first)
         self.seq_lens[slot] = L
         self.last_tok[slot] = first
+        if self.mtp is not None:
+            self.draft_tok[slot] = int(req._first_draft)
+            req._first_draft = None
         self.samp_do[slot] = req.do_sample
         self.samp_temp[slot] = req.temperature
         self.samp_topk[slot] = max(0, int(req.top_k))
@@ -2400,6 +2587,7 @@ class ServingEngine:
                 self.tables[slot, col] = 0
         self.seq_lens[slot] = 0
         self.last_tok[slot] = 0
+        self.draft_tok[slot] = 0
         self.block_tail[slot] = []
         self.samp_do[slot] = False
         self.samp_temp[slot] = 1.0
@@ -2659,6 +2847,16 @@ class ServingEngine:
         L_pad = self._pad_bucket(n)
         suffix = np.zeros((1, L_pad), np.int32)
         suffix[0, :n] = req.prompt_ids[off:off + n]
+        extra = ()
+        if self.mtp is not None:
+            # the prompt shifted by one, for the module's rows; behind
+            # the prompt's last token stands the one this chunk chooses
+            nxt = np.zeros((1, L_pad), np.int32)
+            follow = req.prompt_ids[off + 1:off + n + 1]
+            nxt[0, :len(follow)] = follow
+            if off + n >= L:
+                nxt[0, n - 1] = -1
+            extra = (jnp.asarray(nxt),)
         # the chunk's host side (async enqueue; the LAST chunk host-syncs
         # its logits row inside): the boundary's chunk-prefill phase
         with _span("serve:chunk_dispatch", rid=req.trace_id or req.rid,
@@ -2677,9 +2875,11 @@ class ServingEngine:
                             param_vals, *dpref,
                             jnp.asarray(req._chunk_row[None, :].copy()),
                             jnp.asarray(suffix), jnp.int32(n),
-                            jnp.int32(off)))
+                            jnp.int32(off), *extra))
                 if self.spec_model:
                     row, self.pools, self.dpools = out
+                elif self.mtp is not None:
+                    row, req._first_draft, self.pools = out
                 else:
                     row, self.pools = out
                 if req._chunk_off + n >= L:
@@ -2862,6 +3062,76 @@ class ServingEngine:
         names = [r.name for r in self.cache.rows if not r.paged]
         return dict(zip(names, arrays), steps=steps)
 
+    # ------------------------------------------------- probe (public)
+    def take_blocks(self, n: int) -> List[int]:
+        """Draw ``n`` free blocks for a `probe` (the ordinary allocator:
+        refcounted, ledgered); hand them back with `give_blocks`."""
+        return [self._alloc_block() for _ in range(n)]
+
+    def give_blocks(self, blocks) -> None:
+        for b in blocks:
+            self._release_block(int(b))
+
+    def copy_block(self, src: int, dst: int) -> None:
+        """Copy block ``src`` onto ``dst`` in every layer's pools (the
+        copy-on-write program an admission runs)."""
+        args = (self.pools, self.dpools) if self.spec_model \
+            else (self.pools,)
+        out = self._cow_program()(*args, jnp.int32(src), jnp.int32(dst))
+        if self.spec_model:
+            self.pools, self.dpools = out
+        else:
+            self.pools = out
+
+    def probe(self, ids, tables, seq_lens, *, chunk: bool = False,
+              next_ids=None, slot: int = 0) -> dict:
+        """Run the engine's own forward over its own pools, outside the
+        serve loop, and hand back what the serving programs keep on the
+        device: the float32 ``logits`` `[s, V]` of sequence ``slot``.
+        ``ids`` `[B, s]` are appended at ``seq_lens`` `[B]` through
+        ``tables`` `[B, nb]` by the forward seam, the views and the
+        kernels the engine's programs use: the chunk view with ``chunk``
+        (one sequence at an offset, a prompt chunk's program), else the
+        decode view as a tick holds it (the whole batch; a zero table row
+        is an idle slot).  For a self-drafting model ``next_ids`` `[B, s]`
+        (the tokens that follow) also runs its module over the same
+        positions, writing its rows, and ``draft_logits`` `[s, V]` comes
+        back too.  The pools are threaded and donated as the programs do
+        it; the caller owns the blocks the tables name (`take_blocks`,
+        or the prefix cache's, which must only be read).  A check's tool:
+        each distinct shape compiles a program of its own."""
+        ids = jnp.asarray(ids, jnp.int32)
+        drafting = self.mtp is not None and next_ids is not None
+        key = (ids.shape, bool(chunk), drafting, int(slot))
+        fn = self._probe_fns.get(key)
+        if fn is None:
+            view_cls = self._chunk_view_cls if chunk else None
+            f32 = jnp.float32
+
+            def body(params, pools, tables, lens, ids, *nxt):
+                forward = self._forward(params)
+                args = (ids, pools, tables, lens, lens[:, None], view_cls)
+                if self.mtp is None:
+                    logits, pools = forward(*args, in_tick=not chunk)
+                    return pools, (logits[slot].astype(f32),)
+                h, pools = forward(*args, in_tick=not chunk, hidden=True)
+                out = (self._head(h[slot]).astype(f32),)
+                if nxt:
+                    z, pools = self._draft(pools, tables, lens, h, nxt[0],
+                                           view_cls, in_tick=not chunk)
+                    out += (self._head(z[slot]).astype(f32),)
+                return pools, out
+
+            fn = self._probe_fns[key] = jax.jit(
+                body, donate_argnums=() if jax.default_backend() == "cpu"
+                else (1,))
+        nxt = (jnp.asarray(next_ids, jnp.int32),) if drafting else ()
+        with self._params_for_call() as param_vals:
+            self.pools, out = fn(
+                param_vals, self.pools, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(seq_lens, jnp.int32), ids, *nxt)
+        return dict(zip(("logits", "draft_logits"), out))
+
     def _selected(self, contexts) -> int:
         """Cached tokens the queries of these contexts attend to, summed:
         all of a context, or the cache's `attend_limit` of it under
@@ -2875,6 +3145,8 @@ class ServingEngine:
         one where eligible) and advance the host's view of the slots."""
         if self.gen is not None:
             return self._launch_block_tick(active, t0)
+        if self.mtp is not None:
+            return self._dispatch_mtp(active, t0, chain)
         device_sampling = _flags.get_flag("serving_device_sampling")
         # a chained dispatch continues its predecessor's kind (the
         # overlap gate matched them); at a boundary, spec eligibility is
@@ -3145,6 +3417,61 @@ class ServingEngine:
         pend.kcap = kcap
         return pend
 
+    def _dispatch_mtp(self, active, t0, chain=None):
+        """Launch one self-drafted tick (`_mtp_tick_program`) in flight.
+        As a spec tick (`_dispatch_spec`): each slot's emit cap ``kcap =
+        min(2, remaining budget)`` rides in, the host's lengths advance
+        by that upper bound now and the harvest refunds ``kcap -
+        emitted``; a chained dispatch takes the predecessor's lengths,
+        last tokens AND drafts from the device, so nothing the next tick
+        needs comes back to the host first."""
+        kcap = np.zeros((self.B,), np.int32)
+        for slot in active:
+            req = self.slot_req[slot]
+            cap = min(2, req.max_new_tokens - int(self.tok_pos[slot]))
+            kcap[slot] = cap
+            base = int(self.seq_lens[slot])
+            end = len(req.prompt_ids) + req.max_new_tokens
+            # the model writes positions base..base+cap-1, the module the
+            # slots one further
+            for pos in range(base, min(base + cap + 1, end)):
+                col = pos // self.bs
+                if pos % self.bs == 0 and self.tables[slot, col] == 0:
+                    self.tables[slot, col] = self._alloc_block()
+                    self.reserved -= 1
+                    req._growth_left -= 1
+        san = _jaxsan.token("serving.tick")
+        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
+        if chain is not None:
+            carry = (chain.new_lens, chain.new_last, chain.new_draft)
+        else:
+            carry = (dev(self.seq_lens), dev(self.last_tok),
+                     dev(self.draft_tok))
+        with self._params_for_call() as param_vals, \
+                _flight.guard("serving.tick"):
+            (toks, counts, accepts, new_lens, new_last, new_draft, judged,
+             self.pools, state) = self._dispatch_call(
+                "serving.tick.dispatch",
+                lambda: self._mtp_tick_program()(
+                    param_vals, self.pools, dev(self.tables), *carry,
+                    dev(kcap)))
+        self.steps += 1              # one verify forward (and its draft)
+        for slot in active:
+            self.seq_lens[slot] += int(kcap[slot])
+            self.tok_pos[slot] += int(kcap[slot])
+        # k = 1: the drafts a slot proposes a tick (the harvest's count)
+        pend = _PendingTick(active=active, k=1, toks=toks, logits=None,
+                            reqs=list(self.slot_req), t0=t0,
+                            device_sampling=True, step_no=self.steps,
+                            san=san)
+        pend.spec = True
+        pend.counts, pend.accepts = counts, accepts
+        pend.new_lens, pend.new_last = new_lens, new_last
+        pend.new_draft, pend.judged = new_draft, judged
+        pend.kcap = kcap
+        pend.state = state
+        return pend
+
     def _harvest_tick(self, pend) -> None:
         """Block on the tick's device tokens and feed the requests:
         append, EOS/budget-check, host-sample (fallback path only).
@@ -3197,6 +3524,10 @@ class ServingEngine:
             # applied by an overlapped next dispatch
             counts = np.asarray(pend.counts)
             accepts = np.asarray(pend.accepts)
+            mtp = pend.judged is not None
+            if mtp:
+                judged = np.asarray(pend.judged)
+                new_draft = np.asarray(pend.new_draft)
             metrics_on = _metrics.enabled()
             for slot in pend.active:
                 req = pend.reqs[slot]
@@ -3222,6 +3553,10 @@ class ServingEngine:
                               / max(req._spec_proposed, 1), 4),
                         slot=slot)
                 self.last_tok[slot] = int(toks[slot, c - 1])
+                if mtp:
+                    self.draft_tok[slot] = int(new_draft[slot])
+                    req.draft_log.append((int(judged[slot]),
+                                          bool(accepts[slot]), c))
                 for j in range(c):
                     if req.done:
                         break    # post-eos tokens are discarded
@@ -3235,6 +3570,15 @@ class ServingEngine:
             self.spec_ticks += 1
             self.spec_proposed += spec_proposed
             self.spec_accepted += spec_accepted
+            if mtp and pend.state:
+                # the device-side counts came with the tick's tokens (the
+                # module's layer holds them): what they grew by since the
+                # last harvest feeds the counters
+                drafted, taken = (
+                    int(v) for v in self.cache_state()["mtp"][-1])
+                _M_MTP_DRAFTED.inc(drafted - self.mtp_forwards)
+                _M_MTP_ACCEPTED.inc(taken - self.mtp_accepted)
+                self.mtp_forwards, self.mtp_accepted = drafted, taken
             if spec_proposed:
                 _M_SPEC_PROPOSED.inc(spec_proposed)
                 # the adaptive controller's evidence: tick-level accept
@@ -3460,7 +3804,7 @@ class ServingEngine:
         if self.prefilling and not self._chunk_overlap_ok():
             return False     # pending chunk work needs a real boundary
         if pend.spec:
-            if not self.spec_model:
+            if not self.spec_model and self.mtp is None:
                 return False     # ngram proposals need the harvested
                                  # tokens: a host draft cannot chain
             if self._adapt_step():
@@ -3479,8 +3823,9 @@ class ServingEngine:
             # must land on a REAL boundary — a chained dispatch feeds
             # the predecessor's device handles, so a probe around it
             # would time both ticks
-            if _xray.sampling_on() \
-                    and _xray.sample_due(self._spec_fns.get(pend.k)):
+            if _xray.sampling_on() and _xray.sample_due(
+                    self._mtp_fn if self.mtp is not None
+                    else self._spec_fns.get(pend.k)):
                 return False
             return True
         if not pend.device_sampling and any(
@@ -4117,6 +4462,14 @@ class ServingEngine:
                                 else round(self._accept_ewma, 4)),
                 "ineligible_slots": self.spec_ineligible_slots,
                 "per_slot_accept_rate": per_slot}
+        if self.mtp is not None:
+            out["spec"] = {
+                "draft": "mtp", "depth": self.mtp.depth,
+                "ticks": self.spec_ticks,
+                "drafted": self.mtp_forwards,
+                "accepted": self.mtp_accepted,
+                "accept_rate": round(
+                    self.mtp_accepted / max(self.mtp_forwards, 1), 4)}
         if self._quant_stats is not None:
             out["quant"] = dict(self._quant_stats)
         if self.prefix is not None:

@@ -125,9 +125,15 @@ class Indexer(nn.Layer):
 
 
 class GlmMoeDsaAttention(nn.Layer):
-    def __init__(self, cfg: GlmMoeDsaConfig):
+    """MLA, with the sparse index where the configuration has one
+    (`index_topk` > 0) and over every cached row where it has none
+    (`glm4_moe_lite`; `shift` is its multi-token-prediction module's
+    cache layout, `LatentDenseCache.append_and_attend`)."""
+
+    def __init__(self, cfg, shift: int = 0):
         super().__init__()
         self.cfg = cfg
+        self.shift = shift
         H, nh = cfg.hidden_size, cfg.num_heads
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
             cfg.v_head_dim
@@ -139,12 +145,14 @@ class GlmMoeDsaAttention(nn.Layer):
         self.kv_a_layernorm = nn.RMSNorm(cfg.kv_lora_rank, cfg.rms_eps)
         self.kv_b_proj = lin(cfg.kv_lora_rank, nh * (dn + dv))
         self.o_proj = lin(nh * dv, H)
-        self.indexer = Indexer(cfg)
+        self.indexer = Indexer(cfg) if getattr(cfg, "index_topk", 0) \
+            else None
 
     def forward(self, x, cache=None):
         """Without a cache: the attention's output.  Over a cache view:
         (output, the advanced view, the positions each query selected
-        `[B, s, k]`, -1 where fewer than k exist)."""
+        `[B, s, k]`, -1 where fewer than k exist; None without an
+        index)."""
         cfg = self.cfg
         b, s = x.shape[0], x.shape[1]
         nh, dc = cfg.num_heads, cfg.kv_lora_rank
@@ -164,7 +172,8 @@ class GlmMoeDsaAttention(nn.Layer):
             q_rope = _rope(q[..., dn:], pos, cfg.rope_base)
             k_rope = _rope(kva._value[..., dc:].reshape(b, s, 1, dr), pos,
                            cfg.rope_base)[:, :, 0]
-            q_i, k_i, w_i = self.indexer(x, c_q, pos)
+            q_i, k_i, w_i = self.indexer(x, c_q, pos) \
+                if self.indexer is not None else (None, None, None)
             w_kvb = self.kv_b_proj.weight._value.reshape(dc, nh, dn + dv)
         if cache is None:
             o = self._dense(q[..., :dn], q_rope, c_kv, k_rope, q_i, k_i, w_i,
@@ -175,9 +184,14 @@ class GlmMoeDsaAttention(nn.Layer):
                                w_kvb[..., :dn])
             q_cat = jnp.concatenate([q_abs.astype(q.dtype), q_rope], -1)
             row = jnp.concatenate([c_kv._value, k_rope], -1)
-        new_cache, o_lat, selected = cache.append_and_attend(
-            q_cat, q_i, w_i, row, k_i, topk=cfg.index_topk, scale=scale,
-            d_latent=dc)
+        if self.indexer is None:
+            new_cache, o_lat = cache.append_and_attend(
+                q_cat, row, scale=scale, d_latent=dc, shift=self.shift)
+            selected = None
+        else:
+            new_cache, o_lat, selected = cache.append_and_attend(
+                q_cat, q_i, w_i, row, k_i, topk=cfg.index_topk, scale=scale,
+                d_latent=dc)
         with jax.named_scope("mla_proj"):
             o = jnp.einsum("bthc,chv->bthv", o_lat.astype(q.dtype),
                            w_kvb[..., dn:])
@@ -193,16 +207,10 @@ class GlmMoeDsaAttention(nn.Layer):
         kv = self.kv_b_proj(c_kv)._value.reshape(b, s, nh, -1)
         k_nope, v = kv[..., :dn], kv[..., dn:]
         causal = jnp.tril(jnp.ones((s, s), bool))
-        idx = jnp.einsum(
-            "bthk,bth->btk",
-            jax.nn.relu(jnp.einsum("bthd,bkd->bthk", q_i, k_i,
-                                   preferred_element_type=f32)),
-            w_i.astype(f32))
-        idx = jnp.where(causal, idx, -jnp.inf)
-        top_i = jax.lax.top_k(idx, min(cfg.index_topk, s))[1]
-        keep = causal & jnp.zeros((b, s, s), bool).at[
-            jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
-            top_i].set(True)
+        if self.indexer is None:
+            keep = jnp.broadcast_to(causal, (b, s, s))
+        else:
+            keep = self._selected_mask(q_i, k_i, w_i, causal)
         sc = (jnp.einsum("bthd,bkhd->bhtk", q_nope, k_nope,
                          preferred_element_type=f32)
               + jnp.einsum("bthd,bkd->bhtk", q_rope, k_rope,
@@ -210,12 +218,27 @@ class GlmMoeDsaAttention(nn.Layer):
         p = jax.nn.softmax(jnp.where(keep[:, None], sc, -jnp.inf), axis=-1)
         return jnp.einsum("bhtk,bkhd->bthd", p.astype(v.dtype), v)
 
+    def _selected_mask(self, q_i, k_i, w_i, causal):
+        """`[b, s, s]`: the keys each query's index selects."""
+        cfg, f32 = self.cfg, jnp.float32
+        b, s = q_i.shape[0], q_i.shape[1]
+        idx = jnp.einsum(
+            "bthk,bth->btk",
+            jax.nn.relu(jnp.einsum("bthd,bkd->bthk", q_i, k_i,
+                                   preferred_element_type=f32)),
+            w_i.astype(f32))
+        idx = jnp.where(causal, idx, -jnp.inf)
+        top_i = jax.lax.top_k(idx, min(cfg.index_topk, s))[1]
+        return causal & jnp.zeros((b, s, s), bool).at[
+            jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+            top_i].set(True)
+
 
 class GlmMoeDsaBlock(nn.Layer):
-    def __init__(self, cfg: GlmMoeDsaConfig, layer_idx: int):
+    def __init__(self, cfg, layer_idx: int, shift: int = 0):
         super().__init__()
         self.input_layernorm = nn.RMSNorm(cfg.hidden_size, cfg.rms_eps)
-        self.self_attn = GlmMoeDsaAttention(cfg)
+        self.self_attn = GlmMoeDsaAttention(cfg, shift)
         self.post_attention_layernorm = nn.RMSNorm(cfg.hidden_size,
                                                    cfg.rms_eps)
         mlp = lambda width: LlamaMLP(SimpleNamespace(         # noqa: E731
@@ -252,8 +275,9 @@ class GlmMoeDsaBlock(nn.Layer):
         y, rows = self.mlp.forward_counted(
             h, jnp.repeat(cache.active, a.shape[1]))
         # rows given to each held expert, by kind of program:
-        # [decode step | chunk] x [rows | experts hit] x [held]
-        kind = 0 if a.shape[1] == 1 else 1
+        # [decode step or tick | chunk] x [rows | experts hit] x [held]
+        kind = 0 if a.shape[1] == 1 or getattr(cache, "in_tick", False) \
+            else 1
         new = new.replace(moe_rows=new.moe_rows.at[kind].add(
             jnp.stack([rows, (rows > 0).astype(rows.dtype)])))
         return x + y, new, selected

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 __all__ = ["StaticKVCache", "PagedKVCache", "PagedChunkView",
            "PagedChunkKernelView", "PagedVerifyKernelView", "PoolRow",
-           "CacheSpec", "BlockDiffusion", "kv_cache_spec", "LatentPagedCache"]
+           "CacheSpec", "BlockDiffusion", "SelfDraft", "kv_cache_spec",
+           "LatentPagedCache", "LatentDenseCache"]
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -350,6 +351,20 @@ class BlockDiffusion:
 
 
 @dataclasses.dataclass(frozen=True)
+class SelfDraft:
+    """How a model with a multi-token-prediction module drafts for itself
+    (`CacheSpec.generation`): a forward of a sequence runs the last token
+    and the module's draft of the next, and yields one token or, where
+    the draft was the model's own choice, two; the module then drafts
+    again from the hidden states of the emitted positions.  Its cache is
+    the spec's LAST `depth` layers, whose row for position i sits in slot
+    i + 1: the row is made of token i + 1, so a block's content depends
+    only on tokens up to the block's own end (what prefix sharing needs).
+    The model offers `forward_hidden`, `head` and `draft_hidden`."""
+    depth: int = 1
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What `ServingEngine` needs to know of a model's cache, from
     `model.cache_spec()`: the arrays a layer keeps (`rows`, the same for
@@ -362,7 +377,7 @@ class CacheSpec:
     `attend_limit` is the size of a sparse selection, for the spans'
     `selected_tokens`.  `generation` is None for a model that yields one
     token a forward and sequence, or says what else it does
-    (`BlockDiffusion`): the engine builds its tick from it."""
+    (`BlockDiffusion`, `SelfDraft`): the engine builds its tick from it."""
     num_layers: int
     rows: tuple
     view: type                    # decode step, prefill from empty
@@ -467,6 +482,72 @@ jax.tree_util.register_pytree_node(
     LatentPagedCache,
     lambda c: ((c.ckv, c.kidx, c.moe_rows, c.tables, c.seq_lens), c.bs),
     lambda bs, ch: LatentPagedCache(*ch, bs))
+
+
+class LatentDenseCache:
+    """A layer's cache under latent attention WITHOUT an index (MLA as
+    `glm4_moe_lite` has it): one pool of latent rows (`c_kv | k_rope`, one
+    a token, shared by every head) that every query attends whole,
+    `moe_rows`, the layer's count of the rows its held experts were given,
+    and `mtp`, the self-drafter's (drafted, accepted) counts (device
+    state, not paged; the engine's tick adds to the last layer's).
+
+    One class serves every program: `s` rows are appended at `seq_lens`
+    and each query attends all rows up to its own, through the Pallas
+    kernel for the few queries of a decode step or a verify and in plain
+    XLA for a chunk (`ops/pallas_latent.py`).  `shift=1` is the
+    multi-token-prediction module's layout (`SelfDraft`): rows land one
+    slot later and slot 0 is never read."""
+
+    in_tick = False      # as `PagedKVCache.in_tick`: set by the engine
+
+    def __init__(self, ckv, moe_rows, mtp, tables, seq_lens, block_size):
+        self.ckv, self.moe_rows, self.mtp = ckv, moe_rows, mtp
+        self.tables, self.seq_lens, self.bs = tables, seq_lens, block_size
+
+    from_parts = classmethod(lambda cls, *a: cls(*a))
+
+    @property
+    def pools(self):
+        return (self.ckv, self.moe_rows, self.mtp)
+
+    @property
+    def active(self):
+        return self.tables[:, 0] != 0
+
+    def replace(self, **kw):
+        new = LatentDenseCache(*self.pools, self.tables, self.seq_lens,
+                               self.bs)
+        new.in_tick = self.in_tick
+        for k, v in kw.items():
+            setattr(new, k, v)
+        return new
+
+    def append_and_attend(self, q_cat, row, *, scale, d_latent, shift=0):
+        """Append `row` `[B, s, d_latent + rope]` at `seq_lens + shift`,
+        then attend `q_cat` `[B, s, nh, d_latent + rope]` over every row
+        from slot `shift` to the query's own.  Returns (advanced view,
+        `sum p c_kv` `[B, s, nh, d_latent]` float32)."""
+        from ..ops import pallas_latent, sparse_mla
+        s = row.shape[1]
+        start = self.seq_lens + shift
+        new = self.replace(
+            ckv=sparse_mla.write_rows(self.ckv, self.tables, start, row),
+            seq_lens=self.seq_lens + s)
+        lens = jnp.where(self.active, start + s, 0)
+        attend = pallas_latent.paged_latent_attention \
+            if s <= pallas_latent.KERNEL_MAX_QUERIES \
+            else pallas_latent.latent_chunk_attention
+        with jax.named_scope("mla_attend"):
+            o = attend(q_cat, new.ckv, self.tables, lens, scale=scale,
+                       d_latent=d_latent, first=shift)
+        return new, o
+
+
+jax.tree_util.register_pytree_node(
+    LatentDenseCache,
+    lambda c: ((c.ckv, c.moe_rows, c.mtp, c.tables, c.seq_lens), c.bs),
+    lambda bs, ch: LatentDenseCache(*ch, bs))
 
 
 def _dense_causal(q, k, v):

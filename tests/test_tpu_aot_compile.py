@@ -26,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import (pallas_common, pallas_dsa, pallas_flash,
+                            pallas_latent,
                             pallas_moe, sparse_mla)
 from paddle_tpu.ops import pallas_paged as pp
 
@@ -203,6 +204,30 @@ def _cases():
                 functools.partial(pallas_moe.grouped_matmul,
                                   group_offset=0, interpret=False),
                 (((rows, k), BF16), ((128, k, n), BF16), ((129,), i32)))
+    # GLM-4.7-Flash's dense latent attention at its published shape: 20
+    # heads over 640-lane rows, batch 24 over a table 288 wide (a context
+    # of 18,432), a decode step's one query a slot and a self-drafted
+    # verify's two (the module's cache attends from slot 1); a 512-token
+    # chunk in the XLA form; and its expert layer, all 64 experts held:
+    # a verify's rows (24 slots x 2 positions x 4 choices) and a chunk's
+    pool = ((4097, 64, 640), BF16)
+    for s_q in (1, 2):
+        cases[f"paged_latent_attention B24 s{s_q} nh20 ctx18k bf16"] = (
+            functools.partial(pallas_latent.paged_latent_attention,
+                              scale=1 / 16, d_latent=512, first=s_q - 1,
+                              interpret=False),
+            (((24, s_q, 20, 576), BF16), pool, ((24, 288), i32),
+             ((24,), i32)))
+    cases["latent_chunk_attention s512 nh20 ctx18k bf16"] = (
+        functools.partial(pallas_latent.latent_chunk_attention,
+                          scale=1 / 16, d_latent=512),
+        (((1, 512, 20, 576), BF16), pool, ((1, 288), i32), ((1,), i32)))
+    for rows in (192, 2048):
+        for k, n in ((2048, 1536), (1536, 2048)):
+            cases[f"moe_grouped_matmul e64 m{rows} k{k} n{n} bf16"] = (
+                functools.partial(pallas_moe.grouped_matmul,
+                                  group_offset=0, interpret=False),
+                (((rows, k), BF16), ((64, k, n), BF16), ((65,), i32)))
     B, S, nh, hd = 2, 1024, 12, 64
     kv = ((B, S, nh // 4, hd), BF16)            # GQA: 3 kv heads for 12
     cases["flash fwd+bwd kv_mask dropout gqa bf16"] = (
@@ -258,6 +283,8 @@ KERNEL_CASES = {
     "paged_chunk_prefill": "paged_chunk_attention s1024 nh16 hd128 bfloat16",
     "paged_spec_verify": "paged_verify_attention k4 nh16 hd128 bfloat16",
     "dsa_index_scores": "dsa_index_scores B16 ctx32k bf16",
+    "paged_latent_attention":
+        "paged_latent_attention B24 s2 nh20 ctx18k bf16",
 }
 
 
