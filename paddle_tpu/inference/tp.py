@@ -222,8 +222,11 @@ def forward_tp(meta, params, ids, pools, tables, seq_lens, pos_offset,
         qkv = jnp.matmul(h, _w(blk["qkv_w"]).reshape(
             meta["H"], 3 * nh_l * hd)) \
             + blk["qkv_b"].reshape(3 * nh_l * hd)
-        qkv = qkv.reshape(B, s, 3, nh_l, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        # column windows, not a reshape to [B, s, 3, nh_l, hd]: XLA:TPU
+        # folds that into the dot and then copies the weight into the
+        # other layout every launch (models/gpt.py has the same lines)
+        q, k, v = (t.reshape(B, s, nh_l, hd)
+                   for t in jnp.split(qkv, 3, axis=-1))
         kp, vp = pools[li]
         view = view_cls.from_parts(kp, vp, tables, seq_lens, block_size)
         new_view, out = view.update_and_attend(q, k, v)

@@ -85,8 +85,15 @@ class GPTAttention(nn.Layer):
     def forward(self, x, kv_cache=None):
         b, s = x.shape[0], x.shape[1]
         qkv = self.qkv(x)
-        qkv = _m.reshape(qkv, [b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = _m.unbind(qkv, axis=2)
+        # q, k, v are column windows of the result: the values of
+        # `reshape(qkv, [b, s, 3, nh, hd])` unbound on axis 2, element for
+        # element.  XLA:TPU folds THAT reshape (a new axis between the
+        # matmul's output columns) into the dot, as a 3 x nh-window
+        # convolution whose kernel wants the weight in the other layout,
+        # and every launch then copies every layer's weight first
+        # (PERF.md §6, PR 35).
+        q, k, v = (_m.reshape(t, [b, s, self.num_heads, self.head_dim])
+                   for t in _m.split(qkv, 3, axis=-1))
         if kv_cache is not None and not isinstance(kv_cache, tuple):
             from .kv_cache import PagedKVCache, StaticKVCache
             if isinstance(kv_cache, (StaticKVCache, PagedKVCache)):

@@ -330,7 +330,7 @@ def _lower_engine_programs(v5e, chunk, max_batch=8, **cfg):
     try:
         paddle.seed(0)
         model = GPTForCausalLM(GPTConfig(
-            vocab_size=256, intermediate_size=256, **cfg))
+            **{"vocab_size": 256, "intermediate_size": 256, **cfg}))
         model.eval()
         model.bfloat16()
         eng = ServingEngine(model, max_batch=max_batch,
@@ -411,12 +411,12 @@ def pool_programs(v5e):
     return {key: (f.result(), shape) for key, (f, shape) in futs.items()}
 
 
-def _pool_relayouts(hlo_text: str, shape) -> list:
+def _relayouts(hlo_text: str, shapes) -> list:
     """(computation, instruction line) of every `copy`, `scatter` or
-    `transpose` whose result has the pool's shape."""
+    `transpose` whose result has one of `shapes` (bf16)."""
     import re
-    dims = ",".join(str(d) for d in shape)
-    op = re.compile(rf"= bf16\[{dims}\]\S* (copy|scatter|transpose)\(")
+    dims = "|".join(",".join(str(d) for d in shape) for shape in shapes)
+    op = re.compile(rf"= bf16\[(?:{dims})\]\S* (copy|scatter|transpose)\(")
     out, comp = [], None
     for ln in hlo_text.splitlines():
         if ln.endswith("{") and not ln.startswith(" "):
@@ -434,7 +434,7 @@ def test_serving_program_leaves_the_pools_in_place(pool_programs, nh, hd,
     text = compiled.as_text()
     if program not in ("cow", "prefill"):
         assert "tpu_custom_call" in text         # the kernel reads the pool
-    hits = _pool_relayouts(text, shape)
+    hits = _relayouts(text, [shape])
     assert not [h for h in hits if " copy(" not in h[1]], hits
     dims = ",".join(str(d) for d in shape)
     row_major = f"bf16[{dims}]{{3,2,1,0:" in text.splitlines()[0]
@@ -468,6 +468,52 @@ def test_tick_holds_one_paged_decode_a_layer(pool_programs, nh, hd, program):
     assert len(names) == 2 and all("paged_decode" in n for n in names), names
 
 
+# ------------------------------------- weights stay put (ISSUE 35)
+# XLA:TPU folds a reshape of a fused projection's `[b, s, 3H]` result to
+# `[b, s, 3, nh, hd]` into the dot, as a `bf01_01oi->b01f` convolution
+# with a 3 x nh window whose kernel operand wants the weight in the other
+# layout: every launch of every serving program then copies each layer's
+# `[H, 3H]` weight first (25 MB a layer at the 1.3B serve cell's widths:
+# 1.7 ms a launch, a tenth of the cell's device time, PERF.md §6, PR 35).
+# The engine's programs on a two-layer model at the cell's real widths
+# are compiled here and their HLO read: no `copy` or `transpose` whose
+# result has the shape of a weight, either way round.
+
+WEIGHT_PROGRAMS = ("tick k1", "tick k4", "prefill_cont", "prefill")
+
+
+@pytest.fixture(scope="module")
+def cell_programs(v5e):
+    """`{program: compiled}` at `serve-1p3b-chat`'s widths: hidden 2048,
+    16 heads of 128, FFN 8192, batch 16, context 1536, chunk 256; the
+    vocabulary is 512 so that no activation has the embedding's shape."""
+    _, progs = _lower_engine_programs(
+        v5e, 256, max_batch=16, hidden_size=2048, num_layers=2,
+        num_heads=16, max_seq_len=1536, intermediate_size=8192,
+        vocab_size=512)
+    with concurrent.futures.ThreadPoolExecutor(max_workers=4) as ex:
+        futs = {label: ex.submit(progs[label][1].compile)
+                for label in WEIGHT_PROGRAMS}
+    return {label: f.result() for label, f in futs.items()}
+
+
+@pytest.mark.parametrize("program", WEIGHT_PROGRAMS)
+def test_serving_program_leaves_the_weights_in_place(cell_programs,
+                                                     program):
+    compiled = cell_programs[program]
+    # a weight is a 2-D bf16 argument of the program
+    weights = {tuple(a.shape)
+               for a in jax.tree_util.tree_leaves(compiled.args_info)
+               if len(a.shape) == 2 and a.dtype == BF16}
+    assert {(2048, 6144), (2048, 8192), (8192, 2048)} <= weights, weights
+    text = compiled.as_text()
+    hits = _relayouts(text, weights | {w[::-1] for w in weights})
+    assert not hits, hits
+    # ...and the fused projection is a matmul over the parameter as it
+    # lies, not a windowed convolution
+    assert "dim_labels=bf01_01oi->b01f" not in text
+
+
 def test_copy_metric_reads_the_opcode_copy_and_no_other(pool_programs):
     """`copy_time_pct.serve` (a data-only metric: a pattern for the
     accepted `xplane:matching_time_pct`) finds a re-layout by the
@@ -487,7 +533,7 @@ def test_copy_metric_reads_the_opcode_copy_and_no_other(pool_programs):
     rx = re.compile(lm["args"]["pattern"])
     compiled, shape = pool_programs[12, 64, "tick k4"]
     text = compiled.as_text()
-    assert _pool_relayouts(text, shape)
+    assert _relayouts(text, [shape])
     kinds = set()
     for ln in text.splitlines():
         ln = ln.strip()
