@@ -157,7 +157,7 @@ import math
 import os
 import threading
 from collections import deque
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager
 from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
@@ -211,6 +211,12 @@ _M_SAMPLED = _metrics.counter(
 _M_OVERLAP = _metrics.counter(
     "serving.overlap_dispatches", "ticks dispatched before the previous "
     "tick was harvested (double-buffered fast path)")
+_M_BOUNDARIES = _metrics.counter(
+    "serving.boundaries", "tick boundaries (the schedule ran with "
+    "nothing in flight) by why the tick before was not chained: "
+    "`waiting` an arrival, `finished` / `budget_spent` an answer's end, "
+    "`chunk_pending` a final prefill chunk, `cancelled`, `stopping`, "
+    "`block_tick`, ...; `idle` when nothing was in flight")
 _M_PREFIX_HITS = _metrics.counter(
     "serving.prefix_hits", "admissions whose prompt prefix was resident "
     "in the shared-block index (prefill skipped for those blocks)")
@@ -1026,6 +1032,9 @@ class ServingEngine:
         # the event `serve_forever` runs until, while it runs: a set
         # event ends the chain of ticks (`_can_overlap`)
         self._stop_event = None
+        # why the next boundary is one: `_boundary_reason`'s word for the
+        # tick `_cycle` last harvested alone, "idle" with none in flight
+        self._boundary_why = "idle"
         # --- router evidence (ISSUE 16): always-on (independent of the
         # metrics gate) recent admission timestamps + TTFTs.  /healthz
         # ships rate + median so the fleet router's queue-position
@@ -2020,6 +2029,38 @@ class ServingEngine:
             site=site, retry_on=(RuntimeError, OSError),
             counter=_RetryCounter(self))
 
+    @contextmanager
+    def _staging(self, name: str, shield=None):
+        """The leaf span ``name`` around a launch's host-to-device
+        transfers, one stretch at the head of the callable
+        `_dispatch_call` runs (and retries), so that what is left of the
+        parent span is the enqueue: yields ``dev``, which hands one host
+        array to the device (a tick's through ``shield``, the private
+        copy `_launch_tick` explains); at its end the span carries how
+        many ``arrays`` and ``bytes`` went."""
+        sent = [0, 0]
+
+        def dev(a):
+            if shield is not None:
+                a = shield(a)
+            sent[0] += 1
+            sent[1] += a.nbytes     # the host's count: a jax.Array's
+            return jnp.asarray(a)   # own `nbytes` costs 2 us a read
+
+        with _span(name) as sp:
+            yield dev
+            sp.set(arrays=sent[0], bytes=sent[1])
+
+    def _draw_blocks(self, slot: int, lo: int, hi: int) -> None:
+        """Ensure a physical block exists for every position ``lo..hi-1``
+        of ``slot`` (all draws covered by the admission's reservation)."""
+        for pos in range(lo, hi):
+            col = pos // self.bs
+            if pos % self.bs == 0 and self.tables[slot, col] == 0:
+                self.tables[slot, col] = self._alloc_block()
+                self.reserved -= 1
+                self.slot_req[slot]._growth_left -= 1
+
     def _screen_row(self, row, slot: int, req: Request) -> np.ndarray:
         """Host-materialize a prefill logits row and screen it.
 
@@ -2040,16 +2081,20 @@ class ServingEngine:
                 f"prefill logits non-finite for rid={req.rid}")
         return row_np
 
-    def _screen_decode_logits(self, pend):
-        """Host-materialize the host-sampling decode tick's logits and
-        screen the active rows (chaos NaN injection + watchdog probe).
-        Returns ``(logits ndarray or None, {slot: error})``.  Gated the
-        same way as `_screen_row`: with the watchdog off and no chaos
-        armed, nothing is materialized beyond what the sampler itself
-        would have pulled."""
-        if not _flight.enabled() and not _chaos.active_faults():
-            return None, {}
-        logits_np = np.array(np.asarray(pend.logits))
+    @staticmethod
+    def _screens_decode_logits() -> bool:
+        return bool(_flight.enabled() or _chaos.active_faults())
+
+    def _screen_decode_logits(self, pend, logits_np):
+        """Screen the active rows of the host-sampling decode tick's
+        logits as `_readback` brought them to the host (chaos NaN
+        injection + watchdog probe).  Returns ``(logits ndarray or None,
+        {slot: error})``.  Gated the same way as `_screen_row`: with the
+        watchdog off and no chaos armed, nothing is materialized beyond
+        what the sampler itself pulls."""
+        if not self._screens_decode_logits():
+            return logits_np, {}
+        logits_np = np.array(logits_np)
         bad: dict = {}
         for slot in pend.active:
             req = pend.reqs[slot]
@@ -2203,102 +2248,105 @@ class ServingEngine:
     def _try_admit(self) -> bool:
         if not self.waiting or not self.free_slots:
             return False
-        self._promote_waiting()
-        req = self.waiting[0]
-        L = len(req.prompt_ids)
-        chunked = self.chunk > 0
-        # the prompt tokens a hit may stand for: all but the last, whose
-        # logits are the first token — or, under block diffusion, the
-        # whole blocks (no logits are needed of a prompt)
-        reusable = L - 1 if self.gen is None else self._prefill_len(req)
-        # --- prefix lookup: the longest resident full-block prefix is a
-        # pointer copy; reuse is capped at L-1 so at least one suffix
-        # token runs forward (its logits are the request's first token).
-        # The cap makes copy-on-write exactly the fully-cached aligned
-        # case: the last prompt token must be recomputed INTO a block the
-        # index still shares.
-        chain: List[int] = []
-        cached_len = 0
-        match = None
-        if self.prefix is not None:
-            # a deferred request retries every loop iteration: cache its
-            # lookup across retries (the hash chain is O(prompt)) —
-            # valid only within the index epoch, since an eviction could
-            # free-and-reallocate a matched block under us
-            match = getattr(req, "_prefix_match", None)
-            if match is None \
-                    or getattr(req, "_prefix_epoch", -1) \
-                    != self.prefix.epoch:
-                match = self.prefix.lookup(req.prompt_ids)
-                req._prefix_match = match
-                req._prefix_epoch = self.prefix.epoch
-            chain = match.blocks
-            cached_len = min(len(chain) * self.bs, reusable)
-            if self.mtp is not None:
-                # the module's row in a block's first slot is made of the
-                # hidden state of the token before the block: the last
-                # shared token is recomputed (into a copy of its block,
-                # the copy-on-write below) so that the request's first
-                # private slot can be written
-                cached_len = min(cached_len, len(chain) * self.bs - 1)
-            if cached_len <= 0:
-                chain, cached_len = [], 0
-        split_col = cached_len // self.bs
-        cow = bool(chain) and (cached_len % self.bs != 0)
-        mtp_slot = 1 if self.mtp is not None else 0
-        if chain or chunked:
-            # exact blocks for the real prompt span: suffix/chunk writes
-            # go through PagedChunkView, whose padded positions route to
-            # the pad block — no bucket over-allocation to release
-            # (a self-drafter's module keeps its row of position L - 1
-            # in slot L)
-            need_now = self._blocks_for(L + mtp_slot) - split_col
-        else:
-            L_pad = self._pad_bucket(L)
-            need_now = self._blocks_for(L_pad)  # <= nb_per_seq by clamp
-        # full reservation: prompt blocks now + growth to the worst case
-        total_need = self._blocks_for(L + req.max_new_tokens)
-        growth = max(0, total_need - self._blocks_for(L + mtp_slot))
-        # pin the reused blocks BEFORE any index eviction can run: a
-        # chain entry freed and reallocated under us would alias garbage
-        for b in chain[:split_col]:
-            self._ref_block(b)
-        cow_src = chain[split_col] if cow else None
-        if cow_src is not None:
-            self._ref_block(cow_src)
-
-        def unpin():
+        # the boundary's admission decision, a leaf of serve:schedule:
+        # queue order, prefix lookup, the capacity check
+        with _span("serve:admit"):
+            self._promote_waiting()
+            req = self.waiting[0]
+            L = len(req.prompt_ids)
+            chunked = self.chunk > 0
+            # the prompt tokens a hit may stand for: all but the last, whose
+            # logits are the first token — or, under block diffusion, the
+            # whole blocks (no logits are needed of a prompt)
+            reusable = L - 1 if self.gen is None else self._prefill_len(req)
+            # --- prefix lookup: the longest resident full-block prefix is a
+            # pointer copy; reuse is capped at L-1 so at least one suffix
+            # token runs forward (its logits are the request's first token).
+            # The cap makes copy-on-write exactly the fully-cached aligned
+            # case: the last prompt token must be recomputed INTO a block the
+            # index still shares.
+            chain: List[int] = []
+            cached_len = 0
+            match = None
+            if self.prefix is not None:
+                # a deferred request retries every loop iteration: cache its
+                # lookup across retries (the hash chain is O(prompt)) —
+                # valid only within the index epoch, since an eviction could
+                # free-and-reallocate a matched block under us
+                match = getattr(req, "_prefix_match", None)
+                if match is None \
+                        or getattr(req, "_prefix_epoch", -1) \
+                        != self.prefix.epoch:
+                    match = self.prefix.lookup(req.prompt_ids)
+                    req._prefix_match = match
+                    req._prefix_epoch = self.prefix.epoch
+                chain = match.blocks
+                cached_len = min(len(chain) * self.bs, reusable)
+                if self.mtp is not None:
+                    # the module's row in a block's first slot is made of the
+                    # hidden state of the token before the block: the last
+                    # shared token is recomputed (into a copy of its block,
+                    # the copy-on-write below) so that the request's first
+                    # private slot can be written
+                    cached_len = min(cached_len, len(chain) * self.bs - 1)
+                if cached_len <= 0:
+                    chain, cached_len = [], 0
+            split_col = cached_len // self.bs
+            cow = bool(chain) and (cached_len % self.bs != 0)
+            mtp_slot = 1 if self.mtp is not None else 0
+            if chain or chunked:
+                # exact blocks for the real prompt span: suffix/chunk writes
+                # go through PagedChunkView, whose padded positions route to
+                # the pad block — no bucket over-allocation to release
+                # (a self-drafter's module keeps its row of position L - 1
+                # in slot L)
+                need_now = self._blocks_for(L + mtp_slot) - split_col
+            else:
+                L_pad = self._pad_bucket(L)
+                need_now = self._blocks_for(L_pad)  # <= nb_per_seq by clamp
+            # full reservation: prompt blocks now + growth to the worst case
+            total_need = self._blocks_for(L + req.max_new_tokens)
+            growth = max(0, total_need - self._blocks_for(L + mtp_slot))
+            # pin the reused blocks BEFORE any index eviction can run: a
+            # chain entry freed and reallocated under us would alias garbage
             for b in chain[:split_col]:
-                self._release_block(b)
+                self._ref_block(b)
+            cow_src = chain[split_col] if cow else None
             if cow_src is not None:
-                self._release_block(cow_src)
+                self._ref_block(cow_src)
 
-        short = need_now + growth - (len(self.free_blocks) - self.reserved)
-        if short > 0 and self.prefix is not None:
-            # pool pressure: orphaned index blocks are reclaimable —
-            # evict leaf entries (LRU) until the admission fits or
-            # nothing evictable remains.  Entries whose block is still
-            # table-referenced are skipped (freeing them gains nothing
-            # and would only cold-start a hot prefix)
-            self.prefix.evict(short, self._release_block,
-                              lambda b: int(self.block_rc[b]) == 1)
-            short = need_now + growth \
-                - (len(self.free_blocks) - self.reserved)
-        if short > 0:
-            unpin()
-            # admission deferred on a drained pool: counted ONCE per
-            # request so rejected/stalled traffic is diagnosable from the
-            # metrics snapshot alone (the request stays queued and admits
-            # when evictions return blocks)
-            if not getattr(req, "_deferral_counted", False):
-                req._deferral_counted = True
-                _M_REJECTIONS.inc(reason="pool_exhausted")
-            return False
-        self.waiting.popleft()
-        # admission starts NOW: everything before this point was queue
-        # wait (incl. pool-exhausted deferrals — the tail /metrics must
-        # surface under overload)
-        t_admit = time.perf_counter()
+            def unpin():
+                for b in chain[:split_col]:
+                    self._release_block(b)
+                if cow_src is not None:
+                    self._release_block(cow_src)
+
+            short = need_now + growth - (len(self.free_blocks) - self.reserved)
+            if short > 0 and self.prefix is not None:
+                # pool pressure: orphaned index blocks are reclaimable —
+                # evict leaf entries (LRU) until the admission fits or
+                # nothing evictable remains.  Entries whose block is still
+                # table-referenced are skipped (freeing them gains nothing
+                # and would only cold-start a hot prefix)
+                self.prefix.evict(short, self._release_block,
+                                  lambda b: int(self.block_rc[b]) == 1)
+                short = need_now + growth \
+                    - (len(self.free_blocks) - self.reserved)
+            if short > 0:
+                unpin()
+                # admission deferred on a drained pool: counted ONCE per
+                # request so rejected/stalled traffic is diagnosable from
+                # the metrics snapshot alone (the request stays queued and
+                # admits when evictions return blocks)
+                if not getattr(req, "_deferral_counted", False):
+                    req._deferral_counted = True
+                    _M_REJECTIONS.inc(reason="pool_exhausted")
+                return False
+            self.waiting.popleft()
+            # admission starts NOW: everything before this point was queue
+            # wait (incl. pool-exhausted deferrals — the tail /metrics must
+            # surface under overload)
+            t_admit = time.perf_counter()
         # the launch of the prompt's first program: the whole prefill
         # (legacy mode), or the blocks and the copy-on-write of a shared
         # one (chunked mode; the chunks follow as serve:chunk_dispatch)
@@ -2324,126 +2372,131 @@ class ServingEngine:
                 sp.end()
         self.tables[slot, :] = table_row
 
-        try:
-            with self._params_for_call() as param_vals:
-                # spec-decode engines thread (draft_params, draft_pools)
-                # through admission so the draft model's prompt KV lands
-                # in its pools via the same table row / block ids
-                dpref = ((self._draft_vals(), self.pools, self.dpools)
-                         if self.spec_model else (self.pools,))
-                if chain:
-                    if cow_src is not None:
-                        # the shared block holds the cached positions of
-                        # the last prompt block; copy it so the suffix
-                        # write lands in a private block
-                        cow_args = ((self.pools, self.dpools)
-                                    if self.spec_model else (self.pools,))
-                        out = self._cow_program()(
-                            *cow_args, jnp.int32(cow_src),
-                            jnp.int32(self.tables[slot, split_col]))
-                        if self.spec_model:
-                            self.pools, self.dpools = out
-                        else:
-                            self.pools = out
-                        dpref = ((dpref[0], self.pools, self.dpools)
-                                 if self.spec_model else (self.pools,))
-                    Ls = L - cached_len
-                    L_pad_s = self._pad_bucket(Ls)
-                    suffix = np.zeros((1, L_pad_s), np.int32)
-                    suffix[0, :Ls] = req.prompt_ids[cached_len:]
-                    # private table-row copy: same R002 aliasing contract
-                    # as the full-prefill call below
-                    out = self._dispatch_call(
-                        "serving.prefill.dispatch",
-                        lambda: self._prefill_cont_program(L_pad_s)(
-                            param_vals, *dpref,
-                            jnp.asarray(
-                                self.tables[slot:slot + 1].copy()),
-                            jnp.asarray(suffix), jnp.int32(Ls),
-                            jnp.int32(cached_len)))
-                else:
-                    prompt = np.zeros((1, L_pad), np.int32)
-                    prompt[0, :L] = req.prompt_ids
-                    # the table row must be a PRIVATE copy (graft-lint
-                    # R002): jnp.asarray of the numpy view aliases
-                    # zero-copy, and both the error path and the
-                    # pad-block release below mutate self.tables before
-                    # np.asarray(row) syncs — an in-flight prefill would
-                    # read the mutated block ids
-                    out = self._dispatch_call(
-                        "serving.prefill.dispatch",
-                        lambda: self._prefill_program(L_pad)(
-                            param_vals, *dpref,
-                            jnp.asarray(
-                                self.tables[slot:slot + 1].copy()),
-                            jnp.asarray(prompt), jnp.int32(L)))
-                if self.spec_model:
-                    row, self.pools, self.dpools = out
-                else:
-                    row, self.pools = out
-                # host-sync + NaN screen BEFORE the prefix registers
-                # anything (a poisoned prompt must not enter the index)
-                row = self._screen_row(row, slot, req)
-        except BaseException as e:
-            # admission failed mid-flight: undo every host-side draw so
-            # nothing leaks (references dropped — shared blocks survive
-            # their other holders — slot freed, growth reservation
-            # returned); the request is dropped from the queue and the
-            # error propagates, tagged with the request so the tick
-            # guard can strike/quarantine it instead of dying
-            for col in range(self.nb_per_seq):
-                if self.tables[slot, col]:
+        # legacy mode: the whole prefill and, from its host sync on, the
+        # admission's tail (serve:first_token: prefix registration, the
+        # first token's sampling and stream push, with nothing enqueued
+        # behind the prompt's program) lie inside the span
+        with ExitStack() as spans:
+            spans.callback(sp.end)
+            try:
+                with self._params_for_call() as param_vals:
+                    # spec-decode engines thread (draft_params, draft_pools)
+                    # through admission so the draft model's prompt KV lands
+                    # in its pools via the same table row / block ids
+                    dpref = ((self._draft_vals(), self.pools, self.dpools)
+                             if self.spec_model else (self.pools,))
+                    if chain:
+                        if cow_src is not None:
+                            # the shared block holds the cached positions of
+                            # the last prompt block; copy it so the suffix
+                            # write lands in a private block
+                            cow_args = ((self.pools, self.dpools)
+                                        if self.spec_model else (self.pools,))
+                            out = self._cow_program()(
+                                *cow_args, jnp.int32(cow_src),
+                                jnp.int32(self.tables[slot, split_col]))
+                            if self.spec_model:
+                                self.pools, self.dpools = out
+                            else:
+                                self.pools = out
+                            dpref = ((dpref[0], self.pools, self.dpools)
+                                     if self.spec_model else (self.pools,))
+                        Ls = L - cached_len
+                        L_pad_s = self._pad_bucket(Ls)
+                        suffix = np.zeros((1, L_pad_s), np.int32)
+                        suffix[0, :Ls] = req.prompt_ids[cached_len:]
+                        # private table-row copy: same R002 aliasing contract
+                        # as the full-prefill call below
+                        out = self._dispatch_call(
+                            "serving.prefill.dispatch",
+                            lambda: self._prefill_cont_program(L_pad_s)(
+                                param_vals, *dpref,
+                                jnp.asarray(
+                                    self.tables[slot:slot + 1].copy()),
+                                jnp.asarray(suffix), jnp.int32(Ls),
+                                jnp.int32(cached_len)))
+                    else:
+                        prompt = np.zeros((1, L_pad), np.int32)
+                        prompt[0, :L] = req.prompt_ids
+                        # the table row must be a PRIVATE copy (graft-lint
+                        # R002): jnp.asarray of the numpy view aliases
+                        # zero-copy, and both the error path and the
+                        # pad-block release below mutate self.tables before
+                        # np.asarray(row) syncs — an in-flight prefill would
+                        # read the mutated block ids
+                        out = self._dispatch_call(
+                            "serving.prefill.dispatch",
+                            lambda: self._prefill_program(L_pad)(
+                                param_vals, *dpref,
+                                jnp.asarray(
+                                    self.tables[slot:slot + 1].copy()),
+                                jnp.asarray(prompt), jnp.int32(L)))
+                    if self.spec_model:
+                        row, self.pools, self.dpools = out
+                    else:
+                        row, self.pools = out
+                    # host-sync + NaN screen BEFORE the prefix registers
+                    # anything (a poisoned prompt must not enter the index)
+                    spans.enter_context(_span("serve:first_token"))
+                    row = self._screen_row(row, slot, req)
+            except BaseException as e:
+                # admission failed mid-flight: undo every host-side draw so
+                # nothing leaks (references dropped — shared blocks survive
+                # their other holders — slot freed, growth reservation
+                # returned); the request is dropped from the queue and the
+                # error propagates, tagged with the request so the tick
+                # guard can strike/quarantine it instead of dying
+                for col in range(self.nb_per_seq):
+                    if self.tables[slot, col]:
+                        self._release_block(int(self.tables[slot, col]))
+                        self.tables[slot, col] = 0
+                if cow_src is not None:
+                    self._release_block(cow_src)
+                self.free_slots.appendleft(slot)
+                self.reserved -= growth
+                req._growth_left = 0
+                _M_REJECTIONS.inc(reason="error")
+                try:
+                    e._serving_req = req
+                except Exception:   # exotic exception types without a dict
+                    pass
+                raise
+            if cow_src is not None:
+                self._release_block(cow_src)   # copy dispatched; pin over
+            if not chain:
+                # release pad-bucket blocks beyond the prompt's real span
+                # (their stale contents are masked by seq_lens and
+                # overwritten by any future owner before becoming visible)
+                keep = self._blocks_for(L)
+                for col in range(keep, need_now):
                     self._release_block(int(self.tables[slot, col]))
                     self.tables[slot, col] = 0
-            if cow_src is not None:
-                self._release_block(cow_src)
-            self.free_slots.appendleft(slot)
-            self.reserved -= growth
-            req._growth_left = 0
-            _M_REJECTIONS.inc(reason="error")
-            try:
-                e._serving_req = req
-            except Exception:   # exotic exception types without a dict
-                pass
-            raise
-        finally:
-            sp.end()
-        if cow_src is not None:
-            self._release_block(cow_src)   # copy dispatched; pin over
-        if not chain:
-            # release pad-bucket blocks beyond the prompt's real span
-            # (their stale contents are masked by seq_lens and
-            # overwritten by any future owner before becoming visible)
-            keep = self._blocks_for(L)
-            for col in range(keep, need_now):
-                self._release_block(int(self.tables[slot, col]))
-                self.tables[slot, col] = 0
-        if self.prefix is not None:
-            # register this prompt's full blocks as shareable: reused
-            # entries are touched, new full-block columns become entries
-            # (one index reference each).  Registered blocks are never
-            # written again: decode starts at position L, which lives in
-            # an unregistered (partial or fresh) column.
-            fullb = L // self.bs
-            self.prefix.register(
-                req.prompt_ids,
-                [int(self.tables[slot, c]) for c in range(fullb)],
-                self._ref_block, match=match)
-            shared = split_col + (1 if cow_src is not None else 0)
-            req._prefix_blocks = shared
-            if chain:
-                self.prefix.hits += 1
-                _M_PREFIX_HITS.inc()
-                self.prefix.blocks_shared += shared
-                if shared:
-                    _M_PREFIX_SHARED.inc(shared)
-            else:
-                self.prefix.misses += 1
-                _M_PREFIX_MISSES.inc()
-            # checksum the just-registered blocks (ground truth now;
-            # immutable from here) — no-op unless blocksan is armed
-            _jaxsan.blocksan_snapshot(self)
-        self._finish_admission(req, slot, row, t_admit)
+            if self.prefix is not None:
+                # register this prompt's full blocks as shareable: reused
+                # entries are touched, new full-block columns become entries
+                # (one index reference each).  Registered blocks are never
+                # written again: decode starts at position L, which lives in
+                # an unregistered (partial or fresh) column.
+                fullb = L // self.bs
+                self.prefix.register(
+                    req.prompt_ids,
+                    [int(self.tables[slot, c]) for c in range(fullb)],
+                    self._ref_block, match=match)
+                shared = split_col + (1 if cow_src is not None else 0)
+                req._prefix_blocks = shared
+                if chain:
+                    self.prefix.hits += 1
+                    _M_PREFIX_HITS.inc()
+                    self.prefix.blocks_shared += shared
+                    if shared:
+                        _M_PREFIX_SHARED.inc(shared)
+                else:
+                    self.prefix.misses += 1
+                    _M_PREFIX_MISSES.inc()
+                # checksum the just-registered blocks (ground truth now;
+                # immutable from here) — no-op unless blocksan is armed
+                _jaxsan.blocksan_snapshot(self)
+            self._finish_admission(req, slot, row, t_admit)
         return True
 
     def _finish_admission(self, req, slot, row, t_admit) -> None:
@@ -2623,36 +2676,41 @@ class ServingEngine:
         admissions — so every running stream's inter-token gap is
         bounded by (chunk budget x one chunk) + one decode tick no
         matter how long the arriving prompts are."""
-        for slot in list(range(self.B)):
-            req = self.slot_req[slot]
-            if req is None or not req.cancelled:
-                continue
-            if req._prefilling:
-                self._abort_prefill(req, outcome="cancelled")
-            elif not req.done:
-                self._terminal_trace(req, "cancelled")
-                self._evict(slot)
-                req._stream_push(None)
-        if self.waiting and any(r.cancelled for r in self.waiting):
-            kept = deque()
-            for r in self.waiting:
-                if r.cancelled:
-                    self._terminal_trace(r, "cancelled")
-                    self.finished.append(r)
-                    r._stream_push(None)
-                else:
-                    kept.append(r)
-            self.waiting = kept
-            self._update_pressure()
-        self._shed_waiting()
+        # the boundary's clean-up, a leaf of serve:schedule: what block
+        # release and the bookkeeping of ended requests cost
+        with _span("serve:reap"):
+            for slot in list(range(self.B)):
+                req = self.slot_req[slot]
+                if req is None or not req.cancelled:
+                    continue
+                if req._prefilling:
+                    self._abort_prefill(req, outcome="cancelled")
+                elif not req.done:
+                    self._terminal_trace(req, "cancelled")
+                    self._evict(slot)
+                    req._stream_push(None)
+            if self.waiting and any(r.cancelled for r in self.waiting):
+                kept = deque()
+                for r in self.waiting:
+                    if r.cancelled:
+                        self._terminal_trace(r, "cancelled")
+                        self.finished.append(r)
+                        r._stream_push(None)
+                    else:
+                        kept.append(r)
+                self.waiting = kept
+                self._update_pressure()
+            self._shed_waiting()
+            if self.chunk > 0:
+                # chunked: evict finished FIRST — their slots and blocks
+                # fund this boundary's chunk budget
+                self._evict_done()
         if self.chunk <= 0:
             while self._try_admit():
                 pass
-            self._evict_done()
+            with _span("serve:reap"):
+                self._evict_done()
             return
-        # chunked: evict finished FIRST — their slots and blocks fund
-        # this boundary's chunk budget
-        self._evict_done()
         budget = max(1, int(_flags.get_flag(
             "serving_prefill_chunks_per_tick")))
         if _flags.get_flag("serving_chunks_per_tick_auto"):
@@ -2845,47 +2903,56 @@ class ServingEngine:
         off = req._chunk_off
         n = min(self.chunk, L - off)
         L_pad = self._pad_bucket(n)
-        suffix = np.zeros((1, L_pad), np.int32)
-        suffix[0, :n] = req.prompt_ids[off:off + n]
-        extra = ()
-        if self.mtp is not None:
-            # the prompt shifted by one, for the module's rows; behind
-            # the prompt's last token stands the one this chunk chooses
-            nxt = np.zeros((1, L_pad), np.int32)
-            follow = req.prompt_ids[off + 1:off + n + 1]
-            nxt[0, :len(follow)] = follow
-            if off + n >= L:
-                nxt[0, n - 1] = -1
-            extra = (jnp.asarray(nxt),)
-        # the chunk's host side (async enqueue; the LAST chunk host-syncs
-        # its logits row inside): the boundary's chunk-prefill phase
+        final = off + n >= L
+
+        def launch():
+            with self._staging("serve:chunk_stage") as dev:
+                suffix = np.zeros((1, L_pad), np.int32)
+                suffix[0, :n] = req.prompt_ids[off:off + n]
+                # private row copy: same R002 aliasing contract as the
+                # monolithic prefill's table-row argument
+                args = [dev(req._chunk_row[None, :].copy()), dev(suffix),
+                        dev(jnp.int32(n)), dev(jnp.int32(off))]
+                if self.mtp is not None:
+                    # the prompt shifted by one, for the module's rows;
+                    # behind the prompt's last token stands the one this
+                    # chunk chooses
+                    nxt = np.zeros((1, L_pad), np.int32)
+                    follow = req.prompt_ids[off + 1:off + n + 1]
+                    nxt[0, :len(follow)] = follow
+                    if final:
+                        nxt[0, n - 1] = -1
+                    args.append(dev(nxt))
+            return self._prefill_cont_program(L_pad)(
+                param_vals, *dpref, *args)
+
+        # the chunk's host side (async enqueue): the boundary's
+        # chunk-prefill phase.  The LAST chunk host-syncs its logits row
+        # inside, and from there to the span's end the admission's tail
+        # (the shadow row, prefix registration, the first token's sampling
+        # and stream push, nothing enqueued behind the prompt's program)
+        # is its child serve:first_token
         with _span("serve:chunk_dispatch", rid=req.trace_id or req.rid,
                    q_tokens=n, kv_tokens=off + n,
                    selected_tokens=self._selected(
-                       off + 1 + np.arange(n))) as sp:
+                       off + 1 + np.arange(n))) as sp, ExitStack() as tail:
             try:
                 with self._params_for_call() as param_vals:
                     dpref = ((self._draft_vals(), self.pools, self.dpools)
                              if self.spec_model else (self.pools,))
-                    # private row copy: same R002 aliasing contract as the
-                    # monolithic prefill's table-row argument
                     out = self._dispatch_call(
-                        "serving.prefill.dispatch",
-                        lambda: self._prefill_cont_program(L_pad)(
-                            param_vals, *dpref,
-                            jnp.asarray(req._chunk_row[None, :].copy()),
-                            jnp.asarray(suffix), jnp.int32(n),
-                            jnp.int32(off), *extra))
+                        "serving.prefill.dispatch", launch)
                 if self.spec_model:
                     row, self.pools, self.dpools = out
                 elif self.mtp is not None:
                     row, req._first_draft, self.pools = out
                 else:
                     row, self.pools = out
-                if req._chunk_off + n >= L:
+                if final:
                     # last chunk: host-sync + NaN screen before the shadow
                     # row installs and the prefix registers (same contract
                     # as the monolithic path's _screen_row placement)
+                    tail.enter_context(_span("serve:first_token"))
                     row = self._screen_row(row, slot, req)
             except BaseException as e:
                 self._abort_prefill(req)
@@ -2895,18 +2962,18 @@ class ServingEngine:
                 except Exception:
                     pass
                 raise
+            req._chunk_off = off + n
+            req._prefill_chunks += 1
+            self.prefill_chunks_total += 1
+            self._chunks_this_boundary += 1
+            _M_PREFILL_CHUNKS.inc()
+            if _metrics.enabled():
+                self._flightrec().record_event(
+                    "prefill_chunk", rid=req.rid, slot=slot, start=off,
+                    tokens=n, done=final)
+            if final:
+                self._complete_chunked(req, row)
         self._chunk_s_this_boundary += sp.seconds
-        req._chunk_off = off + n
-        req._prefill_chunks += 1
-        self.prefill_chunks_total += 1
-        self._chunks_this_boundary += 1
-        _M_PREFILL_CHUNKS.inc()
-        if _metrics.enabled():
-            self._flightrec().record_event(
-                "prefill_chunk", rid=req.rid, slot=slot, start=off,
-                tokens=n, done=req._chunk_off >= L)
-        if req._chunk_off >= L:
-            self._complete_chunked(req, row)
 
     def _complete_chunked(self, req, row) -> None:
         """Last chunk landed: install the shadow table row (the slot
@@ -3017,8 +3084,13 @@ class ServingEngine:
         sched_s = chunk_s = 0.0
         if boundary:
             self._chunk_s_this_boundary = 0.0
+            # why the tick before was not chained (`_cycle` keeps the
+            # word `_boundary_reason` gave it), or that none was in flight
+            why, self._boundary_why = self._boundary_why, "idle"
+            _M_BOUNDARIES.inc(why=why)
             with _span("serve:schedule", waiting=len(self.waiting),
-                       running=self.B - len(self.free_slots)) as sp:
+                       running=self.B - len(self.free_slots),
+                       why=why) as sp:
                 self._boundary_schedule()
             # the boundary's host phases: the chunk dispatches nest
             # inside serve:schedule (their seconds were summed by
@@ -3159,14 +3231,8 @@ class ServingEngine:
         # ensure a physical block exists for every position this tick
         # will write (all draws covered by the admission reservation)
         for slot in active:
-            for pos in range(int(self.seq_lens[slot]),
-                             int(self.seq_lens[slot]) + k):
-                col = pos // self.bs
-                if pos % self.bs == 0 and self.tables[slot, col] == 0:
-                    blk = self._alloc_block()
-                    self.reserved -= 1
-                    self.slot_req[slot]._growth_left -= 1
-                    self.tables[slot, col] = blk
+            self._draw_blocks(slot, int(self.seq_lens[slot]),
+                              int(self.seq_lens[slot]) + k)
         # device inputs get PRIVATE host copies: async dispatch returns
         # before the program consumes them, and jax device_put may alias
         # numpy memory zero-copy — without the copy, this tick's own
@@ -3176,33 +3242,35 @@ class ServingEngine:
         # FLAGS_enable_jaxsan off): checksummed at dispatch, verified at
         # harvest, so reintroducing the aliasing bug fails loudly
         san = _jaxsan.token("serving.tick")
-        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
-        last = _last_column(chain.toks) if chain is not None \
-            else dev(self.last_tok)
+        # host-sampling fallback: the k=1 program returns the logits the
+        # per-row host sampler needs.  Else the one k-step tick program;
+        # with sampling off the demotion guarantees no sampled row is
+        # active, the all-False mask takes the greedy cond branch
+        host_sampling = not device_sampling and k == 1
+        last = None if chain is None else _last_column(chain.toks)
+
+        def launch():
+            with self._staging("serve:tick_stage",
+                               partial(_jaxsan.shield, san)) as dev:
+                tok = dev(self.last_tok) if last is None else last
+                args = [dev(self.tables), dev(self.seq_lens), tok]
+                if not host_sampling:
+                    args += [dev(self.samp_do), dev(self.samp_temp),
+                             dev(self.samp_topk), dev(self.samp_topp),
+                             dev(self.samp_seed), dev(self.tok_pos)]
+            program = self._decode_program() if host_sampling \
+                else self._tick_program(k)
+            return program(param_vals, self.pools, *args)
+
         logits, state = None, ()
         with self._params_for_call() as param_vals, \
                 _flight.guard("serving.tick"):
-            if not device_sampling and k == 1:
-                # host-sampling fallback: the k=1 program returns the
-                # logits the per-row host sampler needs
-                greedy, logits, self.pools = self._dispatch_call(
-                    "serving.tick.dispatch",
-                    lambda: self._decode_program()(
-                        param_vals, self.pools, dev(self.tables),
-                        dev(self.seq_lens), last))
+            out = self._dispatch_call("serving.tick.dispatch", launch)
+            if host_sampling:
+                greedy, logits, self.pools = out
                 toks = greedy[:, None]
             else:
-                # the one k-step tick program; with sampling off the
-                # demotion guarantees no sampled row is active, the
-                # all-False mask takes the greedy cond branch
-                toks, self.pools, state = self._dispatch_call(
-                    "serving.tick.dispatch",
-                    lambda: self._tick_program(k)(
-                        param_vals, self.pools, dev(self.tables),
-                        dev(self.seq_lens), last,
-                        dev(self.samp_do), dev(self.samp_temp),
-                        dev(self.samp_topk), dev(self.samp_topp),
-                        dev(self.samp_seed), dev(self.tok_pos)))
+                toks, self.pools, state = out
         self.steps += k
         for slot in active:
             self.seq_lens[slot] += k
@@ -3241,14 +3309,18 @@ class ServingEngine:
                 self.reserved -= 1
                 req._growth_left -= 1
         san = _jaxsan.token("serving.tick")
-        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
+
+        def launch():
+            with self._staging("serve:tick_stage",
+                               partial(_jaxsan.shield, san)) as dev:
+                args = [dev(self.tables), dev(self.seq_lens), dev(toks_in)]
+            return self._block_tick_program()(
+                param_vals, self.pools, *args)
+
         with self._params_for_call() as param_vals, \
                 _flight.guard("serving.tick"):
             toks, step_of, self.pools, state = self._dispatch_call(
-                "serving.tick.dispatch",
-                lambda: self._block_tick_program()(
-                    param_vals, self.pools, dev(self.tables),
-                    dev(self.seq_lens), dev(toks_in)))
+                "serving.tick.dispatch", launch)
         forwards = gen.denoising_steps + 1
         self.steps += forwards
         for slot in active:
@@ -3351,26 +3423,29 @@ class ServingEngine:
             if cap < k:
                 ineligible += 1
             base = int(self.seq_lens[slot])
-            for pos in range(base, base + cap):
-                col = pos // self.bs
-                if pos % self.bs == 0 and self.tables[slot, col] == 0:
-                    blk = self._alloc_block()
-                    self.reserved -= 1
-                    req._growth_left -= 1
-                    self.tables[slot, col] = blk
+            self._draw_blocks(slot, base, base + cap)
         if ineligible:
             self.spec_ineligible_slots += ineligible
             _M_SPEC_INELIGIBLE.inc(ineligible)
         _M_SPEC_K.set(k)
         san = _jaxsan.token("serving.tick")
-        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
-        if chain is not None:
-            lens_in, last_in = chain.new_lens, chain.new_last
-        else:
-            lens_in, last_in = dev(self.seq_lens), dev(self.last_tok)
-        samp = (dev(self.samp_do), dev(self.samp_temp),
-                dev(self.samp_topk), dev(self.samp_topp),
-                dev(self.samp_seed))
+
+        def staged(dtoks=None):
+            with self._staging("serve:tick_stage",
+                               partial(_jaxsan.shield, san)) as dev:
+                if chain is not None:
+                    lens_in, last_in = chain.new_lens, chain.new_last
+                else:
+                    lens_in, last_in = (dev(self.seq_lens),
+                                        dev(self.last_tok))
+                samp = (dev(self.samp_do), dev(self.samp_temp),
+                        dev(self.samp_topk), dev(self.samp_topp),
+                        dev(self.samp_seed))
+                args = [dev(self.tables), lens_in, last_in]
+                if dtoks is not None:
+                    args.append(dev(dtoks))
+                return [*args, *samp, dev(kcap)]
+
         with self._params_for_call() as param_vals, \
                 _flight.guard("serving.tick"):
             if self.spec_model:
@@ -3379,8 +3454,7 @@ class ServingEngine:
                         "serving.tick.dispatch",
                         lambda: self._spec_program(k)(
                             param_vals, self._draft_vals(), self.pools,
-                            self.dpools, dev(self.tables), lens_in,
-                            last_in, *samp, dev(kcap)))
+                            self.dpools, *staged()))
                 self.steps += k + 1      # k draft forwards + one verify
             else:
                 # host-side n-gram proposals (near-zero cost; the whole
@@ -3398,9 +3472,7 @@ class ServingEngine:
                     = self._dispatch_call(
                         "serving.tick.dispatch",
                         lambda: self._spec_hd_program(k)(
-                            param_vals, self.pools, dev(self.tables),
-                            lens_in, last_in, dev(dtoks), *samp,
-                            dev(kcap)))
+                            param_vals, self.pools, *staged(dtoks)))
                 self.steps += 1          # one chunk verify forward
         for slot in active:
             self.seq_lens[slot] += int(kcap[slot])
@@ -3434,27 +3506,26 @@ class ServingEngine:
             end = len(req.prompt_ids) + req.max_new_tokens
             # the model writes positions base..base+cap-1, the module the
             # slots one further
-            for pos in range(base, min(base + cap + 1, end)):
-                col = pos // self.bs
-                if pos % self.bs == 0 and self.tables[slot, col] == 0:
-                    self.tables[slot, col] = self._alloc_block()
-                    self.reserved -= 1
-                    req._growth_left -= 1
+            self._draw_blocks(slot, base, min(base + cap + 1, end))
         san = _jaxsan.token("serving.tick")
-        dev = lambda a: jnp.asarray(_jaxsan.shield(san, a))  # noqa: E731
-        if chain is not None:
-            carry = (chain.new_lens, chain.new_last, chain.new_draft)
-        else:
-            carry = (dev(self.seq_lens), dev(self.last_tok),
-                     dev(self.draft_tok))
+
+        def launch():
+            with self._staging("serve:tick_stage",
+                               partial(_jaxsan.shield, san)) as dev:
+                if chain is not None:
+                    carry = (chain.new_lens, chain.new_last,
+                             chain.new_draft)
+                else:
+                    carry = (dev(self.seq_lens), dev(self.last_tok),
+                             dev(self.draft_tok))
+                args = [dev(self.tables), *carry, dev(kcap)]
+            return self._mtp_tick_program()(param_vals, self.pools, *args)
+
         with self._params_for_call() as param_vals, \
                 _flight.guard("serving.tick"):
             (toks, counts, accepts, new_lens, new_last, new_draft, judged,
              self.pools, state) = self._dispatch_call(
-                "serving.tick.dispatch",
-                lambda: self._mtp_tick_program()(
-                    param_vals, self.pools, dev(self.tables), *carry,
-                    dev(kcap)))
+                "serving.tick.dispatch", launch)
         self.steps += 1              # one verify forward (and its draft)
         for slot in active:
             self.seq_lens[slot] += int(kcap[slot])
@@ -3471,6 +3542,36 @@ class ServingEngine:
         pend.kcap = kcap
         pend.state = state
         return pend
+
+    def _readback(self, pend) -> dict:
+        """Every device-to-host read of a harvested tick beyond its
+        tokens, by name, in one stretch under ``serve:readback`` at the
+        head of ``serve:emit`` (no span where there is nothing to read):
+        the cache's per-layer state rows (``("state", i)``); a spec or
+        self-drafted tick's ``counts``, ``accepts``, ``judged``,
+        ``new_draft``; a block tick's ``step_of``; the host-sampling fallback's
+        ``logits`` where a row is sampled or screened.  The tokens are
+        here, so the program is done and none of them is waited for:
+        each is a device-to-host copy and a wake-up."""
+        want = {("state", i): a for i, a in enumerate(pend.state)}
+        if pend.spec:
+            want["counts"], want["accepts"] = pend.counts, pend.accepts
+            if pend.judged is not None:
+                want["judged"] = pend.judged
+                want["new_draft"] = pend.new_draft
+        elif pend.block is not None:
+            want["step_of"] = pend.block[2]
+        elif pend.logits is not None and (
+                self._screens_decode_logits() or any(
+                    pend.reqs[s].do_sample and not pend.reqs[s].done
+                    for s in pend.active)):
+            want["logits"] = pend.logits
+        if not want:
+            return want
+        with _span("serve:readback", arrays=len(want)) as sp:
+            host = {k: np.asarray(a) for k, a in want.items()}
+            sp.set(bytes=sum(a.nbytes for a in host.values()))
+        return host
 
     def _harvest_tick(self, pend) -> None:
         """Block on the tick's device tokens and feed the requests:
@@ -3490,26 +3591,27 @@ class ServingEngine:
             # this block: a hung device program raises TickTimeout
             # instead of wedging the loop forever.
             toks = self._materialize(pend.toks)
-        if pend.state:
-            # the tokens are here, so the program is done and its state
-            # rows are ready: a copy of a few hundred bytes, no wait
-            self._cache_state = (pend.step_no,
-                                 [np.asarray(a) for a in pend.state])
-        # emit phase, to t_done: append, sample, stream (a harvest that
-        # raises abandons the span, which then records nothing)
+        # emit phase, to t_done: read back, append, sample, stream (a
+        # harvest that raises abandons the span, which then records
+        # nothing)
         sp_emit = _span("serve:emit").begin()
+        host = self._readback(pend)
+        if pend.state:
+            self._cache_state = (pend.step_no, [
+                host["state", i] for i in range(len(pend.state))])
         # the program has materialized: every host buffer fed at dispatch
         # must still hash to its dispatch-time checksum (jaxsan; no-op
         # unless FLAGS_enable_jaxsan)
         _jaxsan.verify(pend.san)
-        logits_np = None
+        logits_np = host.get("logits")
         bad_slots: dict = {}
         if not pend.spec and pend.logits is not None:
             # host-sampling decode path: the per-row logits are host-
             # visible, so NaN attribution is PER SLOT here — an armed
             # chaos injection or a real non-finite forward implicates
             # exactly one row (evicted outcome=error after the loop)
-            logits_np, bad_slots = self._screen_decode_logits(pend)
+            logits_np, bad_slots = self._screen_decode_logits(
+                pend, logits_np)
         toks_before = self.tokens_out
         sampled = 0
         spec_accepted = 0
@@ -3522,12 +3624,10 @@ class ServingEngine:
             # slot) down to the true emitted length — relative, so it
             # composes with any further conservative advance already
             # applied by an overlapped next dispatch
-            counts = np.asarray(pend.counts)
-            accepts = np.asarray(pend.accepts)
+            counts, accepts = host["counts"], host["accepts"]
             mtp = pend.judged is not None
             if mtp:
-                judged = np.asarray(pend.judged)
-                new_draft = np.asarray(pend.new_draft)
+                judged, new_draft = host["judged"], host["new_draft"]
             metrics_on = _metrics.enabled()
             for slot in pend.active:
                 req = pend.reqs[slot]
@@ -3595,8 +3695,8 @@ class ServingEngine:
             # block-diffusion tick: each slot's block arrives whole; its
             # new tokens (behind the prompt's tail, within the budget) are
             # handed over together, each with the forward that revealed it
-            given, new, step_of = pend.block
-            step_of = np.asarray(step_of)
+            given, new, _ = pend.block
+            step_of = host["step_of"]
             per = self.gen.block_length // self.gen.denoising_steps
             _M_BLOCK_FORWARDS.inc(k)
             for slot in pend.active:
@@ -3645,8 +3745,6 @@ class ServingEngine:
                                  # compiled tick keeps decoding; the
                                  # cache rows die with the eviction)
                     if req.do_sample and not pend.device_sampling:
-                        if logits_np is None:
-                            logits_np = np.asarray(pend.logits)
                         tok = req._sample(logits_np[slot])
                         self.last_tok[slot] = tok
                     else:
@@ -3775,9 +3873,15 @@ class ServingEngine:
 
     def _can_overlap(self, pend) -> bool:
         """May tick t+1 dispatch before tick t (`pend`) is harvested?
-        Requires the overlap flag, next-token choice living on device
-        (host sampling owns it otherwise), no admissions pending (they
-        join at a REAL boundary: their prefill must not race the
+        Where `_boundary_reason` names no reason for a boundary."""
+        return self._boundary_reason(pend) is None
+
+    def _boundary_reason(self, pend) -> Optional[str]:
+        """Why tick t+1 may NOT dispatch before tick t (`pend`) is
+        harvested, in one word, or None where it may (the chain).
+        Chaining requires the overlap flag, next-token choice living on
+        device (host sampling owns it otherwise), no admissions pending
+        (they join at a REAL boundary: their prefill must not race the
         in-flight tick's pool writes), and at least one budgeted token
         per active request beyond the in-flight tick (the block-budget
         clamp that keeps EOS overrun inside the reservation).  The
@@ -3788,68 +3892,69 @@ class ServingEngine:
 
         A chained dispatch skips `_boundary_schedule`, and behind a
         front end long answers can chain for seconds, so everything
-        only a boundary acts on says no here: a waiting request, a
+        only a boundary acts on is a reason here: a waiting request, a
         cancelled one in any slot, a requested drain, the serve loop's
         stop event.  The tick in flight is then harvested and the next
-        dispatch is a real boundary — within one tick, whatever runs."""
-        if not _flags.get_flag("serving_overlap") or self.gen is not None:
-            return False     # (a block tick is harvested before the next)
+        dispatch is a real boundary — within one tick, whatever runs.
+
+        The word goes on that boundary's ``serve:schedule`` span as
+        ``why`` and into the counter ``serving.boundaries``; the spec
+        and the plain branch share theirs."""
+        if not _flags.get_flag("serving_overlap"):
+            return "overlap_off"
+        if self.gen is not None:
+            return "block_tick"  # harvested before the next is launched
         if self._drain_requested or (self._stop_event is not None
                                      and self._stop_event.is_set()):
-            return False     # the loop is ending: harvest what flies
+            return "stopping"    # the loop is ending: harvest what flies
         if self.waiting:
-            return False     # admissions join at a real boundary
+            return "waiting"     # admissions join at a real boundary
         if any(r is not None and r.cancelled for r in self.slot_req):
-            return False     # evictions and aborts happen at a boundary
+            return "cancelled"   # evictions and aborts: a boundary's
         if self.prefilling and not self._chunk_overlap_ok():
-            return False     # pending chunk work needs a real boundary
+            return "chunk_pending"   # chunk work only a boundary may do
+        device_sampling = _flags.get_flag("serving_device_sampling")
         if pend.spec:
             if not self.spec_model and self.mtp is None:
-                return False     # ngram proposals need the harvested
-                                 # tokens: a host draft cannot chain
+                return "host_draft"  # ngram proposals need the harvested
+                                     # tokens: a host draft cannot chain
             if self._adapt_step():
-                return False     # a k step is due: chained dispatches
-                                 # reuse chain.k, so force a boundary
-                                 # and let _adapt_k move the rung
-            if not _flags.get_flag("serving_device_sampling"):
-                return False     # mid-run flip: verify owns sampling
-            for slot in pend.active:
-                req = self.slot_req[slot]
-                if req is None or req.done:
-                    return False
-                if req.max_new_tokens - int(self.tok_pos[slot]) < 1:
-                    return False     # per-slot caps need >= 1 headroom
-            # X-ray sampling contract (ISSUE 14): a due synced probe
-            # must land on a REAL boundary — a chained dispatch feeds
-            # the predecessor's device handles, so a probe around it
-            # would time both ticks
-            if _xray.sampling_on() and _xray.sample_due(
-                    self._mtp_fn if self.mtp is not None
-                    else self._spec_fns.get(pend.k)):
-                return False
-            return True
-        if not pend.device_sampling and any(
-                pend.reqs[s].do_sample for s in pend.active):
-            return False
-        if self.spec and self._spec_eligible(
-                pend.active, _flags.get_flag("serving_device_sampling")):
-            return False         # plain->spec switch (e.g. the sampling
-                                 # flag flipped back on): boundary first
+                return "adapt_k"     # a k step is due: chained dispatches
+                                     # reuse chain.k, so force a boundary
+                                     # and let _adapt_k move the rung
+            if not device_sampling:
+                return "host_sampling"   # mid-run flip: verify owns it
+        else:
+            if not pend.device_sampling and any(
+                    pend.reqs[s].do_sample for s in pend.active):
+                return "host_sampling"
+            if self.spec and self._spec_eligible(pend.active,
+                                                 device_sampling):
+                return "kind_switch"     # plain->spec (e.g. the sampling
+                                         # flag flipped back on)
         for slot in pend.active:
             req = self.slot_req[slot]
             if req is None or req.done:
-                return False     # eviction boundary needed first
+                return "finished"        # eviction boundary needed first
             if req.max_new_tokens - int(self.tok_pos[slot]) < 1:
-                return False     # in-flight tick exhausts the budget
+                return "budget_spent"    # the in-flight tick exhausts the
+                                         # budget (per-slot caps need >= 1)
+        # X-ray sampling contract (ISSUE 14): a due synced probe must
+        # land on a REAL boundary — a chained dispatch feeds the
+        # predecessor's device handles, so a probe around it would time
+        # both ticks; the program a chained dispatch would run must not
+        # be due one
         if _xray.sampling_on():
-            # same sampling contract as the spec branch: the program a
-            # chained dispatch would run must not be due a synced probe
-            k = self._tick_size(pend.active)
-            nxt = self._decode_fn if (k == 1 and not _flags.get_flag(
-                "serving_device_sampling")) else self._tick_fns.get(k)
+            if pend.spec:
+                nxt = self._mtp_fn if self.mtp is not None \
+                    else self._spec_fns.get(pend.k)
+            else:
+                k = self._tick_size(pend.active)
+                nxt = self._decode_fn if (k == 1 and not device_sampling) \
+                    else self._tick_fns.get(k)
             if _xray.sample_due(nxt):
-                return False
-        return True
+                return "xray_probe"
+        return None
 
     def _chunk_overlap_ok(self) -> bool:
         """May pending chunk-prefill work ride BEHIND an overlapped
@@ -3904,11 +4009,12 @@ class ServingEngine:
         """One turn of the serve loop, the one both drivers run: takes
         the tick in flight (None at a boundary) and returns the one in
         flight afterwards.  With nothing in flight it dispatches a
-        boundary tick (schedule first).  Then, where `_can_overlap`
-        allows, tick t+1 is chained on `pend`'s device tokens — with
+        boundary tick (schedule first).  Then, where `_boundary_reason`
+        names none, tick t+1 is chained on `pend`'s device tokens — with
         the non-final prefill chunks that may ride behind it — BEFORE
-        `pend` is harvested; otherwise `pend` is harvested alone and
-        the next turn starts at a real boundary.  Crash-only: a failure
+        `pend` is harvested; otherwise `pend` is harvested alone, the
+        reason is kept for the next ``serve:schedule`` span (``why``)
+        and the next turn starts at a real boundary.  Crash-only: a failure
         is absorbed by `_absorb_failure` (request strike, or eviction
         of the slots the ticks in flight covered) and the turn ends
         with nothing in flight; only sanitizer findings propagate."""
@@ -3923,9 +4029,14 @@ class ServingEngine:
                 return None      # nothing decodable yet (chunks, queue)
         nxt = None
         try:
-            if self._can_overlap(pend):
+            why = self._boundary_reason(pend)
+            if why is not None:
+                self._boundary_why = why
+            else:
                 nxt = self._dispatch_tick(boundary=False, chain=pend)
-                if nxt is not None:
+                if nxt is None:
+                    self._boundary_why = "nothing_to_chain"
+                else:
                     nxt.overlapped = True
                     _M_OVERLAP.inc()
                     try:
@@ -3973,14 +4084,16 @@ class ServingEngine:
         _jaxsan.blocksan_verify(self)
         return self.finished
 
+    _IDLE_SPAN_S = 0.1     # the longest one `serve:idle` span lasts
+
     def serve_forever(self, stop_event, idle_s: float = 0.002) -> None:
         """Drive `_cycle` until ``stop_event`` (a threading.Event) is
         set, serving traffic submitted concurrently — the loop behind
         the streaming endpoint (``FLAGS_serving_http_port``), a fleet
         replica and every serve cell of the benchmark: handler threads
         `add_request` and read each request's token stream; this loop
-        ticks while work exists and naps (``idle_s``, under the span
-        ``serve:idle``) otherwise.
+        ticks while work exists and naps (``idle_s``; one span
+        ``serve:idle`` an idle period) otherwise.
 
         The same cycle as `run()`: while nothing needs a boundary, tick
         t+1 is enqueued on tick t's device tokens before t is harvested,
@@ -4025,9 +4138,18 @@ class ServingEngine:
                 if pend is not None or self._has_work():
                     pend = self._cycle(pend)
                 else:
-                    # an empty engine is not a slow one
+                    # an empty engine is not a slow one: ONE span an idle
+                    # period, to the arrival, stop or drain that ends it
+                    # (or _IDLE_SPAN_S, so that a profiler that starts
+                    # inside a long period loses no more of it)
                     with _span("serve:idle"):
-                        time.sleep(idle_s)
+                        t_end = time.perf_counter() + self._IDLE_SPAN_S
+                        while True:
+                            time.sleep(idle_s)
+                            if self._has_work() or stop_event.is_set() \
+                                    or self._drain_requested \
+                                    or time.perf_counter() >= t_end:
+                                break
             if pend is not None:
                 # stopped with a tick in flight: the set event forbids a
                 # chain, so this turn harvests it and leaves none

@@ -26,6 +26,7 @@ The headline contracts pinned here:
   counter, never loaded.
 """
 
+import contextlib
 import json
 import os
 
@@ -116,6 +117,41 @@ def test_transient_dispatch_retry_is_invisible(model):
     assert eng.dispatch_retries == 1 and eng.tick_errors == 0
     assert _counter("serving.dispatch_retries",
                     site="serving.prefill.dispatch") >= 1
+
+
+@pytest.mark.parametrize("stage", ["serve:tick_stage", "serve:chunk_stage"])
+def test_transient_transfer_fault_is_retried_with_its_dispatch(
+        model, monkeypatch, stage):
+    """A launch's host-to-device transfers run inside the callable
+    `_dispatch_call` retries (ISSUE 36 moved them under a span of their
+    own, not out of the retry): one that fails transiently is sent again
+    with the dispatch — same stream, one retry counted, no strike."""
+    eng = _engine(model, prefill_chunk=16)
+    rr = eng.add_request(Request([5, 6, 7], max_new_tokens=4))
+    eng.run()
+    real, fires = ServingEngine._staging, []
+
+    @contextlib.contextmanager
+    def staging(self, name, shield=None):
+        with real(self, name, shield) as dev:
+            def flaky(a):
+                if name == stage and not fires:
+                    fires.append(name)
+                    raise RuntimeError("transfer: transient")
+                return dev(a)
+            yield flaky
+
+    monkeypatch.setattr(ServingEngine, "_staging", staging)
+    req = eng.add_request(Request([5, 6, 7], max_new_tokens=4))
+    with flag_guard(serving_dispatch_retries=2):
+        eng.run()
+    assert fires == [stage]
+    assert req.outcome == "finished"
+    assert req.output_ids == rr.output_ids
+    assert eng.dispatch_retries == 1 and eng.tick_errors == 0
+    site = "serving.tick.dispatch" if stage == "serve:tick_stage" \
+        else "serving.prefill.dispatch"
+    assert _counter("serving.dispatch_retries", site=site) >= 1
 
 
 @pytest.mark.slow   # two engines compile their grids (~4-8s)
