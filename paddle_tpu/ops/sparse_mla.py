@@ -4,17 +4,27 @@ A layer of a `glm_moe_dsa` / DeepSeek-V3.2 style model keeps two pools
 under ONE block table: latent rows (`c_kv | k_rope`, one a token, shared
 by every head) and the indexer's keys (one small row a token).  A query
 scores every cached token with the indexer (`index_scores`), keeps the
-`topk` best (`select`: exact, causal, per sequence), fetches
-those latent rows through the block table (`gather_rows`) and attends
-only to them, in the absorbed form: the per-head `k_nope` / `v`
-expansions are folded into the query and the output, so the scores are
-taken against the latent rows themselves (`attend_selected`).
+`topk` best (`select`: exact, causal, per sequence) and attends only to
+those, in the absorbed form: the per-head `k_nope` / `v` expansions are
+folded into the query and the output, so the scores are taken against
+the latent rows themselves.
 
-All of it is plain XLA: row gathers through `jnp.take`, a selection made
-of compares, counts and small matmuls (no sort), and einsums with float32
-accumulation.  Queries are processed a tile at a
-time (`_Q_TILE`): the per-head index logits of a 512-query chunk against
-30k keys over 32 heads would be 2 GB in float32 at once.
+How the selected rows reach the multiply depends on how many queries
+share a sequence (`sparse_latent_attention`).  The few queries of a
+decode step fetch them through the block table (`gather_rows`, a
+`jnp.take` of single rows: 27 ns a row on the v5e whatever its bytes)
+and attend them (`attend_selected`).  The hundreds of queries of a chunk
+would fetch 2,048 rows each; they attend, a tile of queries at a time,
+EVERY block the sequence holds, copied whole, with the rows the selection
+left out masked from the softmax (`select_mask`, then the Pallas kernel
+`pallas_latent.paged_latent_chunk`): the same set, the same float32
+softmax, FLOPs the chip has to spare in place of descriptors it has not.
+
+The rest is plain XLA: a selection made of compares, counts and small
+matmuls (no sort), and einsums with float32 accumulation.  Queries are
+processed a tile at a time (`_Q_TILE`): the per-head index logits of a
+512-query chunk against 30k keys over 32 heads would be 2 GB in float32
+at once.
 
 Pool layout: `[num_blocks + 1, block_size, lanes]`, block 0 the pad
 block, `lanes` = `padded_width(width)`: a row of 576 values is kept in 640
@@ -36,10 +46,18 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["padded_width", "write_rows", "index_scores",
-           "index_scores_xla", "select",
-           "gather_rows", "attend_selected", "sparse_latent_attention"]
+           "index_scores_xla", "select", "select_mask",
+           "gather_rows", "attend_selected", "sparse_latent_attention",
+           "MASKED_PASS_MAX_ROWS"]
 
 _Q_TILE = 32        # queries scored, selected and attended at a time
+# Where a chunk's two forms cross.  A tile of 32 queries x 64 heads fetches
+# its 32 x 2,048 selected rows in 1.77 ms whatever the context (27.0 ns a
+# row: descriptors, not bytes) and walks 16,384 / 32,768 rows under the mask
+# in 0.52 / 0.96 ms, 71% / 77% of the v5e's 197 TFLOP/s (a micro-run on the
+# chip; PERF.md section 6, PR 38): 29 ns a context row, equal at 60k rows.
+# A table no wider than this holds the masked pass alone.
+MASKED_PASS_MAX_ROWS = 60 * 1024
 LANES = 128         # the minor dimension of a TPU tile
 
 
@@ -137,21 +155,13 @@ def _cumsum_rows(m):
     return within, within[..., -1]
 
 
-def select(scores, topk: int):
-    """The `topk` best-scored positions of each query, exactly, equal
-    scores to the lower position (the set `lax.top_k` returns; here in
-    position order).  Returns (idx `[B, s, k]` int32, valid `[B, s, k]`):
-    fewer than k tokens exist while the context is short, and the rest
-    are marked invalid.
-
-    `lax.top_k` of 2,048 in 32,768 is a full sort on the TPU: 2.91 ms a
-    layer for 16 queries on the v5e against this function's 0.44 (a
-    micro-run; PERF.md section 6, PR 28).  This is a selection instead,
-    all of it dense vector and matmul work:
-    the k-th largest value by bisection over the bits of the float (32
-    compare-and-count passes), equal values admitted by position, and the
-    chosen positions compacted by a two-level cumulative count (which
-    row of 128 holds the j-th chosen, then which lane of that row)."""
+def _choose(scores, topk: int):
+    """The first half of `select`: which positions are chosen, as a dense
+    mask.  Returns (`u`, the scores as sortable uint32 padded to whole
+    rows of `LANES` with values below -inf; `chosen`, `u`'s shape: the
+    `k` best, equal values admitted by position; `k`).  While fewer than
+    `k` tokens exist, `chosen` fills up with `-inf` positions: `_live`
+    says which are real."""
     n = scores.shape[-1]
     k = min(int(topk), n)
     pad = -n % LANES
@@ -171,7 +181,19 @@ def select(scores, topk: int):
     t_in, t_tot = _cumsum_rows(ties)
     t_rank = t_in + (jnp.cumsum(t_tot, -1) - t_tot)[..., None]
     chosen = above | (ties & (t_rank.reshape(u.shape) <= need[..., None]))
-    # compaction: slot j holds the (j + 1)-th chosen position
+    return u, chosen, k
+
+
+def _live(u):
+    """Positions whose score is above -inf: tokens that exist."""
+    return u > _sortable(jnp.float32(-jnp.inf))
+
+
+def _compact(u, chosen, k: int, n: int):
+    """The second half of `select`: a mask into positions.  Slot j of
+    `idx` holds the (j + 1)-th chosen position, by a two-level cumulative
+    count (which row of 128 holds it, then which lane of that row);
+    `valid` is `_live` of that position."""
     c_in, c_tot = _cumsum_rows(chosen)
     c_ends = jnp.cumsum(c_tot, -1)                       # [..., rows]
     want = jnp.arange(1, k + 1, dtype=jnp.float32)
@@ -187,12 +209,44 @@ def select(scores, topk: int):
     idx = jnp.minimum(row * LANES + lane, n - 1).astype(jnp.int32)
     # is the j-th chosen a real token (score above -inf)?  Read the same
     # way, with no gather of scalars: its row by the one-hot, then its lane
-    finite = (u > _sortable(jnp.float32(-jnp.inf))).reshape(c_in.shape)
+    finite = _live(u).reshape(c_in.shape)
     in_row = jnp.einsum("...kr,...rc->...kc", hot,
                         finite.astype(jnp.bfloat16),
                         preferred_element_type=jnp.float32)
     at = jax.nn.one_hot(lane, LANES, dtype=jnp.float32)
     return idx, (in_row * at).sum(-1) > 0
+
+
+def select(scores, topk: int):
+    """The `topk` best-scored positions of each query, exactly, equal
+    scores to the lower position (the set `lax.top_k` returns; here in
+    position order).  Returns (idx `[B, s, k]` int32, valid `[B, s, k]`):
+    fewer than k tokens exist while the context is short, and the rest
+    are marked invalid.
+
+    `lax.top_k` of 2,048 in 32,768 is a full sort on the TPU: 2.91 ms a
+    layer for 16 queries on the v5e against this function's 0.44 (a
+    micro-run; PERF.md section 6, PR 28).  This is a selection instead,
+    all of it dense vector and matmul work, in two halves: the mask
+    (`_choose`: the k-th largest value by bisection over the bits of the
+    float, 32 compare-and-count passes, equal values admitted by
+    position) and its compaction into positions (`_compact`).  A program
+    of many queries attends under the mask itself (`select_mask`) and
+    compacts only for a caller that reads the positions."""
+    u, chosen, k = _choose(scores, topk)
+    return _compact(u, chosen, k, scores.shape[-1])
+
+
+def _mask(u, chosen, n: int):
+    """`_choose`'s set less the positions that hold no token, `[..., n]`."""
+    return (chosen & _live(u))[..., :n]
+
+
+def select_mask(scores, topk: int):
+    """`select`'s set as a dense mask `[..., n]`: True at the positions
+    `select` returns as valid, and nowhere else."""
+    u, chosen, _ = _choose(scores, topk)
+    return _mask(u, chosen, scores.shape[-1])
 
 
 def gather_rows(pool, tables, idx, width: int):
@@ -223,21 +277,57 @@ def attend_selected(q_cat, rows, valid, scale: float, d_latent: int):
 def sparse_latent_attention(q_cat, q_idx, w_idx, ckv_pool, kidx_pool,
                             tables, pos, *, topk: int, scale: float,
                             d_latent: int):
-    """Index, select, gather and attend for queries `[B, s, ...]` at
-    absolute positions `pos` `[B, s]`, over pools that already hold the
-    queries' own rows.  Returns (o `[B, s, nh, d_latent]` float32,
-    selected idx `[B, s, k]`, valid `[B, s, k]`)."""
+    """Index, select and attend for queries `[B, s, ...]` at absolute
+    positions `pos` `[B, s]`, over pools that already hold the queries'
+    own rows.  Returns (o `[B, s, nh, d_latent]` float32, selected idx
+    `[B, s, k]`, valid `[B, s, k]`).
+
+    The few queries of a decode step fetch their selected rows
+    (`gather_rows`) and attend them (`attend_selected`).  A chunk's many
+    queries attend, a tile at a time, every block the sequence holds
+    under the selection's mask (`pallas_latent.paged_latent_chunk`): the
+    same set and the same float32 softmax in another order of sums, with
+    no row fetched alone.  The positions come from the same mask, and a
+    program that drops them never compacts it."""
+    from . import pallas_latent
     B, s = pos.shape
+    few = s <= pallas_latent.KERNEL_MAX_QUERIES
 
     def tile(args):
         qc, qi, wi, p = args
         with jax.named_scope("dsa_index"):
             scores = index_scores(qi, wi, kidx_pool, tables, p)
+        if few:
+            with jax.named_scope("dsa_select"):
+                idx, valid = select(scores, topk)
+            with jax.named_scope("mla_attend"):
+                rows = gather_rows(ckv_pool, tables, idx, q_cat.shape[-1])
+                o = attend_selected(qc, rows, valid, scale, d_latent)
+            return o, idx, valid
+        n = scores.shape[-1]
         with jax.named_scope("dsa_select"):
-            idx, valid = select(scores, topk)
+            u, chosen, k = _choose(scores, topk)
+            idx, valid = _compact(u, chosen, k, n)
+            mask = _mask(u, chosen, n)
+        # the walk ends at the block of the tile's last query
+        lens = p.max(axis=1) + 1
+
+        def masked():
+            return pallas_latent.paged_latent_chunk(
+                qc, ckv_pool, tables, lens, mask, scale=scale,
+                d_latent=d_latent)
+
+        def gathered():
+            at, real = _compact(u, chosen, k, n)
+            rows = gather_rows(ckv_pool, tables, at, q_cat.shape[-1])
+            return attend_selected(qc, rows, real, scale, d_latent)
+
         with jax.named_scope("mla_attend"):
-            rows = gather_rows(ckv_pool, tables, idx, q_cat.shape[-1])
-            o = attend_selected(qc, rows, valid, scale, d_latent)
+            if n <= MASKED_PASS_MAX_ROWS:
+                o = masked()
+            else:
+                o = jax.lax.cond(lens.max() > MASKED_PASS_MAX_ROWS,
+                                 gathered, masked)
         return o, idx, valid
 
     if s <= _Q_TILE:

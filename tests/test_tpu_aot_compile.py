@@ -19,6 +19,7 @@ the chip's to say (`chip_smoke.py` Phase 3).
 
 import concurrent.futures
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -84,16 +85,24 @@ def _mosaic(fn):
     sandbox's CPU backend)."""
     @functools.wraps(fn)
     def traced(*args):
-        saved = pallas_common.interpret_default
-        pallas_common.interpret_default = lambda: False
-        try:
-            return fn(*args)
-        finally:
-            pallas_common.interpret_default = saved
+        # one trace at a time: `compiled` lowers its cases on threads, and
+        # two swaps that interleave would leave the lambda behind
+        with _MOSAIC_LOCK:
+            saved = pallas_common.interpret_default
+            pallas_common.interpret_default = lambda: False
+            try:
+                return fn(*args)
+            finally:
+                pallas_common.interpret_default = saved
     return traced
 
 
+_MOSAIC_LOCK = threading.Lock()
+
+
 _CELL_STEP = "paged_decode_step cell B16 nb24 nh16 hd128 bfloat16"
+_DSA_DECODE = "sparse_latent_attention decode B16 ctx32k bf16"
+_DSA_CHUNK = "sparse_latent_attention chunk s512 ctx32k bf16"
 
 
 def _cases():
@@ -175,12 +184,21 @@ def _cases():
         functools.partial(pallas_dsa.index_scores_decode, interpret=False),
         (((16, 1, 32, 128), BF16), ((16, 1, 32), BF16),
          ((6145, 64, 128), BF16), ((16, nb), i32), ((16, 1), i32)))
-    cases["sparse_latent_attention decode B16 ctx32k bf16"] = (
+    cases[_DSA_DECODE] = (
         _mosaic(functools.partial(sparse_mla.sparse_latent_attention,
                                   topk=2048, scale=1 / 16, d_latent=512)),
         (((16, 1, 64, 576), BF16), ((16, 1, 32, 128), BF16),
          ((16, 1, 32), BF16), ((6145, 64, 640), BF16),
          ((6145, 64, 128), BF16), ((16, nb), i32), ((16, 1), i32)))
+    # ... and a 512-token chunk of one sequence at the same widths: every
+    # tile of 32 queries x 64 heads walks the sequence's blocks under the
+    # selection's mask (`paged_latent_chunk`; the scores in plain XLA)
+    cases[_DSA_CHUNK] = (
+        _mosaic(functools.partial(sparse_mla.sparse_latent_attention,
+                                  topk=2048, scale=1 / 16, d_latent=512)),
+        (((1, 512, 64, 576), BF16), ((1, 512, 32, 128), BF16),
+         ((1, 512, 32), BF16), ((6145, 64, 640), BF16),
+         ((6145, 64, 128), BF16), ((1, nb), i32), ((1, 512), i32)))
     # SDAR-30B-A3B's block tick and chunk at its published head shapes:
     # 32 query heads over kv-head pools of 4 x 128 (nothing repeats K or
     # V), the mask full inside blocks of 4; a tick's block of 4 positions
@@ -269,6 +287,19 @@ def test_graft_entry_compiles_for_v5e(v5e, monkeypatch):
     lowered.compile()
 
 
+@pytest.mark.parametrize("case, fetches_rows", [(_DSA_DECODE, True),
+                                                (_DSA_CHUNK, False)])
+def test_only_a_decode_step_gathers_single_latent_rows(compiled, case,
+                                                       fetches_rows):
+    """A gathered row costs the v5e 27 ns whatever its bytes (PERF.md
+    section 6, PR 38): a decode step's 2,048 a query are worth it, a
+    chunk's 512 x 2,048 a layer are not, so the chunk program copies the
+    sequence's blocks whole and holds no gather of one 640-lane row."""
+    rows = [ln for ln in compiled[case].result().as_text().splitlines()
+            if " gather(" in ln and "slice_sizes={1,640}" in ln]
+    assert bool(rows) == fetches_rows, rows
+
+
 # ------------------------------------------------ names (ISSUE 26 §3)
 # The per-layer metrics find a kernel's events in the chip's trace by the
 # custom call's instruction name, and a program's launches by its module
@@ -285,6 +316,7 @@ KERNEL_CASES = {
     "dsa_index_scores": "dsa_index_scores B16 ctx32k bf16",
     "paged_latent_attention":
         "paged_latent_attention B24 s2 nh20 ctx18k bf16",
+    "paged_latent_chunk": _DSA_CHUNK,
 }
 
 
