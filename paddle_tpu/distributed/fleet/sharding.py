@@ -178,8 +178,8 @@ class DygraphShardingOptimizer:
         self._hcg = hcg
         self._stage = stage
         self._offload = offload
+        self._in_step = False
         self._axis = _resolve_axis(group)
-        axis = self._axis
         # intercept accumulator creation
         orig_get_state = optimizer._get_state
 
@@ -189,8 +189,7 @@ class DygraphShardingOptimizer:
             created = key not in store
             arr = orig_get_state(name, p, like)
             if created:
-                arr = shard_accumulator_fn(arr, axis)
-                store[key] = arr
+                arr = self._place_new(store, key, arr)
             return arr
         optimizer._get_state = sharded_get_state
         orig_master = optimizer._create_master_weight
@@ -201,10 +200,19 @@ class DygraphShardingOptimizer:
             created = key not in mw
             arr = orig_master(p)
             if created:
-                arr = shard_accumulator_fn(arr, axis)
-                mw[key] = arr
+                arr = self._place_new(mw, key, arr)
             return arr
         optimizer._create_master_weight = sharded_master
+
+    def _place_new(self, store, key, arr):
+        """Lay out an accumulator just created: sharded over the axis,
+        and under offload made where all state lives between steps when
+        it is made between steps (`to_static`'s state discovery
+        re-creates a step's fresh state there, at its pre-step value)."""
+        store[key] = shard_accumulator_fn(arr, self._axis)
+        if self._offload and not self._in_step:
+            self._migrate_state("pinned_host")
+        return store[key]
 
     def _shard_grads(self):
         """Stage >= 2: constrain grads to the sharded layout before update."""
@@ -287,6 +295,7 @@ class DygraphShardingOptimizer:
             # accumulator stores.
             snap = {name: dict(store) for name, store
                     in self._inner._accumulators.items()}
+            self._in_step = True
             try:
                 self._migrate_state(None)
                 self._inner.step()
@@ -296,6 +305,8 @@ class DygraphShardingOptimizer:
                     store.clear()
                     store.update(snap.get(name, {}))
                 raise
+            finally:
+                self._in_step = False
         else:
             self._inner.step()
 

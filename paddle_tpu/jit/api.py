@@ -9,9 +9,13 @@ so capture is *direct tracing* of the user's Python (the role SOT plays is
 done by jax.jit's tracer), with a state-discovery pass replacing ProgramDesc
 variable scoping:
 
-1. **Record** — run the function once eagerly with a dispatch hook that
-   records every concrete leaf Tensor feeding an op (parameters, buffers,
-   closure constants).  Mutations are rolled back afterwards.
+1. **Record** — trace the function once (`jax.make_jaxpr`: staged, not
+   executed) with a dispatch hook that records every concrete leaf Tensor
+   feeding an op (parameters, buffers, closure constants); everything
+   computed from the arguments is a tracer and is not recorded.  Mutations
+   are rolled back afterwards, also when the trace raises.  Only an SOT
+   record (`jit/sot.py`), whose burned concretizations need real values,
+   runs the function eagerly instead.
 2. **Functionalize** — lift the surviving recorded tensors (plus live
    optimizer accumulators / step counters / LR) into program inputs; run the
    function under `jax.jit`, swapping tensor storage for tracers. In-place
@@ -52,13 +56,16 @@ _M_JIT_TRACES = _metrics.counter(
 _M_JIT_COMPILE_S = _metrics.histogram(
     "jit.compile_seconds",
     "capture cost per program, by stage label, the seconds of the "
-    "to_static:<stage> spans: stage=discover is the eager state-discovery "
-    "run, trace_lower is jaxpr tracing + lowering, compile is the cache "
+    "to_static:<stage> spans: stage=discover is the state-discovery "
+    "pass, trace_lower is jaxpr tracing + lowering, compile is the cache "
     "load or backend compile, first_run the first execution")
 _M_SOT_GUARD = _metrics.counter(
     "jit.sot_guards", "SOT guarded-dispatch outcomes (kind=hit|miss)")
 _M_GRAPH_BREAKS = _metrics.counter(
     "jit.graph_breaks", "signatures that fell back to eager execution")
+_M_DISCOVERY = _metrics.counter(
+    "jit.discovery", "state-discovery passes by mode: trace (staged, "
+    "nothing executed) or eager (an SOT record, which needs values)")
 
 __all__ = ["to_static", "StaticFunction", "not_to_static", "ignore_module"]
 
@@ -229,7 +236,10 @@ class _Recorder:
             if _is_tracer(t._value):
                 continue
             self._seen_ids.add(id(t))
-            self.first_seen.append((t, t._value, t._grad))
+            # a backward accumulates into an existing grad in place
+            g = t._grad
+            self.first_seen.append(
+                (t, t._value, g, g._value if g is not None else None))
 
     def on_outputs(self, outs):
         for t in outs:
@@ -244,6 +254,50 @@ def _map_tensors(obj, fn):
     if isinstance(obj, dict):
         return {k: _map_tensors(v, fn) for k, v in obj.items()}
     return obj
+
+
+def _roll_back_optimizers(snapshots, finished):
+    """Bring every optimizer back to its snapshot; returns the (slot,
+    changed) pairs of the optimizers the finished pass stepped.
+
+    Entries the pass created become program inputs, at their pre-step
+    value, re-made by the optimizer's own creation path
+    (`_get_state` / `_create_master_weight`, which ZeRO sharding and
+    offload wrap): a traced entry has no placement to copy, and a fresh
+    entry lands where that path puts it.  A pass that raised keeps
+    nothing it created."""
+    out = []
+    for o, accs, gstep in snapshots:
+        # identity, not ==: a traced step count cannot be compared
+        stepped = finished and o._global_step is not gstep
+        o._global_step = gstep
+        # every store first: a creation path may look at all of them
+        # (offload places the whole state) and must see no tracer
+        after = []
+        for name, store in o._accumulators.items():
+            after.append((name, store, list(store.items())))
+            store.clear()
+            store.update(accs.get(name, {}))
+        if not stepped:
+            continue
+        params_by_id = {id(p): p for p in o._parameter_list}
+        for name, store, now in after:
+            old = accs.get(name, {})
+            for key, arr in now:
+                if key not in old:
+                    p = params_by_id[key]
+                    if name == "master_weight":
+                        # pre-step master state is the fp32 param
+                        o._create_master_weight(p)
+                    else:
+                        o._get_state(name, p, like=jax.ShapeDtypeStruct(
+                            arr.shape, arr.dtype))
+                out.append((_DictSlot(store, key), True))
+        out.append((_AttrSlot(o, "_global_step",
+                              cast=lambda v: jnp.asarray(v, jnp.int32)),
+                    True))
+        out.append((_LRSlot(o), False))
+    return out
 
 
 class StaticFunction:
@@ -284,92 +338,59 @@ class StaticFunction:
         return (treedef, tuple(sig))
 
     def _discover_state(self, args, kwargs, sot_record=False):
-        """Recording pass: eager run + rollback; returns
-        (slots, changed, burned) — `burned` is the ordered concretization
-        list when sot_record is on (see jit/sot.py), else None."""
+        """Recording pass: finds the step's state and rolls everything
+        back; returns (slots, changed, burned) — `burned` is the ordered
+        concretization list of an SOT record (see jit/sot.py), else None.
+
+        A plain build traces the function once (`_trace_once`): nothing
+        executes, so parameters and buffers stay concrete and are the
+        leaves recorded.  An SOT record (`sot_record`) runs it eagerly,
+        since its burns need real values.  Recorded tensors, optimizer
+        state and the RNG come back to their pre-pass values in both
+        modes, also when the pass raises: a tracer left in a parameter
+        would poison every later call."""
         from ..optimizer.optimizer import _live_optimizers
         rec = _Recorder()
-        # snapshot optimizer state for rollback
-        opts = list(_live_optimizers())
         opt_snapshots = [(o, {n: dict(s) for n, s in o._accumulators.items()},
-                          o._global_step) for o in opts]
+                          o._global_step) for o in _live_optimizers()]
         rng_state = _random.get_rng_state()
+        arg_ids = set()
+        _map_tensors((args, kwargs), lambda t: arg_ids.add(id(t)))
         # the registry fires these only from THIS thread — concurrent op
         # dispatch (the dataloader's device-prefetch producer fetching
         # the next batch) cannot leak into the recorded state
         _registry.set_trace_recorder(rec.on_inputs)
         _registry.set_trace_out_recorder(rec.on_outputs)
         burned = None
+        finished = False
         try:
             if sot_record:
                 with _sot.recording() as srec:
                     self._fn(*args, **kwargs)
                 burned = srec.values
             else:
-                self._fn(*args, **kwargs)
+                self._trace_once(args, kwargs)
+            finished = True
         finally:
             _registry.set_trace_recorder(None)
             _registry.set_trace_out_recorder(None)
-        _random.set_rng_state(rng_state)
+            _random.set_rng_state(rng_state)
+            recorded = []
+            for t, v0, g0, gv0 in rec.first_seen:
+                # a tracer put into a tensor is a replacement too
+                if id(t) not in arg_ids:
+                    recorded.append((t, t._value is not v0))
+                t._value, t._grad = v0, g0
+                if g0 is not None:
+                    g0._value = gv0
+            del rec
+            opt_slots = _roll_back_optimizers(opt_snapshots, finished)
 
-        slots: List[Any] = []
-        changed: List[bool] = []
-        arg_ids = set()
-        _map_tensors((args, kwargs), lambda t: arg_ids.add(id(t)))
-        recorded = []
-        for t, v0, g0 in rec.first_seen:
-            if id(t) in arg_ids:
-                t._grad = g0
-                continue
-            was_changed = t._value is not v0
-            # rollback
-            t._value = v0
-            t._grad = g0
-            recorded.append((t, was_changed))
-        # Optimizer rollback: keep entries created by the recorded step (the
-        # trace needs them as inputs) but reset values — pre-existing entries
-        # to their snapshot, fresh ones to zeros (their pre-step state).
-        for o, accs, gstep in opt_snapshots:
-            if o._global_step == gstep:
-                continue  # this optimizer didn't step inside fn
-            params_by_id = {id(p): p for p in o._parameter_list}
-            for name, store in o._accumulators.items():
-                for key in store:
-                    old = accs.get(name, {}).get(key)
-                    if old is not None:
-                        store[key] = old
-                    elif name == "master_weight":
-                        # pre-step master state is the fp32 param, not zeros
-                        p = params_by_id.get(key)
-                        store[key] = p._value.astype(jnp.float32) \
-                            if p is not None else store[key]
-                    else:
-                        arr = store[key]
-                        z = jnp.zeros(arr.shape, arr.dtype)
-                        # zeros_like on a non-default-memory array (e.g.
-                        # pinned_host offloaded state) trips XLA's memory-
-                        # space check; build zeros then copy the placement
-                        # — of a state that WAS placed (mesh-sharded,
-                        # offloaded).  State left at the default
-                        # placement stays uncommitted like the parameters
-                        # beside it: a device_put would commit it, the
-                        # first step's outputs would then all come back
-                        # committed, and the second call would compile
-                        # the whole step a second time.
-                        if getattr(arr, "committed", False):
-                            z = jax.device_put(z, arr.sharding)
-                        store[key] = z
-                    slots.append(_DictSlot(store, key))
-                    changed.append(True)
-            o._global_step = gstep
-            slots.append(_AttrSlot(o, "_global_step",
-                                   cast=lambda v: jnp.asarray(v, jnp.int32)))
-            changed.append(True)
-            slots.append(_LRSlot(o))
-            changed.append(False)
+        slots = [s for s, _ in opt_slots]
+        changed = [c for _, c in opt_slots]
         # drop temporaries: only tensors still alive elsewhere are state
         refs = [(weakref.ref(t), ch) for t, ch in recorded]
-        del recorded, rec
+        del recorded
         gc.collect()
         for r, ch in refs:
             t = r()
@@ -379,8 +400,29 @@ class StaticFunction:
             changed.append(ch)
         return slots, changed, burned
 
+    def _trace_once(self, args, kwargs):
+        """Stage the function once as `functional` does — its tensor
+        arguments wrapped as tracers, the traced key source installed —
+        and throw the trace away: only the recorder's view of it is
+        kept."""
+        vals = {}
+        _map_tensors((args, kwargs),
+                     lambda t: vals.setdefault(id(t), t._value))
+
+        def staged(key, vals):
+            t_args, t_kwargs = _map_tensors(
+                (args, kwargs), lambda t: Tensor._wrap(
+                    vals[id(t)], stop_gradient=t.stop_gradient))
+            with _random.key_source_guard(_random.TracedKeySource(key)):
+                self._fn(*t_args, **t_kwargs)
+
+        jax.make_jaxpr(staged)(_random.get_rng_state(), vals)
+
     def _build(self, args, kwargs, sot=False):
-        with _span("to_static:discover", fn=self.__name__) as discover:
+        mode = "eager" if sot else "trace"
+        _M_DISCOVERY.inc(fn=self.__name__, mode=mode)
+        with _span("to_static:discover", fn=self.__name__,
+                   mode=mode) as discover:
             slots, changed, burned = self._discover_state(args, kwargs,
                                                           sot_record=sot)
         mutable_idx = [i for i, c in enumerate(changed) if c]

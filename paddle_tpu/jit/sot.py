@@ -17,9 +17,10 @@ specialization*: `bool(t)` / `int(t)` / `float(t)` / `t.item()` on a traced
 Tensor (the things that otherwise raise ConcretizationTypeError and force a
 whole-function eager fallback) are intercepted:
 
-1. **Record** — the eager state-discovery pass runs with recording ON:
-   every concretization's Python value is appended, in execution order, to
-   the burn list.
+1. **Record** — the state-discovery pass runs the function eagerly (the
+   one case in which it does not just trace it) with recording ON: every
+   concretization's Python value is appended, in execution order, to the
+   burn list.
 2. **Replay** — during `jax.jit` tracing the same call sites pop the
    burned values (so Python takes the same branches) and emit the traced
    predicate as an extra program OUTPUT — the guard.
@@ -115,6 +116,12 @@ class _Replaying:
 
 recording = _Recording
 replaying = _Replaying
+
+
+def recording_active() -> bool:
+    """True while an eager SOT record runs the function (the record
+    holds pre-step buffer references for its rollback)."""
+    return _S.mode == "record"
 
 
 def intercept(kind: str, tensor, concretize):

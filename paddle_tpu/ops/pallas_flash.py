@@ -401,7 +401,7 @@ _PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 # The three calls are jitted on their own: a model's layers then share one
 # trace and one Mosaic lowering of each kernel inside a step's program, and
-# an eager caller (`to_static`'s discovery pass) lowers each once, not once
+# an eager caller (an SOT record of `to_static`) lowers each once, not once
 # a layer.
 _kernel_call = functools.partial(jax.jit, static_argnames=(
     "causal", "rate", "has_mask", "interpret", "block_q", "block_k"))

@@ -29,8 +29,9 @@ Programs are cached per ``(tree structure + dtypes, per-leaf static
 config, clip config, scaler config, donation)`` on the OPTIMIZER
 INSTANCE (update rules are per-instance closures over hyperparameters).
 Param/master/state buffers are donated so XLA updates them in place in
-HBM, exactly like the per-leaf path — and like it, donation is disabled
-while the `to_static` state-discovery pass holds rollback references.
+HBM, exactly like the per-leaf path — and like it, donation is held off
+only while an eager SOT record (`jit/sot.py`) holds rollback references
+(`to_static`'s traced state discovery executes nothing).
 
 Numerics: the fused program replays the per-leaf computation with the
 same primitives in the same order (left-fold squared-norm accumulation,

@@ -43,10 +43,11 @@ def _live_optimizers():
 
 
 def _donation_safe() -> bool:
-    """Donation must be off while the jit state-discovery pass records
-    pre-step buffer references for rollback."""
-    from ..ops import registry as _registry
-    return _registry._trace_recorder is None
+    """Donation must be off while an eager SOT record (jit/sot.py) holds
+    pre-step buffer references for rollback.  `to_static`'s traced state
+    discovery executes nothing, so nothing it stages is donated."""
+    from ..jit import sot as _sot
+    return not _sot.recording_active()
 
 
 def _instance_update(opt, rule, value, grad, master, states, lr, wd, step):
@@ -224,8 +225,8 @@ class Optimizer:
     def _update(self, value, grad, master, states, lr, wd, step):
         """Dispatch into the jitted rule; scalars ride as traced args so one
         executable serves every step and LR schedule value.  Donation updates
-        param/state buffers in place in HBM except during jit state-discovery
-        (the recorder holds references for rollback)."""
+        param/state buffers in place in HBM except during an eager SOT
+        record (it holds references for rollback)."""
         rule = type(self)._jitted_rule(donate=_donation_safe())
         if _metrics._ENABLED:
             _M_DISPATCH.inc_key(_K_LEAF_UPDATE)

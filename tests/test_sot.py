@@ -220,15 +220,17 @@ def test_record_trace_divergence_breaks_cleanly():
         state["calls"] += 1
         if x.max() < -100:             # always concretized (early return)
             return x
-        if state["calls"] % 2 == 0:    # python-only branch, flips per run
-            if x.sum() > 0:            # extra burn on even runs only
+        if state["calls"] % 2 == 1:    # python-only branch, flips per run
+            if x.sum() > 0:            # extra burn on odd runs only
                 return x * 2
         return x - 1
 
     sf = to_static(f)
     with pytest.warns(UserWarning, match="falling back"):
-        out = sf(t([1.5]))     # SOT record (odd) burns 1 value; the
-                               # trace (even) hits a second concretization
+        # run 1 is the traced state discovery (breaks at the first burn);
+        # the SOT record (run 2) burns 1 value; the replay trace (run 3)
+        # hits a second concretization
+        out = sf(t([1.5]))
     assert out is not None
     assert sf._stats["graph_breaks"]
     assert "burn" in sf._stats["graph_breaks"][0]["reason"]
