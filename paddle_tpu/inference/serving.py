@@ -343,6 +343,23 @@ _M_SSM_BYTES = _metrics.gauge(
     "recurrent state and convolution window of every layer and slot)")
 
 
+_M_RING_BYTES = _metrics.gauge(
+    "serving.cache.window_ring_bytes", "device bytes of the window layers' "
+    "per-slot rings (K and V of the last `window` positions a slot and "
+    "layer), the same whatever the context")
+_M_RING_WRITES = _metrics.counter(
+    "serving.cache.ring_writes", "positions written into the window "
+    "layers' rings (a real prompt row, or a position a tick's forward "
+    "wrote for a running slot; each lands in every window layer)")
+_M_FULL_BLOCKS = _metrics.gauge(
+    "serving.cache.full_blocks_in_use", "paged blocks held by sequences "
+    "(only the full-attention layers keep rows in them where the model "
+    "has window layers)")
+_M_LIVE_TOKENS = _metrics.gauge(
+    "serving.cache.live_context_tokens", "context positions of the "
+    "sequences in the batch slots (a prompt mid-prefill counts whole)")
+
+
 _M_BLOCK_FORWARDS = _metrics.counter(
     "serving.block.forwards", "forwards of a block-diffusion tick "
     "(denoising + commit), whole-batch forwards a launch")
@@ -1009,11 +1026,20 @@ class ServingEngine:
         if (self.mtp is not None or self._per_slot) and self.chunk <= 0:
             self.chunk = self.pad_ladder[-1]
         self.ssm_resets = self.ssm_carried = 0
-        self.ssm_state_bytes = sum(
-            p.size * p.dtype.itemsize for layer in self.pools
-            for row, p in zip(self.cache.rows, layer) if row.per_slot)
-        if self._per_slot:
+        # ... of which a recurrent layer's state (a ring is not one)
+        self._ssm = any(r.per_slot and not r.window for r in self.cache.rows)
+        self.ssm_state_bytes = self.cache.held_bytes(
+            self.pools, lambda row: row.per_slot and not row.window)
+        if self._ssm:
             _M_SSM_BYTES.set(self.ssm_state_bytes)
+        # window layers' rings (`PoolRow.window`) beside the full layers'
+        # paged pools: their bytes stay what they are whatever the context
+        self._ring = any(r.window for r in self.cache.rows)
+        self.ring_writes = 0
+        self.window_ring_bytes = self.cache.held_bytes(
+            self.pools, lambda row: row.window > 0)
+        if self._ring:
+            _M_RING_BYTES.set(self.window_ring_bytes)
         if self.gen is not None:
             # chunks begin and end on multiples of the block length (the
             # mask is full inside a block); unchunked, a chunk is as long
@@ -1249,16 +1275,19 @@ class ServingEngine:
         return forward
 
     def _draft(self, pools, tables, lens, h, next_ids, view_cls=None,
-               in_tick=False):
+               in_tick=False, slots=None, n_new=None):
         """The self-drafter's side of the forward seam (the parameters
         are bound by `_forward`): the module over the positions whose
-        hidden states are `h`, `lens` the lengths BEFORE them.  Returns
-        (the module's last hidden states `[B, s, H]`, of which `_head`
-        gives the logits; new pools)."""
+        hidden states are `h`, `lens` the lengths BEFORE them (`slots`,
+        `n_new`: as `_forward`'s, for a cache with per-slot rows).
+        Returns (the module's last hidden states `[B, s, H]`, of which
+        `_head` gives the logits; new pools)."""
         from ..framework.dygraph import no_grad
         views = self._views(pools, tables, lens, view_cls)
         for view in views:
             view.in_tick = in_tick
+            if slots is not None:
+                view.slots, view.n_new = slots, n_new
         with no_grad():
             z, new_views = self.model.draft_hidden(h, next_ids, views)
         return z._value, [c.pools for c in new_views]
@@ -1542,17 +1571,21 @@ class ServingEngine:
             return row, pools
 
         def prefill_mtp(params, pools, table_row, prompt, true_len, off,
-                        next_ids):
+                        next_ids, *slot):
             """A chunk of a self-drafting model's prompt: the model's
             rows, then the module's from the chunk's own hidden states
             and ``next_ids``, the prompt shifted by one; -1 there stands
             for the token this chunk's last logits choose (the prompt's
-            last chunk).  Returns (the last real token's logits, the
-            module's draft of the token after the chosen one, pools)."""
+            last chunk).  With per-slot rows ``slot`` is the sequence's
+            batch slot, as ``prefill_slot``'s.  Returns (the last real
+            token's logits, the module's draft of the token after the
+            chosen one, pools)."""
             forward = self._forward(params)
             lens = jnp.reshape(off, (1,))
+            at = dict(slots=jnp.reshape(slot[0], (1,)),
+                      n_new=jnp.reshape(true_len, (1,))) if slot else {}
             h, pools = forward(prompt, pools, table_row, lens, off, view_cls,
-                               hidden=True)
+                               hidden=True, **at)
             def last_row(x):     # the head over the last real token only
                 return self._head(jax.lax.dynamic_slice_in_dim(
                     x, true_len - 1, 1, axis=1))[0, 0]
@@ -1561,7 +1594,7 @@ class ServingEngine:
             first = jnp.argmax(row).astype(jnp.int32)
             z, pools = self._draft(pools, table_row, lens, h,
                                    jnp.where(next_ids < 0, first, next_ids),
-                                   view_cls)
+                                   view_cls, **at)
             return row, jnp.argmax(last_row(z)).astype(jnp.int32), pools
 
         def prefill_slot(params, pools, table_row, prompt, true_len, off,
@@ -1601,6 +1634,8 @@ class ServingEngine:
             body, state, outs = prefill_mtp, (_PARAMS, _POOLS), \
                 (_REP, _REP, _POOLS)
             ins += (_In((1, L_pad), i32),)
+            if self._per_slot:
+                ins += (_In((), i32),)
         elif self._per_slot and at_offset:
             body, state, outs = prefill_slot, (_PARAMS, _POOLS), \
                 (_REP, _POOLS)
@@ -3027,7 +3062,8 @@ class ServingEngine:
                 raise
             req._chunk_off = off + n
             req._prefill_chunks += 1
-            if self._per_slot:
+            self._count_ring(n)
+            if self._ssm:
                 if off:
                     self.ssm_carried += 1
                     _M_SSM_CARRIED.inc()
@@ -3193,6 +3229,31 @@ class ServingEngine:
         pend.sched_s, pend.chunk_s = sched_s, chunk_s
         return pend
 
+    def _count_ring(self, positions: int) -> None:
+        """Positions written into the window layers' rings."""
+        if self._ring:
+            self.ring_writes += positions
+            _M_RING_WRITES.inc(positions)
+
+    def cache_counts(self) -> dict:
+        """What the cache of a model with window layers holds now (host
+        data): the rings' bytes (the same whatever the context), the paged
+        blocks the sequences hold (only full layers keep rows in them),
+        the context positions of the sequences in the batch slots (a
+        prompt mid-prefill counts whole: its admission drew the blocks of
+        all of it), and the positions written into the rings so far.  The
+        gauges of the metrics registry are set from it."""
+        live = sum(len(r.prompt_ids) if r._prefilling
+                   else int(self.seq_lens[s])
+                   for s, r in enumerate(self.slot_req) if r is not None)
+        out = {"window_ring_bytes": self.window_ring_bytes,
+               "full_blocks_in_use": self.num_blocks - len(self.free_blocks),
+               "live_context_tokens": live,
+               "ring_writes": self.ring_writes}
+        _M_FULL_BLOCKS.set(out["full_blocks_in_use"])
+        _M_LIVE_TOKENS.set(live)
+        return out
+
     def cache_state(self) -> dict:
         """Per-layer device state the programs thread with the pools (an
         expert layer's row counts), `[num_layers, ...]` a row by its
@@ -3352,6 +3413,7 @@ class ServingEngine:
         for slot in active:
             self.seq_lens[slot] += k
             self.tok_pos[slot] += k
+        self._count_ring(k * len(active))
         pend = _PendingTick(active=active, k=k, toks=toks, logits=logits,
                             reqs=list(self.slot_req), t0=t0,
                             device_sampling=device_sampling,
@@ -3604,6 +3666,8 @@ class ServingEngine:
              self.pools, state) = self._dispatch_call(
                 "serving.tick.dispatch", launch)
         self.steps += 1              # one verify forward (and its draft)
+        # the verify writes both positions of every running slot
+        self._count_ring(2 * len(active))
         for slot in active:
             self.seq_lens[slot] += int(kcap[slot])
             self.tok_pos[slot] += int(kcap[slot])
@@ -4686,7 +4750,9 @@ class ServingEngine:
         state = self.cache_state()
         if state:
             out["cache_state"] = state
-        if self._per_slot:
+        if self._ring:
+            out["cache"] = self.cache_counts()
+        if self._ssm:
             out["ssm"] = {"state_resets": self.ssm_resets,
                           "carried_chunks": self.ssm_carried,
                           "state_bytes": self.ssm_state_bytes}

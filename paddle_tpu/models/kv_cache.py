@@ -30,7 +30,8 @@ import jax.numpy as jnp
 __all__ = ["StaticKVCache", "PagedKVCache", "PagedChunkView",
            "PagedChunkKernelView", "PagedVerifyKernelView", "PoolRow",
            "CacheSpec", "BlockDiffusion", "SelfDraft", "kv_cache_spec",
-           "SlotStateView", "SlotStateKernelView",
+           "SlotStateView", "SlotStateKernelView", "WindowRingView",
+           "WindowRingKernelView",
            "LatentPagedCache", "LatentDenseCache"]
 
 
@@ -355,6 +356,50 @@ class SlotStateKernelView(SlotStateView, PagedChunkKernelView):
     decode step's one query a slot included)."""
 
 
+class WindowRingView(SlotStateView):
+    """A layer of a model whose window layers keep a ring of the last
+    `window` positions a batch slot (`PoolRow.window`, `exaone_moe`) and
+    whose full layers keep their (K, V) under the block table: a full
+    layer attends as `SlotStateView` does, a window layer through
+    `window_update_and_attend`, which never touches the paged pools."""
+
+    def _at_slots(self, pool):
+        """`pool`'s entries at the view's slots, as they are (a ring needs
+        no zeroing: `ops/window_attention.py`)."""
+        if self.slots is None:
+            return pool
+        return jnp.concatenate([jax.lax.dynamic_slice_in_dim(
+            pool, self.slots[i], 1) for i in range(self.seq_lens.shape[0])])
+
+    def window_update_and_attend(self, q, k, v, *, window: int, scale):
+        """Attend `q` `[b, s, nh, hd]` under the window rule over the
+        slots' rings (the first two rows of `state`: K's, then V's) and
+        the new `k`, `v` `[b, s, nkv, hd]`, then write the real rows of
+        those into the rings (`n_new` of them; every row of a tick, whose
+        idle slots write nothing).  Returns (the advanced view, `[b, s, nh,
+        hd]`)."""
+        from ..ops import window_attention as wa
+        s = q.shape[1]
+        lens = self.seq_lens
+        ring_k, ring_v = (self._at_slots(r) for r in self.state[:2])
+        o = wa.window_attend(q, k, v, ring_k, ring_v, lens, window=window,
+                             scale=scale)
+        n_new = self.n_new if self.n_new is not None \
+            else jnp.full(lens.shape, s, lens.dtype)
+        rings = [self.put_slot_rows(pool, wa.ring_write(
+                     ring, rows, lens, n_new, self.active))
+                 for pool, ring, rows in zip(self.state, (ring_k, ring_v),
+                                             (k, v))]
+        new = self.with_state(*rings, *self.state[2:])
+        new.seq_lens = lens + s
+        return new, o
+
+
+class WindowRingKernelView(WindowRingView, SlotStateKernelView):
+    """`WindowRingView` whose full layers attend through the kv-head chunk
+    kernel (a decode step's one query a slot included)."""
+
+
 @dataclasses.dataclass(frozen=True)
 class PoolRow:
     """One device array of a layer's cache.  A paged row is a pool
@@ -368,18 +413,32 @@ class PoolRow:
     (and `paged=False`) it is `(slots,) + lead`, one entry a batch slot:
     the state a recurrent layer carries for the sequence in that slot,
     which a view reads and writes at its sequences' slots (`SlotStateView`);
-    it stays on the device and is never handed back."""
+    it stays on the device and is never handed back.  `window` > 0 says a
+    per-slot row is a ring of that many positions (position `p` at index
+    `p mod window`: `ops/window_attention.py`).  `layers` names the layers
+    that hold the row (None: every layer); any other layer holds a
+    stand-in of one block (a paged row: the pad block) or one slot, which
+    no program of it reads (a window layer's (K, V) pools, a full layer's
+    rings)."""
     name: str
     lead: tuple = ()
     trail: tuple = ()
     dtype: object = None          # None: the model's parameter dtype
     paged: bool = True
     per_slot: bool = False
+    window: int = 0
+    layers: tuple = None
 
     def __post_init__(self):
         if self.paged and self.per_slot:
             raise ValueError(f"PoolRow {self.name!r}: a per-slot row is "
                              f"not paged (give paged=False)")
+        if self.window and not self.per_slot:
+            raise ValueError(f"PoolRow {self.name!r}: a ring is a per-slot "
+                             f"row (give per_slot=True)")
+
+    def held_by(self, layer: int) -> bool:
+        return self.layers is None or layer in self.layers
 
     @property
     def block_axis(self) -> int:
@@ -461,13 +520,23 @@ class CacheSpec:
     def init_pools(self, num_blocks, block_size, dtype, place=None,
                    num_slots=0):
         """Zeroed arrays of every layer (a `per_slot` row has `num_slots`
-        entries); `place(row, array)` may move or shard one."""
-        def one(row):
-            z = jnp.zeros(row.shape(num_blocks, block_size, num_slots),
+        entries; a layer that does not hold a row, its stand-in of one
+        block or slot); `place(row, array)` may move or shard one."""
+        def one(row, layer):
+            held = row.held_by(layer)
+            z = jnp.zeros(row.shape(num_blocks if held else 0, block_size,
+                                    num_slots if held else 1),
                           row.dtype or dtype)
             return z if place is None else place(row, z)
-        return [tuple(one(r) for r in self.rows)
-                for _ in range(self.num_layers)]
+        return [tuple(one(r, i) for r in self.rows)
+                for i in range(self.num_layers)]
+
+    def held_bytes(self, pools, which) -> int:
+        """Device bytes of `pools` in the rows `which(row)` selects, in the
+        layers that hold them (the stand-ins left out)."""
+        return sum(p.size * p.dtype.itemsize for i, layer in enumerate(pools)
+                   for row, p in zip(self.rows, layer)
+                   if which(row) and row.held_by(i))
 
 
 def kv_cache_spec(num_layers: int, num_kv_heads: int, head_dim: int,

@@ -671,3 +671,92 @@ def test_serving_program_updates_the_state_in_place(ssm_programs, program):
         + 64 * 3 * 5120 * 2
     assert compiled[program].memory_analysis().alias_size_in_bytes \
         >= pool_bytes
+
+
+# ------------------------------------- window rings beside paged pools
+# K-EXAONE's stage (`exaone_moe`): a window layer's K and V live in a ring
+# of 128 positions a slot, a full layer's and the MTP module's in paged
+# kv-head pools.  The engine's own self-drafted tick and its largest chunk
+# program over a model at the published widths (hidden 6,144, 64 query
+# heads and 8 kv heads of 128, window 128, experts of 2,048 under the
+# router's 128; one window layer, one full layer and the module, one
+# expert held, vocabulary 384) are compiled here at the cell's slots and
+# context: no weight and no pool is copied or re-laid-out at a launch,
+# every pool comes back where it lay, and a launch's scratch stays far
+# under what the chip has beside the stage's 9.1 GB of weights.  (Without
+# the barrier after the attention's projections, XLA:TPU turned W_q, W_k
+# and W_v round at every launch of the chunk program, to give the dot the
+# heads-major layout the attention reads: 0.4 GB of copies a chunk.)
+
+@pytest.fixture(scope="module")
+def ring_programs(v5e):
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                              ExaoneMoeForCausalLM)
+
+    sh = SingleDeviceSharding(v5e)
+    spec = lambda tree: jax.tree_util.tree_map(        # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sh),
+        tree)
+    saved = pallas_common.interpret_default
+    pallas_common.interpret_default = lambda: False
+    try:
+        paddle.seed(0)
+        model = ExaoneMoeForCausalLM(ExaoneMoeConfig(
+            vocab_size=384, num_layers=2, n_experts_held=1,
+            layer_types=("sliding_attention", "full_attention"),
+            mlp_layer_types=("sparse", "sparse"), max_seq_len=33280,
+            param_dtype="bfloat16"))
+        model.eval()
+        eng = ServingEngine(model, max_batch=64, max_context=33280,
+                            block_size=64, num_blocks=520,
+                            prefill_chunk=512, pad_buckets=[128, 256, 512])
+        lowered = {}
+        with eng._params_for_call() as params:
+            for label, fn in (("tick", eng._mtp_tick_program()),
+                              ("chunk", eng._prefill_cont_program(512))):
+                body = fn.__wrapped__.__wrapped__
+                lowered[label] = jax.jit(
+                    body, donate_argnums=fn.decl.donated).lower(
+                        *spec(eng._inert_args(fn.decl, params)))
+            weights = {tuple(p.shape) for p in params if p.ndim == 2}
+        pools = [[tuple(p.shape) for p in layer] for layer in eng.pools]
+        pool_bytes = sum(p.size * p.dtype.itemsize for layer in eng.pools
+                         for p in layer)
+    finally:
+        pallas_common.interpret_default = saved
+    with concurrent.futures.ThreadPoolExecutor(max_workers=2) as ex:
+        futs = {k: ex.submit(low.compile) for k, low in lowered.items()}
+    return ({k: f.result() for k, f in futs.items()}, pools, pool_bytes,
+            weights)
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_window_rings_and_weights_stay_in_place(ring_programs, program):
+    import re
+    compiled, pools, pool_bytes, weights = ring_programs
+    ring, kv = (64, 8, 128, 128), (8, 521, 64, 128)
+    # layer 0 windowed (its (K, V) a one-block stand-in), layer 1 full,
+    # the module full (its rings a one-slot stand-in)
+    assert [layer[:4] for layer in pools] == [
+        [(8, 1, 64, 128)] * 2 + [ring] * 2, [kv] * 2 + [(1, 8, 128, 128)] * 2,
+        [kv] * 2 + [(1, 8, 128, 128)] * 2]
+    assert {(6144, 8192), (6144, 1024), (8192, 6144), (12288, 6144)} \
+        <= weights, weights
+    text = compiled[program].as_text()
+    # the tick routes 64 slots x 2 positions x 8 experts = 1,024 rows of
+    # 6,144: the expert layer's gathered rows have W_k's shape turned
+    # round, and are no weight
+    hits = [h for h in _relayouts(text, weights | {w[::-1] for w in weights}
+                                  | {ring, kv})
+            if 'op_name="gather"' not in h[1]]
+    assert not hits, hits
+    assert "dim_labels=bf01_01oi->b01f" not in text
+    names = _custom_call_names(text)
+    assert any("paged_chunk_prefill" in n for n in names), names
+    mem = compiled[program].memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
